@@ -33,14 +33,15 @@ contiguous blocks fed straight to the §6.3 filter kernel:
     uses the same fused group launch, so requests pruned by the evolving
     bounds never pay for their block of the cross-product matrix;
   * ``backend='fused-gather'`` (the TPU platform default) additionally
-    fuses the CANDIDATE GATHER into that launch: the kernel scalar-prefetches
-    the CSR posting-list row offsets and DMA-gathers each row block from the
+    fuses the CANDIDATE GATHER into that launch: the kernel takes the CSR
+    posting-list row offsets and DMA-gathers each row block from the
     device-resident superkey store (``MateIndex.device_store()``, refreshed
     on §5.4 mutation epochs) straight into VMEM — the host never gathers the
     candidate superkeys and the gathered rows×lanes block never exists in
     HBM (``DiscoveryStats.gather_bytes_saved`` counts the traffic avoided).
-    Demotes to 'fused' per launch when the store is over budget or the batch
-    exceeds the scatter-tile table cap;
+    Demotes to 'fused' when the store is over the device budget, counted in
+    ``DiscoveryStats.gather_demotions``; launches over the scatter tile's
+    table cap split into table chunks (``kernels.ops.table_chunks``);
   * tables are visited in the same descending posting-list order as
     Algorithm 1; rule 1 (global cutoff) applies BETWEEN batches — identical
     pruning guarantee, since the bound only improves as the scan proceeds;
@@ -445,6 +446,9 @@ def discover_batched(
         if not routed and bk.gather and ops.gather_store_fits(index.superkeys)
         else None
     )
+    # a gather backend without a store is the one counted demotion: the
+    # host gathers the candidate superkeys for the fused launch instead
+    demoted = not routed and bk.gather and store is None
     topk = _TopK(k)
     n_tables = block.n_tables
     for start in range(0, n_tables, batch_tables):
@@ -458,7 +462,7 @@ def discover_batched(
             break
         lo, hi = int(block.table_ptr[start]), int(block.table_ptr[stop])
         rows = block.rows[lo:hi]
-        use_gather = store is not None and (stop - start) <= ops._FUSED_MAX_TABLES
+        use_gather = store is not None
         # the gather-fused contract: the host NEVER touches the candidate
         # superkeys — the kernel DMA-gathers them from the device store.
         # The routed contract is stricter still: the host never gathers a
@@ -497,16 +501,13 @@ def discover_batched(
             # fused filter+segment-count launch: the match matrix is never
             # produced (zero filter_matrix_bytes), only the counts vector
             # comes back; surviving tables' slices are recomputed on demand
-            # in _score_tables.  (ops falls back to the composed path above
-            # its table cap — hits non-None — and stats must follow suit.)
+            # in _score_tables.
             hits, counts = ops.filter_hits_table_counts(
                 row_f, q_f, elig, seg, stop - start, backend=bk,
                 fused_block_n=fused_block_n,
             )
-            if hits is None:
-                stats.filter_fused_launches += 1
-            else:
-                stats.filter_matrix_bytes += int(elig.size)
+            stats.filter_fused_launches += 1
+            stats.gather_demotions += int(demoted)
         elif bk.device and topk.full and topk.bound() > 0:
             # bound can prune → composed device launch: hits stay on device,
             # only the per-table counts vector is read back; surviving
@@ -581,6 +582,10 @@ class PlanCounts:
     # each of its candidate tables was counted on exactly one of them)
     route_bytes: int = 0  # routed index: this request's share of the
     # cross-shard count-merge bytes (its counts vector × shards touched)
+    # the shared launch's demotions off the gather-fused path, charged to
+    # every request of the group (same fields on DiscoveryStats)
+    gather_demotions: int = 0
+    shard_gather_demotions: int = 0
 
     def cacheable(self) -> "PlanCounts":
         """A copy safe to hold in a cache: the (possibly device-resident)
@@ -649,10 +654,7 @@ def plan_and_count(
     q_f = q_all if fl == full_lanes else q_all[:, :fl]
     routed = getattr(index, "routed", False)
     use_gather = (
-        not routed
-        and bk.gather
-        and ops.gather_store_fits(index.superkeys)
-        and n_tables_all <= ops._FUSED_MAX_TABLES
+        not routed and bk.gather and ops.gather_store_fits(index.superkeys)
     )
     # gather-fused group launch: no host superkey gather at all — the kernel
     # pulls every request's candidate rows from the device store, and phase B
@@ -666,13 +668,17 @@ def plan_and_count(
         None if row_sk_all is None
         else row_sk_all if fl == full_lanes else row_sk_all[:, :fl]
     )
+    # launch-level counters: only the demotions are read back from it (the
+    # routed byte/launch accounting is attributed per request below)
+    group = DiscoveryStats()
+    group.gather_demotions = int(not routed and bk.gather and not use_gather)
     if routed:
         # shard-local counts-only launches for the whole group; per-request
         # routing accounting is attributed below from each plan's own items.
         hits_all = None
         counts_all = index.routed_counts(
             rows_all, q_f, elig_all, seg_all, n_tables_all,
-            backend=bk, fused_block_n=fused_block_n,
+            backend=bk, fused_block_n=fused_block_n, stats=group,
         )
     elif use_gather:
         hits_all, counts_all = ops.filter_hits_table_counts(
@@ -733,6 +739,8 @@ def plan_and_count(
                 gather_saved=ni * (fl * 4 - 4) if use_gather else 0,
                 route_launches=n_sh,
                 route_bytes=n_sh * ti * 4,
+                gather_demotions=group.gather_demotions,
+                shard_gather_demotions=group.shard_gather_demotions,
             )
         )
         r_off += ni
@@ -780,6 +788,8 @@ def score_from_counts(
         stats.gather_bytes_saved += pc.gather_saved
         stats.shard_launches += pc.route_launches
         stats.route_bytes_merged += pc.route_bytes
+        stats.gather_demotions += pc.gather_demotions
+        stats.shard_gather_demotions += pc.shard_gather_demotions
     else:
         # the shared launch computes (and reads back) this plan's rows
         # against the GROUP's keys — the documented cross-product trade.

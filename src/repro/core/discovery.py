@@ -63,8 +63,11 @@ class DiscoveryStats:
     # (the ENTIRE cross-shard traffic of a routed filter; compare against
     # n_items × lanes × 4, the superkey bytes a host-gather path would ship)
     shard_gather_demotions: int = 0  # shard launches demoted off the
-    # gather-fused path (store over budget / scatter-tile cap / no per-shard
-    # store, e.g. the pre-routed mesh row filter) — each is also debug-logged
+    # gather-fused path (store over budget / no per-shard store, e.g. the
+    # pre-routed mesh row filter) — each is also debug-logged
+    gather_demotions: int = 0  # single-index launches demoted off the
+    # gather-fused path to the host-gather fused launch (store over the
+    # device budget, ``kernels.ops.GATHER_STORE_MAX_BYTES``)
     # ranking-subsystem accounting (``core.profiles`` / ``core.ranking``):
     tables_gated: int = 0  # candidate tables the profile gate dropped before
     # any filter launch (provably joinability 0 — pure pruning, so the

@@ -5,7 +5,7 @@ Both halves of the system shard the same way.  The ONLINE filtering layer
 OFFLINE build (``core.index.build_index``) is embarrassingly parallel over
 unique values (hashing) and corpus rows (super keys, posting lists).  The
 shard helpers at the bottom of this module (``shard_bounds``,
-``mesh_shard_count``, ``pad_rows_to_shards``, ``shard_map_compat``) are the
+``mesh_shard_count``, ``pad_rows_to_shards``) are the
 shared vocabulary: contiguous balanced row/value blocks, padded to the mesh
 where device work needs equal shards.
 
@@ -30,7 +30,6 @@ blocks are balanced by construction (equal shard sizes after padding).
 from __future__ import annotations
 
 import functools
-import inspect
 import logging
 
 import jax
@@ -42,25 +41,6 @@ from repro.kernels import registry
 from repro.kernels.registry import Backend
 
 _LOG = logging.getLogger(__name__)
-
-# jax.shard_map landed after 0.4.x; fall back to the experimental home
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the version-compat shard_map entry shared with the offline build
-# (kernels.ops.xash_values_mesh) — same callable the filter wraps below
-shard_map_compat = _shard_map
-
-
-def _no_rep_check_kwargs() -> dict:
-    """shard_map kwargs disabling the replication-rule check (pallas_call has
-    no replication rule); the flag was renamed check_rep → check_vma."""
-    params = inspect.signature(_shard_map).parameters
-    for name in ("check_rep", "check_vma"):
-        if name in params:
-            return {name: False}
-    return {}
 
 
 def filter_counts_local(
@@ -221,14 +201,14 @@ def make_distributed_filter(
     """
     impl = shard_impl_for(backend)
     local = _FILTER_IMPLS[impl]
-    extra = _no_rep_check_kwargs() if impl == "fused" else {}
 
+    # pallas_call has no replication rule: the fused body skips the check
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(row_axes), P(row_axes), P()),
         out_specs=(P(), P()),
-        **extra,
+        check_vma=impl != "fused",
     )
     def _sharded(superkeys, row_tables, query_sks):
         tc, kc = local(superkeys, row_tables, query_sks, n_tables)
@@ -245,7 +225,7 @@ def make_distributed_filter(
 
 
 def _routed_local_counts_fn(
-    row_axes, n_shards, pad_store, pad_items, qb, q, fl, n_tables, impl: str,
+    mesh, row_axes, pad_items, qb, q, n_tables, impl: str,
 ):
     """Build the jitted shard_map'd routed filter for one shape bucket.
 
@@ -260,22 +240,23 @@ def _routed_local_counts_fn(
     Each shard gathers ONLY from its own store block and the single
     cross-shard exchange is the counts psum: superkey rows never leave
     their shard.  ``impl`` 'fused' runs the Pallas fused counts kernel per
-    shard (mode='sum'); 'xla' is the lane-unrolled fallback — bit-identical
-    counts either way.
+    shard (mode='sum', ``n_tables`` within its scatter-tile cap); 'xla' is
+    the lane-unrolled body for the composed and host backends —
+    bit-identical counts either way.
     """
     from repro.kernels import filter_kernel
 
     def _local(store, rows, seg, elig, qry):
+        fl = qry.shape[1]
         sk = store[rows][:, :fl]
         if impl == "fused":
-            interpret = jax.default_backend() != "tpu"
             tb = max(-(-n_tables // 128) * 128, 128)
             block_n = min(pad_items, filter_kernel.fused_block_n(tb))
             block_q = min(qb, filter_kernel.DEFAULT_BLOCK_Q)
             counts, _ = filter_kernel.filter_table_counts(
                 sk.T, qry.T, elig, seg,
                 n_tables=tb, n_queries=q, block_n=block_n, block_q=block_q,
-                mode="sum", interpret=interpret,
+                mode="sum", interpret=jax.default_backend() != "tpu",
             )
             counts = counts[:n_tables]
         else:
@@ -292,21 +273,16 @@ def _routed_local_counts_fn(
             )
         return jax.lax.psum(counts, row_axes)
 
-    def wrap(mesh):
-        extra = _no_rep_check_kwargs() if impl == "fused" else {}
-        return jax.jit(
-            _shard_map(
-                _local,
-                mesh=mesh,
-                in_specs=(
-                    P(row_axes), P(row_axes), P(row_axes), P(row_axes), P()
-                ),
-                out_specs=P(),
-                **extra,
-            )
+    # pallas_call has no replication rule: the fused body skips the check
+    return jax.jit(
+        jax.shard_map(
+            _local,
+            mesh=mesh,
+            in_specs=(P(row_axes), P(row_axes), P(row_axes), P(row_axes), P()),
+            out_specs=P(),
+            check_vma=impl != "fused",
         )
-
-    return wrap
+    )
 
 
 def _routed_mesh_store(index):
@@ -336,25 +312,33 @@ def routed_filter_counts_mesh(
     seg_ids: np.ndarray,
     n_tables: int,
     backend: Backend | str | None = None,
-) -> tuple[np.ndarray, bool]:
-    """One shard_map launch of the routed filter over ``index``'s mesh.
+) -> np.ndarray:
+    """The routed filter over ``index``'s mesh: int32[n_tables] counts,
+    bit-identical to the host-routed (and single-host) counts.
 
-    Partitions the batch's candidate items by owning shard, pads each
+    Each launch partitions its candidate items by owning shard, pads each
     shard's slice to a shared pow2 bucket, and runs the per-shard filter +
-    counts psum as a single SPMD program.  Returns ``(counts, demoted)``:
-    ``counts`` int32[n_tables] bit-identical to the host-routed (and
-    single-host) counts; ``demoted`` True when a fused/gather backend fell
-    back to the lane-unrolled XLA shard body (Pallas unavailable under this
-    mesh — logged, counted by the caller on ``DiscoveryStats``).
+    counts psum as a single SPMD program.  Fused backends run the Pallas
+    fused body, split into ``ops.table_chunks`` launches above its table
+    cap; the others run the lane-unrolled XLA body.
     """
     from repro.kernels import ops
 
-    bk = registry.resolve_backend(backend)
+    impl = "fused" if registry.resolve_backend(backend).fused else "xla"
+    return ops.chunked_counts(
+        lambda r, e, s, nt: _routed_launch(index, r, query_sk, e, s, nt, impl),
+        seg_ids, elig, n_tables, np.asarray(rows, dtype=np.int64),
+    )
+
+
+def _routed_launch(index, rows, query_sk, elig, seg_ids, n_tables, impl):
+    """One shard_map launch of the routed filter (see
+    ``routed_filter_counts_mesh``)."""
+    from repro.kernels import ops
+
     mesh, row_axes = index._mesh, index._row_axes
     n_shards = index.n_shards
-    rows = np.asarray(rows, dtype=np.int64)
-    n, q = rows.shape[0], query_sk.shape[0]
-    fl = query_sk.shape[1]
+    q, fl = query_sk.shape
     sid = index._shard_ids_of_rows(rows)
     store, pad_store = _routed_mesh_store(index)
 
@@ -376,45 +360,23 @@ def routed_filter_counts_mesh(
     qry_p = np.full((qb, fl), 0xFFFFFFFF, dtype=np.uint32)
     qry_p[:q] = query_sk
 
-    from repro.kernels import filter_kernel
-
-    fused_capable = bk.fused or bk.gather
-    want_fused = fused_capable and (
-        max(-(-n_tables // 128) * 128, 128) <= filter_kernel.FUSED_MAX_TABLES
-    )
-    demoted = bool(index._mesh_filter_cache.get("__demoted__", False))
-    impls = ["xla"] if (demoted or not want_fused) else ["fused", "xla"]
+    key = (pad_store, pad_items, qb, q, fl, n_tables, impl)
+    fn = index._mesh_filter_cache.get(key)
+    if fn is None:
+        fn = _routed_local_counts_fn(
+            mesh, row_axes, pad_items, qb, q, n_tables, impl
+        )
+        index._mesh_filter_cache[key] = fn
     sharding = NamedSharding(mesh, P(row_axes))
-    args = (
-        store,
-        jax.device_put(rows_p, sharding),
-        jax.device_put(seg_p, sharding),
-        jax.device_put(elig_p, sharding),
-        jnp.asarray(qry_p),
+    return np.asarray(
+        fn(
+            store,
+            jax.device_put(rows_p, sharding),
+            jax.device_put(seg_p, sharding),
+            jax.device_put(elig_p, sharding),
+            jnp.asarray(qry_p),
+        )
     )
-    for impl in impls:
-        key = (pad_store, pad_items, qb, q, fl, n_tables, impl)
-        fn = index._mesh_filter_cache.get(key)
-        if fn is None:
-            fn = _routed_local_counts_fn(
-                row_axes, n_shards, pad_store, pad_items, qb, q, fl,
-                n_tables, impl,
-            )(mesh)
-            index._mesh_filter_cache[key] = fn
-        try:
-            counts = np.asarray(fn(*args))
-            return counts, fused_capable and impl != "fused"
-        except Exception:  # pragma: no cover - backend-dependent compile path
-            if impl == "xla":
-                raise
-            _LOG.debug(
-                "routed mesh filter: fused shard body failed to compile on"
-                " %s — demoting to the XLA shard body",
-                jax.default_backend(), exc_info=True,
-            )
-            index._mesh_filter_cache["__demoted__"] = True
-            demoted = True
-    raise AssertionError("unreachable")  # pragma: no cover
 
 
 # ---------------------------------------------------------------------------

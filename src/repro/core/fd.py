@@ -172,6 +172,8 @@ def fds_from_counts(
         stats.gather_bytes_saved += pc.gather_saved
         stats.shard_launches += pc.route_launches
         stats.route_bytes_merged += pc.route_bytes
+        stats.gather_demotions += pc.gather_demotions
+        stats.shard_gather_demotions += pc.shard_gather_demotions
     else:
         stats.filter_matrix_bytes += n_items * pc.group_keys
         if pc.hits_host:

@@ -350,21 +350,21 @@ class MateIndex:
         return self._mutations
 
     def device_store(self):
-        """Device-resident per-row superkey store: uint32[total_rows, lanes].
+        """Device-resident superkey store (``kernels.ops.DeviceStore``, the
+        gather kernel's packed layout).
 
         The gather-fused filter backend DMA-gathers candidate rows from this
-        array inside the kernel, so it must track every §5.4 mutation:
+        store inside the kernel, so it must track every §5.4 mutation:
         the upload is re-done (lazily, on next access) whenever
         ``mutation_epoch`` moved past the epoch the resident copy was taken
         at — in-place superkey edits (``delete_table`` zeroing,
         ``update_cell`` re-hash) bump the epoch too, so a stale device copy
-        can never be served.  Rows stay row-major (each row's lanes
-        contiguous) — the layout the kernel's per-row DMA descriptors need.
+        can never be served.
         """
         if self._device_store is None or self._device_store_epoch != self._mutations:
-            import jax.numpy as jnp
+            from repro.kernels import ops
 
-            self._device_store = jnp.asarray(self.superkeys)
+            self._device_store = ops.device_store(self.superkeys)
             self._device_store_epoch = self._mutations
         return self._device_store
 

@@ -135,13 +135,9 @@ class MateShard:
         when (and only when) THIS shard's mutation epoch moved — the
         per-shard counterpart of ``MateIndex.device_store``."""
         if self._store is None or self._store_epoch != self._mutations:
-            import jax
-            import jax.numpy as jnp
+            from repro.kernels import ops
 
-            arr = jnp.asarray(self.superkeys)
-            if self.device is not None:
-                arr = jax.device_put(arr, self.device)
-            self._store = arr
+            self._store = ops.device_store(self.superkeys, self.device)
             self._store_epoch = self._mutations
         return self._store
 
@@ -191,12 +187,9 @@ class ShardedMateIndex:
         bounds = table_aligned_bounds(corpus.row_base, n_shards)
         table_bounds = np.searchsorted(corpus.row_base, bounds)
         if devices is None:
-            try:
-                import jax
+            import jax
 
-                devices = jax.devices()
-            except Exception:  # pragma: no cover - jax always importable here
-                devices = []
+            devices = jax.devices()
         self.shards: list[MateShard] = []
         for i in range(n_shards):
             lo, hi = int(bounds[i]), int(bounds[i + 1])
@@ -484,14 +477,11 @@ class ShardedMateIndex:
     ) -> np.ndarray:
         """One shard-local counts-only launch (gather-fused → fused → host)."""
         fl = query_sk.shape[1]
-        if (
-            bk.gather
-            and n_tables <= ops._FUSED_MAX_TABLES
-            and ops.gather_store_fits(shard.superkeys)
-        ):
-            c = ops.gather_filter_table_counts(
-                shard.device_store(), local, query_sk, elig_s, seg_s,
-                n_tables, block_n=fused_block_n,
+        if bk.gather and ops.gather_store_fits(shard.superkeys):
+            _, c = ops.filter_hits_table_counts(
+                None, query_sk, elig_s, seg_s, n_tables, backend=bk,
+                fused_block_n=fused_block_n, store=shard.device_store(),
+                rows=local,
             )
             if stats is not None:
                 stats.filter_fused_launches += 1
@@ -499,23 +489,23 @@ class ShardedMateIndex:
             return c
         if bk.gather:
             _LOG.debug(
-                "routed shard %d: demoting fused-gather (tables=%d, store"
-                " %d bytes) to the host-gather fused launch",
-                shard.shard_id, n_tables, shard.superkeys.nbytes,
+                "routed shard %d: demoting fused-gather (store %d bytes) to"
+                " the host-gather fused launch",
+                shard.shard_id, shard.superkeys.nbytes,
             )
             if stats is not None:
                 stats.shard_gather_demotions += 1
         row_sk = shard.superkeys[local][:, :fl]
-        if (bk.fused or bk.gather) and n_tables <= ops._FUSED_MAX_TABLES:
-            c = ops.filter_table_counts(
-                row_sk, query_sk, elig_s, seg_s, n_tables,
-                block_n=fused_block_n,
+        if bk.fused:
+            _, c = ops.filter_hits_table_counts(
+                row_sk, query_sk, elig_s, seg_s, n_tables, backend="fused",
+                fused_block_n=fused_block_n,
             )
             if stats is not None:
                 stats.filter_fused_launches += 1
             return c
-        # composed/host backends (and the over-cap fallback): counts-only by
-        # construction — the shard-local matrix never leaves the shard.
+        # composed/host backends: counts-only by construction — the
+        # shard-local matrix never leaves the shard.
         hits = ops.subsume_np(row_sk, query_sk) & np.asarray(elig_s, dtype=bool)
         return np.bincount(
             np.asarray(seg_s, dtype=np.int64),
@@ -529,15 +519,13 @@ class ShardedMateIndex:
         """Mesh mode: ONE shard_map launch, per-shard filter + psum merge."""
         from repro.core import distributed
 
-        counts, demoted = distributed.routed_filter_counts_mesh(
+        counts = distributed.routed_filter_counts_mesh(
             self, rows, query_sk, elig, seg_ids, n_tables, bk
         )
         if stats is not None:
             stats.shard_launches += self.n_shards
             stats.route_bytes_merged += int(counts.nbytes) * self.n_shards
-            if demoted:
-                stats.shard_gather_demotions += self.n_shards
-            else:
+            if bk.fused:
                 stats.filter_fused_launches += self.n_shards
         return counts
 

@@ -250,6 +250,8 @@ class SessionStats:
     route_bytes_merged: int = 0  # cross-shard count-merge bytes (the ONLY
     # bytes that cross a shard boundary on the routed filter path)
     shard_gather_demotions: int = 0  # shard launches demoted off gather-fused
+    gather_demotions: int = 0  # launches demoted off gather-fused (store
+    # over the device budget)
     # ranking-subsystem counters (``core.profiles`` / ``core.ranking``):
     tables_gated: int = 0  # candidate tables the profile gate dropped
     gate_bytes_saved: int = 0  # superkey bytes the gate kept out of filters

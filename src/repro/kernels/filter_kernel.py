@@ -9,10 +9,15 @@ scatter-accumulated over the CSR table ids — the reduction happens in VMEM,
 the n×q matrix never reaches HBM, which is what makes the filter
 memory-roofline-optimal: 16 bytes read per row, 4 bytes written per table).
 
-Layout note: super keys live in HBM as ``uint32[n, lanes]``; lanes is tiny
-(4 for 128-bit hashes) and would be a terrible minor-most dim for the 8×128
-VREG tiling, so the wrappers in ops.py transpose to ``[lanes, n]`` before the
-call — each lane row is then a well-formed 128-aligned vector.
+Layout note: super keys live on the host as ``uint32[n, lanes]``; lanes is
+tiny (4 for 128-bit hashes) and would be a terrible minor-most dim for the
+8×128 VREG tiling, so the wrappers in ops.py transpose candidate blocks to
+``[lanes, n]`` before the call — each lane row is then a well-formed
+128-aligned vector.  The gather kernel's device-resident store is instead
+packed into whole 128-lane lines (see ``_gather_counts_kernel``).  Per-row
+vectors (table ids, row offsets) travel as ``[1, n]`` rows and become
+``[bn, 1]`` columns by an int32 reshape inside the kernels: Mosaic cannot
+reshape a bool vector into a column.
 """
 
 from __future__ import annotations
@@ -113,6 +118,48 @@ def filter_match(
     )(row_sk_t, query_sk_t)
 
 
+def _scatter_counts(acc, seg, counts_ref, first, mode: str = "sum"):
+    """Row-reduce a masked [bn, bq] hit tile and scatter it into per-table
+    counts: the one-hot f32 matvec shared by both fused kernels.
+
+    ``seg`` is the int32[bn, 1] table-id column (-1 matches no iota column,
+    so padding rows contribute 0).  The one-hot [bn, tb] is contracted over
+    its row axis on the MXU; f32 accumulation is exact here (per-step
+    partials are bounded by bn·bq « 2^24)."""
+    per_row = jnp.sum(acc.astype(jnp.int32), axis=1, keepdims=True)  # [bn, 1]
+    if mode == "any":
+        per_row = (per_row > 0).astype(jnp.int32)
+    bn, tb = acc.shape[0], counts_ref.shape[1]
+    onehot = seg == jax.lax.broadcasted_iota(jnp.int32, (bn, tb), 1)
+    partial = jax.lax.dot_general(
+        per_row.astype(jnp.float32),
+        onehot.astype(jnp.float32),
+        (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.int32)  # [1, tb]
+
+    @pl.when(first)
+    def _init_counts():
+        counts_ref[...] = partial
+
+    @pl.when(jnp.logical_not(first))
+    def _accum_counts():
+        counts_ref[...] += partial
+
+
+def _mask_tile(acc, elig_ref, seg, j, n_queries: int):
+    """Eligibility, padded-query-column and padding-row masks of a hit tile.
+
+    Padded query columns (col id ≥ n_queries) carry all-ones super keys that
+    match nothing EXCEPT saturated (all-ones) row super keys, which would
+    otherwise be overcounted when no eligibility mask zero-pads them."""
+    if elig_ref is not None:
+        acc = acc & (elig_ref[...] != 0)
+    bn, bq = acc.shape
+    col = j * bq + jax.lax.broadcasted_iota(jnp.int32, (bn, bq), 1)
+    return acc & (col < n_queries) & (seg >= 0)
+
+
 def _table_counts_kernel(
     *refs, lanes: int, mode: str, has_elig: bool, n_queries: int
 ):
@@ -125,18 +172,17 @@ def _table_counts_kernel(
       row_ref:    uint32[lanes, bn]   candidate-row super keys (transposed)
       query_ref:  uint32[lanes, bq]   query-key super keys (transposed)
       elig_ref:   int8[bn, bq]        eligibility (only when has_elig)
-      seg_ref:    int32[bn]           table index per row; -1 = padding row
-      counts_ref: int32[tb]           per-table counts (ONE block, all steps)
-      key_ref:    int32[bq]           per-key survivor counts
+      seg_ref:    int32[1, bn]        table index per row; -1 = padding row
+      counts_ref: int32[1, tb]        per-table counts (ONE block, all steps)
+      key_ref:    int32[1, bq]        per-key survivor counts
+
+    A [n, 1] seg operand would also compile, but XLA would relay it out
+    lane-padded (128× its bytes) before every launch.
 
     ``mode``: 'sum' counts eligible (row, key) hits per table (the engines'
     exact rule-2 bound); 'any' counts rows matching ≥1 key (the distributed
     filter's per-table semantics — requires a single query block, since
     per-block ORs cannot be summed across query blocks).
-
-    The scatter is a one-hot f32 matvec: seg ids broadcast-compared against
-    the table-id iota, then per_row @ onehot on the MXU.  f32 accumulation is
-    exact here (per-step partials are bounded by bn·bq « 2^24).
     """
     if has_elig:
         row_ref, query_ref, elig_ref, seg_ref, counts_ref, key_ref = refs
@@ -145,44 +191,19 @@ def _table_counts_kernel(
         elig_ref = None
     j = pl.program_id(0)  # query-block index
     i = pl.program_id(1)  # row-block index (inner grid axis → sequential)
+    bn = row_ref.shape[1]
     acc = None
     for lane in range(lanes):
-        r = row_ref[lane, :]  # [bn]
-        q = query_ref[lane, :]  # [bq]
-        ok = (q[None, :] & ~r[:, None]) == 0  # [bn, bq]
+        r = row_ref[lane, :][:, None]  # [bn, 1]
+        q = query_ref[lane : lane + 1, :]  # [1, bq]
+        ok = (q & ~r) == 0  # [bn, bq]
         acc = ok if acc is None else (acc & ok)
-    if elig_ref is not None:
-        acc = acc & (elig_ref[...] != 0)
-    # mask padded query columns (col id ≥ n_queries): their all-ones super
-    # keys match nothing EXCEPT saturated (all-ones) row super keys, which
-    # would otherwise be overcounted when no eligibility mask zero-pads them
-    bn_, bq_ = acc.shape
-    col = j * bq_ + jax.lax.broadcasted_iota(jnp.int32, (bn_, bq_), 1)
-    acc = acc & (col < n_queries)
-    seg = seg_ref[...]  # [bn]
-    acc = acc & (seg >= 0)[:, None]  # padding rows contribute nothing
-    acc_i32 = acc.astype(jnp.int32)
-    key_partial = jnp.sum(acc_i32, axis=0)  # [bq]
-    per_row = jnp.sum(acc_i32, axis=1)  # [bn]
-    if mode == "any":
-        per_row = (per_row > 0).astype(jnp.int32)
-    bn = per_row.shape[0]
-    tb = counts_ref.shape[0]
-    # one-hot scatter: -1 (padding) matches no iota column → contributes 0.
-    onehot = seg[:, None] == jax.lax.broadcasted_iota(jnp.int32, (bn, tb), 1)
-    partial = jnp.dot(
-        per_row.astype(jnp.float32)[None, :],
-        onehot.astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    )[0].astype(jnp.int32)  # [tb]
-
-    @pl.when(jnp.logical_and(i == 0, j == 0))
-    def _init_counts():
-        counts_ref[...] = partial
-
-    @pl.when(jnp.logical_or(i != 0, j != 0))
-    def _accum_counts():
-        counts_ref[...] += partial
+    seg = seg_ref[...].reshape(bn, 1)
+    acc = _mask_tile(acc, elig_ref, seg, j, n_queries)
+    key_partial = jnp.sum(acc.astype(jnp.int32), axis=0, keepdims=True)
+    _scatter_counts(
+        acc, seg, counts_ref, jnp.logical_and(i == 0, j == 0), mode
+    )
 
     @pl.when(i == 0)
     def _init_keys():
@@ -243,8 +264,8 @@ def filter_table_counts(
     if elig is not None:
         in_specs.append(pl.BlockSpec((block_n, block_q), lambda j, i: (i, j)))
         operands.append(elig)
-    in_specs.append(pl.BlockSpec((block_n,), lambda j, i: (i,)))
-    operands.append(seg_ids)
+    in_specs.append(pl.BlockSpec((1, block_n), lambda j, i: (0, i)))
+    operands.append(seg_ids.reshape(1, n))
     counts, key_counts = pl.pallas_call(
         functools.partial(
             _table_counts_kernel,
@@ -256,39 +277,50 @@ def filter_table_counts(
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((n_tables,), lambda j, i: (0,)),
-            pl.BlockSpec((block_q,), lambda j, i: (j,)),
+            pl.BlockSpec((1, n_tables), lambda j, i: (0, 0)),
+            pl.BlockSpec((1, block_q), lambda j, i: (0, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_tables,), jnp.int32),
-            jax.ShapeDtypeStruct((q,), jnp.int32),
+            jax.ShapeDtypeStruct((1, n_tables), jnp.int32),
+            jax.ShapeDtypeStruct((1, q), jnp.int32),
         ],
         interpret=interpret,
     )(*operands)
-    return counts, key_counts
+    return counts[0], key_counts[0]
+
+
+# the packed store's line width: one (8, 128)-tiled uint32 lane row
+STORE_LINE = 128
 
 
 def _gather_counts_kernel(
-    *refs, lanes: int, has_elig: bool, n_queries: int, block_n: int
+    *refs, lanes: int, store_lanes: int, has_elig: bool, n_queries: int,
+    block_n: int,
 ):
     """Gather-fused filter + segment-count: one launch from posting-list row
     offsets to per-table counts.
 
     The candidate rows' super keys are DMA-gathered from the device-resident
-    store (HBM, ``memory_space=ANY``) straight into a VMEM scratch tile using
-    the scalar-prefetched row offsets — the rows×lanes candidate block never
-    exists in HBM, and the host never gathers (or ships) it at all.  The
-    gathered tile then feeds the same subsume ∧ elig → row-sum → one-hot-MXU
-    scatter as ``_table_counts_kernel``.
+    PACKED store (HBM, ``memory_space=ANY``) straight into a VMEM scratch
+    tile — the rows×lanes candidate block never exists in HBM, and the host
+    never gathers (or ships) it at all.  The store is the row-major
+    superkey array reshaped to ``uint32[n_lines, 128]``: line ``r // per``
+    holds row ``r`` at columns ``(r % per)·store_lanes`` onward, with
+    ``per = 128 // store_lanes``.  Each DMA copies the one 512-byte line
+    holding its row (a DMA's minor extent must be the whole 128-lane tile
+    row); the row's lanes are then shifted to the tile's first columns by a
+    masked rotate-and-add fold, once per row block.
 
-    Refs (``rows_ref`` is the scalar-prefetch operand; has_elig sets arity):
-      rows_ref:   int32[n]            posting-list row offsets (SMEM)
-      store_ref:  uint32[N, lanes_s]  per-row super-key store (HBM/ANY)
+    Refs (has_elig sets arity):
+      rows_smem:  int32[1, bn]        row offsets (SMEM: DMA addresses)
+      rows_ref:   int32[1, bn]        the same offsets (VMEM: lane offsets)
+      store_ref:  uint32[n_lines, 128] packed super-key store (HBM/ANY)
       query_ref:  uint32[lanes, bq]   query-key super keys (transposed)
       elig_ref:   int8[bn, bq]        eligibility (only when has_elig)
-      seg_ref:    int32[bn]           table index per row; -1 = padding row
-      counts_ref: int32[tb]           per-table counts (ONE block, all steps)
-      row_vmem:   uint32[bn, lanes_s] gathered super-key scratch tile
+      seg_ref:    int32[1, bn]        table index per row; -1 = padding row
+      counts_ref: int32[1, tb]        per-table counts (ONE block, all steps)
+      line_vmem:  uint32[bn, 128]     gathered store lines
+      key_vmem:   int32[bn, 128]      each row's lanes in columns 0..lanes-1
       sem:        DMA semaphore for the gather copies
 
     Grid is (row blocks, query blocks) with the QUERY axis innermost, the
@@ -299,84 +331,76 @@ def _gather_counts_kernel(
     per query block — so it emits per-table counts alone ('sum' semantics).
 
     ``lanes`` is the number of lanes PROBED (== the query operand's lane
-    count).  It may be smaller than the store's lane count (the serving
-    tier's lane-prefix degrade): each row DMA still moves the full store row
-    — 16..64 contiguous bytes — but only the first ``lanes`` columns of the
-    scratch tile enter the subsumption test.
+    count).  It may be smaller than ``store_lanes`` (the serving tier's
+    lane-prefix degrade): only the first ``lanes`` of each row are picked.
     """
     if has_elig:
-        rows_ref, store_ref, query_ref, elig_ref, seg_ref, counts_ref = refs[:6]
-        row_vmem, sem = refs[6:]
+        rows_smem, rows_ref, store_ref, query_ref, elig_ref, seg_ref = refs[:6]
+        counts_ref, line_vmem, key_vmem, sem = refs[6:]
     else:
-        rows_ref, store_ref, query_ref, seg_ref, counts_ref = refs[:5]
+        rows_smem, rows_ref, store_ref, query_ref, seg_ref = refs[:5]
+        counts_ref, line_vmem, key_vmem, sem = refs[5:]
         elig_ref = None
-        row_vmem, sem = refs[5:]
     i = pl.program_id(0)  # row-block index (outer)
     j = pl.program_id(1)  # query-block index (inner → scratch reuse across j)
+    per_line = STORE_LINE // store_lanes  # rows per line, a power of two
+    slot_bits = per_line.bit_length() - 1
+    lane_bits = store_lanes.bit_length() - 1
+
+    def _copy(r):
+        line = rows_smem[0, r] >> slot_bits
+        return pltpu.make_async_copy(
+            store_ref.at[pl.ds(line, 1)], line_vmem.at[pl.ds(r, 1)], sem
+        )
 
     @pl.when(j == 0)
     def _gather():
-        # one DMA per candidate row: store rows are contiguous [lanes_s]
-        # uint32 runs, so each descriptor moves one aligned 16..64-byte line.
-        # All copies are issued back-to-back, then drained — the per-row
+        # all copies are issued back-to-back, then drained — the per-row
         # latency overlaps across the outstanding queue.
         def _start(r, _):
-            idx = rows_ref[i * block_n + r]
-            pltpu.make_async_copy(
-                store_ref.at[pl.ds(idx, 1)], row_vmem.at[pl.ds(r, 1)], sem
-            ).start()
+            _copy(r).start()
             return 0
 
         jax.lax.fori_loop(0, block_n, _start, 0)
 
         def _wait(r, _):
-            idx = rows_ref[i * block_n + r]
-            pltpu.make_async_copy(
-                store_ref.at[pl.ds(idx, 1)], row_vmem.at[pl.ds(r, 1)], sem
-            ).wait()
+            _copy(r).wait()
             return 0
 
         jax.lax.fori_loop(0, block_n, _wait, 0)
+        # keep each row's own slot of its line, then fold the line onto its
+        # first store_lanes columns: summing all its rotations by multiples
+        # of store_lanes adds only zeros to the kept slot.  int32 views:
+        # Mosaic rotates and reduces no unsigned type, and the subsumption
+        # test below is bitwise, so it reads the same bits either way.
+        lines = jax.lax.bitcast_convert_type(line_vmem[...], jnp.int32)
+        slot = (rows_ref[...] & (per_line - 1)).reshape(block_n, 1)
+        col = jax.lax.broadcasted_iota(jnp.int32, lines.shape, 1)
+        keys = jnp.where((col >> lane_bits) == slot, lines, 0)
+        step = STORE_LINE // 2
+        while step >= store_lanes:
+            keys = keys + pltpu.roll(keys, step, 1)
+            step //= 2
+        key_vmem[...] = keys
 
     acc = None
     for lane in range(lanes):
-        r = row_vmem[:, lane]  # [bn]
-        q = query_ref[lane, :]  # [bq]
-        ok = (q[None, :] & ~r[:, None]) == 0  # [bn, bq]
+        r = key_vmem[:, lane : lane + 1]  # [bn, 1]
+        q = jax.lax.bitcast_convert_type(
+            query_ref[lane : lane + 1, :], jnp.int32
+        )  # [1, bq]
+        ok = (q & ~r) == 0  # [bn, bq]
         acc = ok if acc is None else (acc & ok)
-    if elig_ref is not None:
-        acc = acc & (elig_ref[...] != 0)
-    # mask padded query columns — same phantom-column guard as the non-gather
-    # fused kernel (saturated store rows would otherwise count them).
-    bn_, bq_ = acc.shape
-    col = j * bq_ + jax.lax.broadcasted_iota(jnp.int32, (bn_, bq_), 1)
-    acc = acc & (col < n_queries)
-    seg = seg_ref[...]  # [bn]
-    acc = acc & (seg >= 0)[:, None]  # padding rows contribute nothing
-    per_row = jnp.sum(acc.astype(jnp.int32), axis=1)  # [bn]
-    tb = counts_ref.shape[0]
-    onehot = seg[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (block_n, tb), 1
-    )
-    partial = jnp.dot(
-        per_row.astype(jnp.float32)[None, :],
-        onehot.astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    )[0].astype(jnp.int32)  # [tb]
-
-    @pl.when(jnp.logical_and(i == 0, j == 0))
-    def _init_counts():
-        counts_ref[...] = partial
-
-    @pl.when(jnp.logical_or(i != 0, j != 0))
-    def _accum_counts():
-        counts_ref[...] += partial
+    seg = seg_ref[...].reshape(block_n, 1)
+    acc = _mask_tile(acc, elig_ref, seg, j, n_queries)
+    _scatter_counts(acc, seg, counts_ref, jnp.logical_and(i == 0, j == 0))
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "n_tables", "n_queries", "block_n", "block_q", "interpret"
+        "store_lanes", "n_tables", "n_queries", "block_n", "block_q",
+        "interpret",
     ),
 )
 def gather_filter_table_counts(
@@ -386,6 +410,7 @@ def gather_filter_table_counts(
     elig: jnp.ndarray | None,
     seg_ids: jnp.ndarray,
     *,
+    store_lanes: int,
     n_tables: int,
     n_queries: int | None = None,
     block_n: int = DEFAULT_BLOCK_N,
@@ -394,20 +419,21 @@ def gather_filter_table_counts(
 ) -> jnp.ndarray:
     """Gather-fused filter + per-table segment count.
 
-    One launch from posting-list offsets to counts: ``rows`` (the CSR
-    candidate row ids) is scalar-prefetched, and each grid step DMA-gathers
-    its row block of ``store`` into VMEM before the fused subsume ∧ elig +
-    reduce + scatter — the gathered rows×lanes block never touches HBM.
+    One launch from posting-list offsets to counts: each grid step
+    DMA-gathers its row block's store lines into VMEM before the fused
+    subsume ∧ elig + reduce + scatter — the gathered rows×lanes block never
+    touches HBM.  The offsets reach SMEM one [1, block_n] block per grid
+    step, so no launch-sized operand has to fit in SMEM.
 
     Args:
-      rows:       int32[n] row offsets into ``store`` (n divisible by
+      rows:       int32[n] row offsets into the store (n divisible by
                   block_n; padding offsets must be valid, e.g. 0, and carry
                   seg id -1).
-      store:      uint32[N, lanes_s] device-resident super-key store,
-                  ROW-major (each row's lanes contiguous, one DMA line).
+      store:      uint32[n_lines, 128] packed super-key store (see
+                  ``_gather_counts_kernel``), ``store_lanes`` lanes per row.
       query_sk_t: uint32[lanes, q] transposed query super keys (q divisible
-                  by block_q); ``lanes <= lanes_s`` — a strict prefix probes
-                  a lane-degraded filter over the full-width store.
+                  by block_q); ``lanes <= store_lanes`` — a strict prefix
+                  probes a lane-degraded filter over the full-width store.
       elig:       int8[n, q] eligibility, or None for all-eligible.
       seg_ids:    int32[n] table index per row (-1 for padding rows).
       n_tables:   padded table count tb (multiple of 128).
@@ -419,43 +445,46 @@ def gather_filter_table_counts(
     """
     lanes, q = query_sk_t.shape
     n = rows.shape[0]
-    assert lanes <= store.shape[1], (lanes, store.shape)
+    assert lanes <= store_lanes and STORE_LINE % store_lanes == 0
+    assert store.shape[1] == STORE_LINE, store.shape
     n_queries = q if n_queries is None else n_queries
     grid = (n // block_n, q // block_q)  # query axis INNER → scratch reuse
+    rows2 = rows.reshape(1, n)
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),  # store stays in HBM
-        pl.BlockSpec((lanes, block_q), lambda i, j, rows_ref: (0, j)),
+        pl.BlockSpec(
+            (1, block_n), lambda i, j: (0, i), memory_space=pltpu.SMEM
+        ),
+        pl.BlockSpec((1, block_n), lambda i, j: (0, i)),
+        pl.BlockSpec(memory_space=pl.ANY),  # store stays in HBM
+        pl.BlockSpec((lanes, block_q), lambda i, j: (0, j)),
     ]
-    operands = [store, query_sk_t]
+    operands = [rows2, rows2, store, query_sk_t]
     if elig is not None:
-        in_specs.append(
-            pl.BlockSpec((block_n, block_q), lambda i, j, rows_ref: (i, j))
-        )
+        in_specs.append(pl.BlockSpec((block_n, block_q), lambda i, j: (i, j)))
         operands.append(elig)
-    in_specs.append(pl.BlockSpec((block_n,), lambda i, j, rows_ref: (i,)))
-    operands.append(seg_ids)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((n_tables,), lambda i, j, rows_ref: (0,)),
-        scratch_shapes=[
-            pltpu.VMEM((block_n, store.shape[1]), jnp.uint32),
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    return pl.pallas_call(
+    in_specs.append(pl.BlockSpec((1, block_n), lambda i, j: (0, i)))
+    operands.append(seg_ids.reshape(1, n))
+    counts = pl.pallas_call(
         functools.partial(
             _gather_counts_kernel,
             lanes=lanes,
+            store_lanes=store_lanes,
             has_elig=elig is not None,
             n_queries=n_queries,
             block_n=block_n,
         ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_tables,), jnp.int32),
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, n_tables), lambda i, j: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, n_tables), jnp.int32),
+        scratch_shapes=[
+            pltpu.VMEM((block_n, STORE_LINE), jnp.uint32),
+            pltpu.VMEM((block_n, STORE_LINE), jnp.int32),
+            pltpu.SemaphoreType.DMA,
+        ],
         interpret=interpret,
-    )(rows, *operands)
+    )(*operands)
+    return counts[0]
 
 
 @functools.partial(

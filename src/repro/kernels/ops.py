@@ -8,6 +8,7 @@ the kernels run everywhere; on TPU backends the real Mosaic path is used).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -113,7 +114,7 @@ def xash_values_mesh(
         return out
     sharding = NamedSharding(mesh, P(row_axes))
     hash_fn = jax.jit(
-        distributed.shard_map_compat(
+        jax.shard_map(
             lambda e: xash_lib.xash(e, cfg),
             mesh=mesh,
             in_specs=P(row_axes),
@@ -205,9 +206,18 @@ def subsume_np(row_sk: np.ndarray, query_sk: np.ndarray) -> np.ndarray:
     The single definition of the filter predicate outside the kernels — the
     engines' numpy paths route here so the semantics can't silently diverge.
     """
-    rows = np.asarray(row_sk, dtype=np.uint32)
-    qry = np.asarray(query_sk, dtype=np.uint32)
-    return np.all((qry[None, :, :] & ~rows[:, None, :]) == 0, axis=-1)
+    rows = np.ascontiguousarray(row_sk, dtype=np.uint32)
+    qry = np.ascontiguousarray(query_sk, dtype=np.uint32)
+    if rows.shape[1] % 2 == 0:
+        # lane pairs as uint64: the test is bitwise, so the pairs read the
+        # same bits in half the passes
+        rows, qry = rows.view(np.uint64), qry.view(np.uint64)
+    # one [n, q] pass per lane: no [n, q, lanes] temporary
+    not_rows = ~rows
+    ok = (qry[None, :, 0] & not_rows[:, 0, None]) == 0
+    for lane in range(1, rows.shape[1]):
+        ok &= (qry[None, :, lane] & not_rows[:, lane, None]) == 0
+    return ok
 
 
 # CPU fallback pads each dim up to a power-of-two bucket so XLA compiles
@@ -315,8 +325,8 @@ def _combine_counts(match, elig, seg, *, num_segments: int):
 
 
 # above this table count the fused one-hot tile would blow VMEM even at the
-# minimum row block, and the composed path wins anyway (readback is already
-# counts-dominated at that scale) — see filter_kernel.fused_block_n
+# minimum row block (see filter_kernel.fused_block_n): wider launches split
+# into table_chunks
 _FUSED_MAX_TABLES = filter_kernel.FUSED_MAX_TABLES
 
 
@@ -404,8 +414,68 @@ def gather_store_fits(superkeys: np.ndarray | jnp.ndarray) -> bool:
     return superkeys.nbytes <= GATHER_STORE_MAX_BYTES
 
 
+class DeviceStore(NamedTuple):
+    """A device-resident superkey store in the gather kernel's packed layout.
+
+    ``lines`` is the row-major ``uint32[n_rows, lanes]`` superkey array
+    reshaped to ``uint32[n_lines, 128]`` (zero-padded to whole (8, 128)
+    tiles): its HBM footprint is the logical ``lanes × 4`` bytes per row.
+    A ``[n_rows, lanes]`` operand would instead be relaid out lane-padded
+    to 128 lanes for the kernel (32× the logical bytes at 128 bits)."""
+
+    lines: jax.Array  # uint32[n_lines, 128]
+    lanes: int  # uint32 lanes per superkey row
+    n_rows: int
+
+
+def device_store(superkeys: np.ndarray, device=None) -> DeviceStore:
+    """Pack ``uint32[n_rows, lanes]`` superkeys into a ``DeviceStore`` and
+    upload it (to ``device`` when given, else the default device)."""
+    superkeys = np.asarray(superkeys, dtype=np.uint32)
+    n, lanes = superkeys.shape
+    line = filter_kernel.STORE_LINE
+    n_lines = max(-(-(n * lanes) // (8 * line)) * 8, 8)
+    flat = np.zeros(n_lines * line, dtype=np.uint32)
+    flat[: n * lanes] = superkeys.reshape(-1)
+    return DeviceStore(
+        jax.device_put(flat.reshape(n_lines, line), device), lanes, n
+    )
+
+
+def table_chunks(seg_ids: np.ndarray, n_tables: int):
+    """Split one fused launch into launches of at most ``_FUSED_MAX_TABLES``
+    tables (the scatter tile's VMEM cap): yields ``(item slice, table lo,
+    table hi)``.  Every engine emits ascending seg ids (CSR table order), so
+    each table range is one contiguous item range."""
+    seg = np.asarray(seg_ids)
+    if n_tables <= _FUSED_MAX_TABLES:
+        yield slice(0, seg.shape[0]), 0, n_tables
+        return
+    if np.any(np.diff(seg) < 0):
+        raise ValueError("seg_ids must be ascending to split a launch by table")
+    for t_lo in range(0, n_tables, _FUSED_MAX_TABLES):
+        t_hi = min(t_lo + _FUSED_MAX_TABLES, n_tables)
+        i_lo, i_hi = np.searchsorted(seg, [t_lo, t_hi])
+        yield slice(int(i_lo), int(i_hi)), t_lo, t_hi
+
+
+def chunked_counts(launch, seg_ids, elig, n_tables: int, *per_item):
+    """Run ``launch(*per_item_slices, elig_slice, seg_slice, n)`` once per
+    ``table_chunks`` range and concatenate the per-table counts."""
+    counts = np.zeros(n_tables, dtype=np.int32)
+    seg = np.asarray(seg_ids)
+    for sl, t_lo, t_hi in table_chunks(seg, n_tables):
+        counts[t_lo:t_hi] = launch(
+            *(x[sl] for x in per_item),
+            None if elig is None else elig[sl],
+            seg[sl] - t_lo,
+            t_hi - t_lo,
+        )
+    return counts
+
+
 def gather_filter_table_counts(
-    store: jnp.ndarray,
+    store: DeviceStore,
     rows: np.ndarray,
     query_sk: np.ndarray | jnp.ndarray,
     elig: np.ndarray | None,
@@ -419,17 +489,18 @@ def gather_filter_table_counts(
     per-table counts out — ONE launch from CSR posting lists to counts.
 
     The composed path ships n×lanes gathered superkeys through HBM before the
-    filter ever runs; here the kernel scalar-prefetches the (ragged, padded)
-    row offsets and DMA-gathers each row block from the device-resident
-    ``store`` straight into VMEM, so the gathered block never exists in HBM
-    and the host ships n×4 offset bytes instead of n×lanes×4 key bytes.
+    filter ever runs; here the kernel takes the (ragged, padded) row offsets
+    and DMA-gathers each row block from the device-resident ``store``
+    straight into VMEM, so the gathered block never exists in HBM and the
+    host ships n×4 offset bytes instead of n×lanes×4 key bytes.
 
     Args:
-      store:    uint32[N, lanes_s] device-resident superkey store
-                (``MateIndex.device_store()``), row-major.
+      store:    packed device-resident superkey store (``device_store``,
+                ``MateIndex.device_store()``).
       rows:     int[n] row offsets into ``store`` (the CSR candidate rows).
-      query_sk: uint32[q, lanes] query-key super keys; ``lanes <= lanes_s``
-                probes a lane-prefix degrade over the full-width store.
+      query_sk: uint32[q, lanes] query-key super keys; ``lanes <=
+                store.lanes`` probes a lane-prefix degrade over the
+                full-width store.
       elig:     bool[n, q] eligibility per (item, key), or None.
       seg_ids:  int32[n] table index (0..n_tables) of each candidate item.
       n_tables: number of tables covered by this block.
@@ -438,7 +509,7 @@ def gather_filter_table_counts(
                 budget block, so it can only shrink the tile, never blow it.
     Returns:
       int32[n_tables] counts on the host — bit-identical to
-      ``filter_table_counts(store[rows][:, :lanes], ...)`` (mode='sum').
+      ``filter_table_counts(superkeys[rows][:, :lanes], ...)`` (mode='sum').
     """
     n, q = rows.shape[0], query_sk.shape[0]
     if n == 0 or q == 0 or n_tables == 0:
@@ -446,8 +517,8 @@ def gather_filter_table_counts(
     if n_tables > _FUSED_MAX_TABLES:
         raise ValueError(
             f"gather-fused scatter tile supports at most {_FUSED_MAX_TABLES}"
-            f" tables per launch, got {n_tables} — split the batch or use the"
-            " composed path"
+            f" tables per launch, got {n_tables} — split the launch with"
+            " table_chunks"
         )
     interpret = _on_cpu() if interpret is None else interpret
     nb = _bucket(n, _FALLBACK_MIN_N)
@@ -474,10 +545,11 @@ def gather_filter_table_counts(
         elig_p = jnp.asarray(elig_p)
     counts = filter_kernel.gather_filter_table_counts(
         jnp.asarray(rows_p),
-        store,
+        store.lines,
         jnp.asarray(qry_p).T,
         elig_p,
         jnp.asarray(seg_p),
+        store_lanes=store.lanes,
         n_tables=tb,
         n_queries=q,
         block_n=block_n,
@@ -497,7 +569,7 @@ def filter_hits_table_counts(
     use_device: bool = True,
     backend: Backend | str | None = None,
     fused_block_n: int | None = None,
-    store: jnp.ndarray | None = None,
+    store: DeviceStore | None = None,
     rows: np.ndarray | None = None,
 ) -> tuple[np.ndarray | jnp.ndarray | None, np.ndarray]:
     """Device-side inputs for the §6.2 bound checks: eligible filter hits plus
@@ -515,7 +587,8 @@ def filter_hits_table_counts(
       fused_block_n: optional row-block override for the fused launch.
       store:    device-resident superkey store for the ``fused-gather``
                 backend (``MateIndex.device_store()``); with ``rows`` set the
-                gather-fused launch replaces ``row_sk`` entirely.
+                gather-fused launch replaces ``row_sk`` entirely.  Without
+                it ``fused-gather`` runs the host-gather fused launch.
       rows:     int[n] store row offsets for the gather-fused launch.
     Returns:
       (hits, counts) — ``counts`` int32[n_tables] is the one per-batch host
@@ -525,8 +598,8 @@ def filter_hits_table_counts(
       ``hits`` is None: the match matrix was never produced at all — callers
       recompute the (few) surviving tables' slices on demand.  ``row_sk`` may
       be None when ``store``+``rows`` are given (the gather-fused contract:
-      the host never gathers the candidate superkeys); a demotion off the
-      gather path then materialises them from the device store.
+      the host never gathers the candidate superkeys).  Fused launches over
+      more tables than the scatter tile holds split into ``table_chunks``.
     """
     n = rows.shape[0] if row_sk is None else row_sk.shape[0]
     q = query_sk.shape[0]
@@ -535,25 +608,22 @@ def filter_hits_table_counts(
     if not use_device:
         backend = "numpy"
     backend = registry.resolve_backend(backend).name
-    if backend == "fused-gather":
-        if store is not None and rows is not None and n_tables <= _FUSED_MAX_TABLES:
-            counts = gather_filter_table_counts(
-                store, rows, query_sk, elig, seg_ids, n_tables,
-                block_n=fused_block_n,
-            )
-            return None, counts
-        # no device store (or the scatter tile would blow VMEM): demote to
-        # the host-gather fused launch, which shares the cap fallback below
-        backend = "fused"
-    if row_sk is None:
-        # demoted off the gather path without host superkeys: gather them
-        # from the device store (rare — cap overflow or store missing).
-        row_sk = np.asarray(store)[np.asarray(rows)][:, : query_sk.shape[1]]
-    if backend == "fused" and n_tables > _FUSED_MAX_TABLES:
-        backend = "pallas"  # scatter tile would blow VMEM; composed oracle
-    if backend == "fused":
-        counts = filter_table_counts(
-            row_sk, query_sk, elig, seg_ids, n_tables, block_n=fused_block_n
+    if backend == "fused-gather" and store is not None:
+        counts = chunked_counts(
+            lambda r, e, s, nt: gather_filter_table_counts(
+                store, r, query_sk, e, s, nt, block_n=fused_block_n
+            ),
+            seg_ids, elig, n_tables, np.asarray(rows),
+        )
+        return None, counts
+    if backend in ("fused", "fused-gather"):
+        # fused-gather without a store: the caller kept the store off the
+        # device (over budget) and counts that demotion
+        counts = chunked_counts(
+            lambda r, e, s, nt: filter_table_counts(
+                r, query_sk, e, s, nt, block_n=fused_block_n
+            ),
+            seg_ids, elig, n_tables, np.asarray(row_sk),
         )
         return None, counts
     if backend == "auto":

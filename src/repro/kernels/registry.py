@@ -12,9 +12,9 @@ distributed filter.  This module centralises all of it:
         explicit config  >  MATE_FILTER_BACKEND env var  >  platform default
 
     (platform default: ``fused-gather`` on TPU — the roofline path, demoting
-    to ``fused`` when the device superkey store is absent or over budget —
-    and ``auto`` everywhere else, where ``auto`` is the size-based numpy/XLA
-    split).
+    to ``fused``, counted in stats, when the device superkey store is over
+    budget — and ``auto`` everywhere else, where ``auto`` is the size-based
+    numpy/XLA split).
   * ``register_backend`` — the extension point; the built-in table covers
     the four §6.3 filter implementations plus ``auto``.
 
@@ -94,7 +94,7 @@ register_backend(BackendSpec(
 register_backend(BackendSpec(
     "fused-gather", "gather-fused Pallas kernel: DMA-gathers candidate rows"
     " from the device superkey store inside the fused counts-only launch"
-    " (demotes to 'fused' when the store is absent or over budget;"
+    " (demotes to 'fused', counted, when the store is over budget;"
     " interpret mode off-TPU)", fused=True, gather=True,
 ))
 register_backend(BackendSpec(
@@ -132,10 +132,8 @@ def resolve_backend(
     ``backend`` may be an already-resolved ``Backend`` (returned as-is), a
     registered name (source='config'), or None — in which case the
     ``MATE_FILTER_BACKEND`` env var applies (source='env') and, failing
-    that, the platform default (source='platform').  Unknown names raise;
-    an unknown env value is ignored (matching the historic dispatch, so a
-    typo'd env var degrades to the platform default instead of crashing
-    every launch).
+    that, the platform default (source='platform').  Unknown names raise,
+    from the config and from the env var alike.
     """
     if isinstance(backend, Backend):
         return backend
@@ -147,6 +145,11 @@ def resolve_backend(
             )
         return Backend(backend, source="config")
     env = os.environ.get(ENV_VAR, "").strip().lower()
-    if env in _REGISTRY:
-        return Backend(env, source="env")
-    return Backend(platform_default(platform), source="platform")
+    if not env:
+        return Backend(platform_default(platform), source="platform")
+    if env not in _REGISTRY:
+        raise ValueError(
+            f"unknown filter backend {env!r} in {ENV_VAR}; registered: "
+            f"{', '.join(_REGISTRY)}"
+        )
+    return Backend(env, source="env")

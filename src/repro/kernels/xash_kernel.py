@@ -83,9 +83,7 @@ def _superkey_kernel(enc_ref, rank_ref, out_ref, *, cfg: XashConfig, n_cols: int
     rank_row = rank_ref[0, :]  # [37]
 
     def body(c, acc):
-        cell = pl.load(
-            enc_ref, (slice(None), pl.dslice(c, 1), slice(None))
-        ).reshape(bn, enc_ref.shape[2])
+        cell = enc_ref[:, pl.ds(c, 1), :].reshape(bn, enc_ref.shape[2])
         return acc | _cell_bits(cell, rank_row, cfg)
 
     bits = jax.lax.fori_loop(

@@ -20,14 +20,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.models import params as P_
 from repro.models.config import ModelConfig
 
-# explicit Auto axis types appeared after jax 0.4.x; older Meshes are Auto-only
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
 
 def _axis_kw(n_axes: int) -> dict:
-    if _AXIS_TYPE is None:
-        return {}
-    return {"axis_types": (_AXIS_TYPE.Auto,) * n_axes}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 V5E = {

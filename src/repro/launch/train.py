@@ -61,7 +61,7 @@ def main(argv=None):
     param_sh = meshlib.param_shardings(specs, mesh)
 
     key = jax.random.PRNGKey(args.seed)
-    with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+    with jax.set_mesh(mesh):
         params = params_lib.materialize(specs, key)
         params = jax.tree.map(jax.device_put, params, param_sh)
         opt_state = opt.init_state(params, tcfg.adamw)

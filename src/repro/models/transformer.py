@@ -234,24 +234,6 @@ REMAT_POLICY = "full"  # 'full' | 'dots' (save matmul outputs: no re-gather
 # of FSDP weights in the backward pass, more activation memory) | 'none'
 
 
-@jax.custom_vjp
-def _act_barrier(h):
-    # optimization_barrier has no differentiation rule on older jax (0.4.x);
-    # gradients pass straight through (the barrier is an identity).
-    return jax.lax.optimization_barrier(h)
-
-
-def _act_barrier_fwd(h):
-    return _act_barrier(h), None
-
-
-def _act_barrier_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-_act_barrier.defvjp(_act_barrier_fwd, _act_barrier_bwd)
-
-
 def _remat_wrap(body, remat: bool):
     if not remat or REMAT_POLICY == "none":
         return body
@@ -269,7 +251,7 @@ def _scan_group(params_group, x, positions, cfg, plan: GroupPlan, *, remat: bool
         # barrier: stops XLA commuting convert(dynamic-slice(stack)) into
         # dynamic-slice(convert(stack)), which would materialise an f32 copy
         # of the whole saved-activation stack (2× activation memory).
-        h = _act_barrier(h)
+        h = jax.lax.optimization_barrier(h)
         h = layers.constrain_seq(h)
         for i, (mixer, ffn) in enumerate(plan.sublayers):
             window = cfg.sliding_window if mixer == "attn" else 0

@@ -20,18 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax.shard_map landed after 0.4.x; fall back to the experimental home,
-# which spells check_vma as check_rep
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _exp_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
-
 from repro.models import layers as L, transformer
 from repro.models.config import ModelConfig
 from repro.train.step import chunked_ce
@@ -85,7 +73,7 @@ def pipeline_loss_fn(
         return x
 
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(pspec, P(batch_axes, None), P(batch_axes, None)),
         out_specs=P(),

@@ -123,7 +123,8 @@ def test_fused_zero_query_and_empty_blocks():
 def test_fused_counts_large_table_counts():
     """Regression: when the VMEM budget shrinks block_n (tb > 1024), the
     block size must still divide the padded row count — a non-divisor grid
-    silently drops trailing rows.  Also pins the >cap composed fallback."""
+    silently drops trailing rows.  Also pins the >cap split into table
+    chunks (still counts-only, same counts)."""
     from repro.kernels import filter_kernel
 
     n, q, n_tables = 8192, 64, 1100  # tb=1152 → budget block_n < 1024
@@ -139,13 +140,15 @@ def test_fused_counts_large_table_counts():
         b = filter_kernel.fused_block_n(tb)
         assert b & (b - 1) == 0 and 128 <= b <= 1024
         assert b == 128 or b * tb <= filter_kernel.FUSED_ONEHOT_BUDGET
-    # above the cap the dispatch must fall back (hits non-None, same counts)
+    # above the cap the dispatch splits into table chunks (hits None, same
+    # counts)
     big = filter_kernel.FUSED_MAX_TABLES + 1
     seg_big = np.sort(RNG.integers(0, big, size=300)).astype(np.int32)
+    seg_big[-1] = big - 1  # the last chunk holds one table
     hits, counts = ops.filter_hits_table_counts(
         row_sk[:300], q_sk[:5], elig[:300, :5], seg_big, big, backend="fused"
     )
-    assert hits is not None
+    assert hits is None
     assert np.array_equal(
         counts, _oracle_counts(row_sk[:300], q_sk[:5], elig[:300, :5], seg_big, big)
     )
@@ -188,17 +191,17 @@ def test_fused_false_pins_composed_path(lake, monkeypatch):
 
 
 def test_fused_table_cap_fallback_accounting(lake, monkeypatch):
-    """Regression: when ops falls back to the composed path above the table
-    cap, engine stats must NOT claim the counts-only contract."""
+    """Above the table cap a fused launch splits into table chunks: results
+    stay bit-identical and the counts-only contract still holds."""
     corpus, index, query, q_cols = lake
-    monkeypatch.setattr(ops, "_FUSED_MAX_TABLES", 4)  # force the fallback
+    monkeypatch.setattr(ops, "_FUSED_MAX_TABLES", 4)  # force the split
     seq, _ = discovery.discover(index, query, q_cols, k=10)
     bat, st = discover_batched(index, query, q_cols, k=10, backend="fused")
     assert [(e.table_id, e.joinability) for e in bat] == [
         (e.table_id, e.joinability) for e in seq
     ]
-    assert st.filter_fused_launches == 0
-    assert st.filter_matrix_bytes > 0
+    assert st.filter_fused_launches > 0
+    assert st.filter_matrix_bytes == 0
 
 
 def test_fused_mode_any_matches_distributed_semantics():

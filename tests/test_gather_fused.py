@@ -86,7 +86,7 @@ def test_gather_counts_match_host_gather(bits, n, q, n_tables):
     store, rows, q_sk, elig, seg = _rand_case(lanes, n, q, n_tables, seed=bits + n)
     composed = ops.filter_table_counts(store[rows], q_sk, elig, seg, n_tables)
     gathered = ops.gather_filter_table_counts(
-        jnp.asarray(store), rows, q_sk, elig, seg, n_tables
+        ops.device_store(store), rows, q_sk, elig, seg, n_tables
     )
     assert np.array_equal(gathered, composed), (bits, n, q, n_tables)
     assert np.array_equal(
@@ -102,7 +102,7 @@ def test_gather_dispatch_counts_only_no_host_superkeys(bits):
     store, rows, q_sk, elig, seg = _rand_case(lanes, 420, 17, 7, seed=bits)
     hits, counts = ops.filter_hits_table_counts(
         None, q_sk, elig, seg, 7, backend="fused-gather",
-        store=jnp.asarray(store), rows=rows,
+        store=ops.device_store(store), rows=rows,
     )
     assert hits is None
     want = ops.filter_table_counts(store[rows], q_sk, elig, seg, 7)
@@ -120,13 +120,13 @@ def test_gather_lane_prefix_degrade_over_full_width_store():
             store[rows][:, :probe_lanes], q_sk, elig, seg, 11
         )
         gathered = ops.gather_filter_table_counts(
-            jnp.asarray(store), rows, q_sk, elig, seg, 11
+            ops.device_store(store), rows, q_sk, elig, seg, 11
         )
         assert np.array_equal(gathered, composed), probe_lanes
 
 
 def test_gather_zero_shapes_short_circuit():
-    store = jnp.asarray(RNG.integers(0, 2**32, size=(64, 4), dtype=np.uint32))
+    store = ops.device_store(RNG.integers(0, 2**32, size=(64, 4), dtype=np.uint32))
     zq = np.zeros((0, 4), dtype=np.uint32)
     assert ops.gather_filter_table_counts(
         store, np.zeros(0, np.int64), zq, None, np.zeros(0, np.int32), 5
@@ -141,7 +141,7 @@ def test_gather_zero_shapes_short_circuit():
 
 
 def test_gather_table_cap_raises_on_direct_call():
-    store = jnp.asarray(RNG.integers(0, 2**32, size=(64, 4), dtype=np.uint32))
+    store = ops.device_store(RNG.integers(0, 2**32, size=(64, 4), dtype=np.uint32))
     big = ops._FUSED_MAX_TABLES + 1
     with pytest.raises(ValueError, match="at most"):
         ops.gather_filter_table_counts(
@@ -273,26 +273,35 @@ def test_gather_zero_query_plan_is_safe():
 # §5.4 mutations: the device store must refresh on every epoch bump
 # ---------------------------------------------------------------------------
 
+def _store_rows(store):
+    """Unpack a packed ``ops.DeviceStore`` back to uint32[n_rows, lanes]."""
+    flat = np.asarray(store.lines).reshape(-1)
+    return flat[: store.n_rows * store.lanes].reshape(store.n_rows, store.lanes)
+
+
 def test_device_store_refreshes_on_epoch_bump():
     corpus = synthetic.make_corpus(synthetic.SyntheticSpec(n_tables=30, seed=8))
     index = MateIndex(corpus)
     s0 = index.device_store()
     assert s0 is index.device_store()  # cached within an epoch
-    assert np.array_equal(np.asarray(s0), index.superkeys)
+    assert np.array_equal(_store_rows(s0), index.superkeys)
     index.delete_table(0)  # in-place zeroing + epoch bump
     s1 = index.device_store()
     assert s1 is not s0
-    assert np.array_equal(np.asarray(s1), index.superkeys)
-    assert np.asarray(s1)[: int(corpus.row_base[1])].sum() == 0
+    assert np.array_equal(_store_rows(s1), index.superkeys)
+    assert _store_rows(s1)[: int(corpus.row_base[1])].sum() == 0
     index.update_cell(1, 0, 0, "mutated-value")  # in-place row rewrite
     s2 = index.device_store()
     assert s2 is not s1
-    assert np.array_equal(np.asarray(s2), index.superkeys)
+    assert np.array_equal(_store_rows(s2), index.superkeys)
     tid = index.insert_table([["a", "b"], ["c", "d"]])
     s3 = index.device_store()
-    assert s3.shape[0] == index.superkeys.shape[0] > s2.shape[0]
-    assert np.array_equal(np.asarray(s3), index.superkeys)
+    assert s3.n_rows == index.superkeys.shape[0] > s2.n_rows
+    assert np.array_equal(_store_rows(s3), index.superkeys)
     assert tid == len(index.corpus.tables) - 1
+    # packed layout: whole (8, 128) tiles, exactly the logical bytes + tail
+    assert s3.lines.shape[1] == 128 and s3.lines.shape[0] % 8 == 0
+    assert s3.lines.nbytes - index.superkeys.nbytes < 8 * 128 * 4
 
 
 def test_gather_bit_identical_across_mutations(lake):
@@ -334,12 +343,12 @@ def test_gather_store_budget_demotes_to_host_gather(lake, monkeypatch):
     ]
     assert st.gather_bytes_saved == 0
     assert st.filter_fused_launches > 0  # demoted to fused, not to composed
+    assert st.gather_demotions > 0
 
 
 def test_gather_table_cap_demotes_per_batch(lake, monkeypatch):
-    """Batches above the scatter-tile table cap fall off the gather path
-    (host gather + composed launch) — results stay bit-identical and the
-    stats stop claiming the counts-only contract."""
+    """Batches above the scatter-tile table cap split into table chunks and
+    stay on the gather path — results bit-identical, counts-only."""
     corpus, query, q_cols = lake
     index = MateIndex(corpus)
     seq, _ = discovery.discover(index, query, q_cols, k=10)
@@ -348,9 +357,10 @@ def test_gather_table_cap_demotes_per_batch(lake, monkeypatch):
     assert [(e.table_id, e.joinability, e.mapping) for e in bat] == [
         (e.table_id, e.joinability, e.mapping) for e in seq
     ]
-    assert st.gather_bytes_saved == 0
-    assert st.filter_fused_launches == 0
-    assert st.filter_matrix_bytes > 0
+    assert st.gather_bytes_saved > 0
+    assert st.filter_fused_launches > 0
+    assert st.filter_matrix_bytes == 0
+    assert st.gather_demotions == 0
 
 
 def test_gather_session_and_serving_inherit(lake):
@@ -403,6 +413,6 @@ def test_gather_property_bit_identity(bits, n, q, n_tables, seed, use_elig):
         elig = None
     composed = ops.filter_table_counts(store[rows], q_sk, elig, seg, n_tables)
     gathered = ops.gather_filter_table_counts(
-        jnp.asarray(store), rows, q_sk, elig, seg, n_tables
+        ops.device_store(store), rows, q_sk, elig, seg, n_tables
     )
     assert np.array_equal(gathered, composed)

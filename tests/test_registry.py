@@ -42,11 +42,13 @@ def test_resolved_backend_passes_through():
 def test_unknown_config_name_raises_unknown_env_degrades(monkeypatch):
     with pytest.raises(ValueError, match="unknown filter backend"):
         registry.resolve_backend("cuda")
-    # a typo'd env var must NOT crash every launch — it degrades to the
-    # platform default, matching the historic dispatch
+    # a typo'd env var raises too: silently serving the platform default
+    # would hide which filter path a run measured
     monkeypatch.setenv(ENV, "cudnn")
-    bk = registry.resolve_backend(None)
-    assert bk.source == "platform"
+    with pytest.raises(ValueError, match=ENV):
+        registry.resolve_backend(None)
+    monkeypatch.setenv(ENV, "")  # unset-equivalent: the platform default
+    assert registry.resolve_backend(None).source == "platform"
 
 
 def test_backend_properties():

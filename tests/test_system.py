@@ -69,7 +69,10 @@ def test_discovery_driver_sharded_build_subprocess():
         ],
         capture_output=True, text=True, timeout=600,
         cwd=__file__.rsplit("/", 2)[0],
-        env={**__import__("os").environ, "PYTHONPATH": "src"},
+        env={
+            **__import__("os").environ, "PYTHONPATH": "src",
+            "JAX_ENABLE_COMPILATION_CACHE": "false",
+        },
     )
     assert res.returncode == 0, res.stderr[-2000:]
     assert "build stats: shards=4 mesh={'data': 4}" in res.stdout, res.stdout
@@ -82,7 +85,10 @@ def test_discovery_driver_rank_flags_subprocess():
     count rank restores the exact engines_bit_identical comparison."""
     import os
 
-    env = {**os.environ, "PYTHONPATH": "src"}
+    env = {
+        **os.environ, "PYTHONPATH": "src",
+        "JAX_ENABLE_COMPILATION_CACHE": "false",
+    }
     cwd = __file__.rsplit("/", 2)[0]
     res = subprocess.run(
         [
