@@ -72,10 +72,12 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
+import time
 from collections import defaultdict
 
 import numpy as np
 
+from repro import telemetry
 from repro.core import discovery as seq
 from repro.core import ranking
 from repro.core.corpus import Table
@@ -124,6 +126,7 @@ def plan_query(
     init_mode: str = "cardinality",
     *,
     profile_gate: bool = False,
+    rid: int | None = None,
 ) -> QueryPlan:
     """Initialization phase (§6.1) in columnar form: one hash launch, one
     posting-list gather, one eligibility matrix.
@@ -134,40 +137,49 @@ def plan_query(
     any superkey is gathered or filtered: pure pruning, the verified top-k
     set is unchanged; ``stats.tables_gated`` / ``gate_bytes_saved`` count
     the work the filter launches never saw.  ``tables_fetched`` /
-    ``pl_items_total`` stay PRE-gate (what the posting lists produced)."""
-    stats = DiscoveryStats()
-    init_col = seq.init_column_selection(query, q_cols, init_mode, index)
-    init_idx = q_cols.index(init_col)
-    keys = [tuple(row[c] for c in q_cols) for row in query.cells]
-    distinct_keys = list(dict.fromkeys(keys))
-    q_sk = index.superkey_of_keys(distinct_keys)
+    ``pl_items_total`` stay PRE-gate (what the posting lists produced).
+    ``rid`` labels the ``plan.query`` span (the serving request's id)."""
+    with telemetry.span("plan.query", rid=rid):
+        stats = DiscoveryStats()
+        with telemetry.span("plan.init_column"):
+            init_col = seq.init_column_selection(query, q_cols, init_mode, index)
+            init_idx = q_cols.index(init_col)
+        with telemetry.span("plan.hash_keys"):
+            keys = [tuple(row[c] for c in q_cols) for row in query.cells]
+            distinct_keys = list(dict.fromkeys(keys))
+            q_sk = index.superkey_of_keys(distinct_keys)
 
-    values = list(dict.fromkeys(query.column(init_col)))
-    value_id = {v: i for i, v in enumerate(values)}
-    # bool[n_values, K]: key kid is probed against items of value v only if
-    # the key's init-column entry IS v (Alg. 1 matches per posting list).
-    elig_value = np.zeros((len(values), len(distinct_keys)), dtype=bool)
-    for kid, key in enumerate(distinct_keys):
-        elig_value[value_id[key[init_idx]], kid] = True
-
-    block = index.gather_candidates(values)
-    stats.pl_items_total = block.n_items
-    stats.tables_fetched = block.n_tables
-    if profile_gate and block.n_tables and distinct_keys:
-        keep = index.gate_candidates(distinct_keys, block.table_ids)
-        if not keep.all():
-            stats.tables_gated = int((~keep).sum())
-            n_before = block.n_items
-            block = _gate_block(block, keep)
-            # superkey lanes the filter launches now never gather/compare
-            stats.gate_bytes_saved = (
-                (n_before - block.n_items) * q_sk.shape[1] * 4
+        with telemetry.span("plan.gather_candidates"):
+            values = list(dict.fromkeys(query.column(init_col)))
+            block = index.gather_candidates(values)
+        stats.pl_items_total = block.n_items
+        stats.tables_fetched = block.n_tables
+        if profile_gate and block.n_tables and distinct_keys:
+            with telemetry.span("plan.profile_gate"):
+                keep = index.gate_candidates(distinct_keys, block.table_ids)
+                if not keep.all():
+                    stats.tables_gated = int((~keep).sum())
+                    n_before = block.n_items
+                    block = _gate_block(block, keep)
+                    # superkey lanes the filter launches now never gather/compare
+                    stats.gate_bytes_saved = (
+                        (n_before - block.n_items) * q_sk.shape[1] * 4
+                    )
+        with telemetry.span("plan.eligibility"):
+            value_id = {v: i for i, v in enumerate(values)}
+            # bool[n_values, K]: key kid is probed against items of value v
+            # only if the key's init-column entry IS v (Alg. 1 matches per
+            # posting list).
+            elig_value = np.zeros((len(values), len(distinct_keys)), dtype=bool)
+            for kid, key in enumerate(distinct_keys):
+                elig_value[value_id[key[init_idx]], kid] = True
+            elig = (
+                elig_value[block.value_idx]
+                if block.n_items
+                else np.zeros((0, len(distinct_keys)), dtype=bool)
             )
-    elig = (
-        elig_value[block.value_idx]
-        if block.n_items
-        else np.zeros((0, len(distinct_keys)), dtype=bool)
-    )
+        telemetry.count("items", block.n_items)
+        telemetry.count("tables", block.n_tables)
     return QueryPlan(query, q_cols, distinct_keys, q_sk, block, elig, stats)
 
 
@@ -329,6 +341,17 @@ def _score_tables(
     lazy = hits is None
     if lazy:
         assert elig is not None
+    # per-table clocks only while tracing: the slice re-gather and the exact
+    # verification, summed into the score.tables span
+    timed = telemetry.enabled()
+    clock = time.perf_counter_ns
+    regather_ns = exact_ns = 0
+    if timed:
+        before = (
+            stats.verified_tp + stats.verified_fp,
+            stats.tables_pruned_rule1 + stats.tables_pruned_rule2,
+            stats.tables_evaluated - stats.tables_pruned_rule2,
+        )
     device_hits = (not lazy) and not isinstance(hits, np.ndarray)
     if device_hits:
         bound0 = topk.bound() if topk.full else -1
@@ -353,6 +376,8 @@ def _score_tables(
         if topk.full and int(counts[t - t_start]) <= topk.bound():
             stats.tables_pruned_rule2 += 1
             continue
+        if timed:
+            t_a = clock()
         if lazy:
             rsk = (
                 row_sk[lo:hi]
@@ -365,8 +390,27 @@ def _score_tables(
             sub = np.asarray(hits[lo:hi])
             if device_hits:
                 stats.filter_readback_bytes += sub.size
+        if timed:
+            t_b = clock()
+            regather_ns += t_b - t_a
         joinability, mapping = _calculate_j(index, plan, rows[lo:hi], sub)
+        if timed:
+            exact_ns += clock() - t_b
         topk.offer(tid, joinability, mapping)
+    if timed:
+        telemetry.count("regather_s", regather_ns * 1e-9)
+        telemetry.count("exact_s", exact_ns * 1e-9)
+        telemetry.count(
+            "pairs_verified", stats.verified_tp + stats.verified_fp - before[0]
+        )
+        telemetry.count(
+            "tables_pruned",
+            stats.tables_pruned_rule1 + stats.tables_pruned_rule2 - before[1],
+        )
+        telemetry.count(
+            "tables_verified",
+            stats.tables_evaluated - stats.tables_pruned_rule2 - before[2],
+        )
 
 
 def discover_batched(
@@ -534,15 +578,17 @@ def discover_batched(
         stats.filter_passed += int(counts.sum())
         if rank == "quality":
             batch_ids = block.table_ids[start:stop]
-            sc = ranking.quality_scores(
-                index, batch_ids, np.asarray(counts),
-                len(plan.distinct_keys), q_sketch, stats=stats,
-            )
+            with telemetry.span("score.rank"):
+                sc = ranking.quality_scores(
+                    index, batch_ids, np.asarray(counts),
+                    len(plan.distinct_keys), q_sketch, stats=stats,
+                )
             scores.update(zip(batch_ids.tolist(), sc.tolist()))
-        _score_tables(
-            index, plan, topk, hits, counts, rows, start, stop, lo,
-            row_sk=row_sk, elig=elig, prefetch_frac=prefetch_frac,
-        )
+        with telemetry.span("score.tables"):
+            _score_tables(
+                index, plan, topk, hits, counts, rows, start, stop, lo,
+                row_sk=row_sk, elig=elig, prefetch_frac=prefetch_frac,
+            )
     return _ranked_entries(topk, rank, scores), stats
 
 
@@ -604,9 +650,11 @@ def plan_and_count(
     filter_lanes: int | None = None,
     fused_block_n: int | None = None,
     profile_gate: bool = False,
+    rids: list[int] | None = None,
 ) -> list[PlanCounts]:
     """Phase A of ``discover_many``: plan every request, then run the ONE
     shared filter launch and demux it into per-request ``PlanCounts``.
+    ``rids`` label each request's ``plan.query`` span (serving request ids).
 
     ``profile_gate=True`` applies the column-profile gate per plan (see
     ``plan_query``) before the shared launch is assembled, so gated tables
@@ -625,30 +673,34 @@ def plan_and_count(
     """
     bk = registry.resolve_backend(backend)
     plans = [
-        plan_query(index, q, q_cols, init_mode, profile_gate=profile_gate)
-        for q, q_cols in queries
+        plan_query(
+            index, q, q_cols, init_mode, profile_gate=profile_gate,
+            rid=None if rids is None else rids[i],
+        )
+        for i, (q, q_cols) in enumerate(queries)
     ]
     if not plans:
         return []
-    rows_all = np.concatenate([p.block.rows for p in plans])
-    q_all = np.concatenate([p.q_sk for p in plans])
-    # block-diagonal eligibility (a request's keys only probe its own
-    # candidate rows) + a global per-item table index for the one-pass
-    # per-table rule-1/2 count reduction.
-    elig_all = np.zeros((rows_all.shape[0], q_all.shape[0]), dtype=bool)
-    seg_all = np.zeros(rows_all.shape[0], dtype=np.int32)
-    r_off = k_off = 0
-    n_tables_all = 0
-    for p in plans:
-        ni, ki, ti = p.block.n_items, p.q_sk.shape[0], p.block.n_tables
-        elig_all[r_off : r_off + ni, k_off : k_off + ki] = p.elig
-        if ni:
-            seg_all[r_off : r_off + ni] = n_tables_all + _segment_ids(
-                p.block.table_ptr, 0, ti
-            )
-        r_off += ni
-        k_off += ki
-        n_tables_all += ti
+    with telemetry.span("filter.assemble"):
+        rows_all = np.concatenate([p.block.rows for p in plans])
+        q_all = np.concatenate([p.q_sk for p in plans])
+        # block-diagonal eligibility (a request's keys only probe its own
+        # candidate rows) + a global per-item table index for the one-pass
+        # per-table rule-1/2 count reduction.
+        elig_all = np.zeros((rows_all.shape[0], q_all.shape[0]), dtype=bool)
+        seg_all = np.zeros(rows_all.shape[0], dtype=np.int32)
+        r_off = k_off = 0
+        n_tables_all = 0
+        for p in plans:
+            ni, ki, ti = p.block.n_items, p.q_sk.shape[0], p.block.n_tables
+            elig_all[r_off : r_off + ni, k_off : k_off + ki] = p.elig
+            if ni:
+                seg_all[r_off : r_off + ni] = n_tables_all + _segment_ids(
+                    p.block.table_ptr, 0, ti
+                )
+            r_off += ni
+            k_off += ki
+            n_tables_all += ti
     full_lanes = index.cfg.lanes
     fl = full_lanes if filter_lanes is None else max(1, min(int(filter_lanes), full_lanes))
     q_f = q_all if fl == full_lanes else q_all[:, :fl]
@@ -801,21 +853,23 @@ def score_from_counts(
             stats.filter_readback_bytes += n_items * pc.group_keys
     scores: dict[int, float] = {}
     if rank == "quality" and block.n_tables:
-        q_sketch = ranking.query_sketch(index, plan.distinct_keys)
-        sc = ranking.quality_scores(
-            index, block.table_ids, np.asarray(pc.counts),
-            len(plan.distinct_keys), q_sketch, stats=stats,
-        )
-        scores = dict(zip(block.table_ids.tolist(), sc.tolist()))
+        with telemetry.span("score.rank"):
+            q_sketch = ranking.query_sketch(index, plan.distinct_keys)
+            sc = ranking.quality_scores(
+                index, block.table_ids, np.asarray(pc.counts),
+                len(plan.distinct_keys), q_sketch, stats=stats,
+            )
+            scores = dict(zip(block.table_ids.tolist(), sc.tolist()))
     topk = _TopK(k)
     # rule 1 (PL-desc suffix pruning) applies inside the range: the filter
     # already ran batched for every table, only verification work and
     # hit-slice readbacks (or fused recomputes) remain to be skipped.
-    _score_tables(
-        index, plan, topk, hits, pc.counts, block.rows, 0, block.n_tables, 0,
-        rule1=True, row_sk=pc.row_sk, elig=plan.elig,
-        prefetch_frac=prefetch_frac,
-    )
+    with telemetry.span("score.tables"):
+        _score_tables(
+            index, plan, topk, hits, pc.counts, block.rows, 0, block.n_tables, 0,
+            rule1=True, row_sk=pc.row_sk, elig=plan.elig,
+            prefetch_frac=prefetch_frac,
+        )
     return _ranked_entries(topk, rank, scores), stats
 
 

@@ -14,6 +14,7 @@ row's super key.
 from __future__ import annotations
 
 import dataclasses
+import time
 
 import numpy as np
 
@@ -40,6 +41,7 @@ class Table:
 
 class Corpus:
     def __init__(self, tables: list[Table], max_len: int = encoding.MAX_LEN):
+        t0 = time.perf_counter()
         self.tables = tables
         self.max_len = max_len
         self.max_cols = max((t.n_cols for t in tables), default=1)
@@ -68,6 +70,8 @@ class Corpus:
                     self.cell_value_ids[base + r, c] = vid
         self.unique_values = uniques
         self.unique_enc = encoding.encode_values(uniques, max_len)
+        # read by ``index.build_index`` into ``BuildStats.corpus_seconds``
+        self.build_seconds = time.perf_counter() - t0
 
     # -- lookups ------------------------------------------------------------
 

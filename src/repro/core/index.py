@@ -232,6 +232,9 @@ class BuildStats:
     profile_seconds: float = 0.0  # per-column ProfileStore pass (ranking)
     profile_bytes: int = 0  # ProfileStore footprint (all arrays)
     total_seconds: float = 0.0
+    # ``Corpus`` construction before the build (cell interning + encoding),
+    # as the corpus measured it; not part of total_seconds
+    corpus_seconds: float = 0.0
 
     @property
     def sharded(self) -> bool:
@@ -702,6 +705,7 @@ def build_index(
         bytes_hashed=int(corpus.unique_enc.size),
         shard_values=np.diff(distributed.shard_bounds(n_values, n_shards))
         .astype(int).tolist(),
+        corpus_seconds=corpus.build_seconds,
     )
     avg_w = corpus.avg_row_width()
 

@@ -665,6 +665,7 @@ def build_routed_index(
         bytes_hashed=int(corpus.unique_enc.size),
         shard_values=np.diff(distributed.shard_bounds(n_values, n_shards))
         .astype(int).tolist(),
+        corpus_seconds=corpus.build_seconds,
     )
 
     t0 = time.perf_counter()

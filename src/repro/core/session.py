@@ -210,17 +210,13 @@ class DiscoveryConfig:
 
 
 # DiscoveryStats counters ``SessionStats.absorb`` does NOT aggregate:
-# per-request plan shape (meaningless summed across requests) and the
-# per-launch lane width.  Every OTHER DiscoveryStats field is absorbed by
-# name — so adding a counter to DiscoveryStats without either mirroring it
-# on SessionStats or listing it here raises AttributeError on the first
-# absorb, instead of silently dropping it from session accounting (the
-# hand-patched-aggregation failure mode of PRs 7–8).
+# per-request posting-item counts and the per-launch lane width.  Every
+# OTHER DiscoveryStats field is absorbed by name — so adding a counter to
+# DiscoveryStats without either mirroring it on SessionStats or listing it
+# here raises AttributeError on the first absorb, instead of silently
+# dropping it from session accounting (the hand-patched-aggregation failure
+# mode of PRs 7–8).
 _NOT_AGGREGATED = frozenset({
-    "tables_fetched",
-    "tables_evaluated",
-    "tables_pruned_rule1",
-    "tables_pruned_rule2",
     "pl_items_total",
     "pl_items_checked",
     "filter_lanes",
@@ -237,6 +233,12 @@ class SessionStats:
     """Aggregate accounting across every request a session served."""
 
     requests: int = 0
+    # candidate tables across requests: fetched (pre-gate), evaluated, and
+    # pruned by rule 1 / rule 2 before verification
+    tables_fetched: int = 0
+    tables_evaluated: int = 0
+    tables_pruned_rule1: int = 0
+    tables_pruned_rule2: int = 0
     filter_checks: int = 0
     filter_passed: int = 0
     verified_tp: int = 0
@@ -411,12 +413,14 @@ class MateSession:
         queries: list[tuple[Table, list[int]]],
         *,
         filter_lanes: int | None = None,
+        rids: list[int] | None = None,
     ) -> list["batched_lib.PlanCounts"]:
         """Phase A of group discovery: the shared filter launch, demuxed per
         request (``core.batched.plan_and_count`` under this session's
         backend/config).  No stats are absorbed here — a request only counts
         when its PlanCounts is scored.  ``filter_lanes`` runs the launch at
-        a lane prefix (the serving tier's pressure-degrade path)."""
+        a lane prefix (the serving tier's pressure-degrade path); ``rids``
+        label the requests' planning spans (``repro.telemetry``)."""
         return batched_lib.plan_and_count(
             self.index,
             queries,
@@ -425,6 +429,7 @@ class MateSession:
             filter_lanes=filter_lanes,
             fused_block_n=self.config.fused_block_n,
             profile_gate=self.config.profile_gate,
+            rids=rids,
         )
 
     def score_from_counts(

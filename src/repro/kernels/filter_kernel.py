@@ -115,6 +115,7 @@ def filter_match(
         out_specs=pl.BlockSpec((block_n, block_q), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, q), jnp.int8),
         interpret=interpret,
+        name="filter_match",
     )(row_sk_t, query_sk_t)
 
 
@@ -285,6 +286,7 @@ def filter_table_counts(
             jax.ShapeDtypeStruct((1, q), jnp.int32),
         ],
         interpret=interpret,
+        name="filter_table_counts",
     )(*operands)
     return counts[0], key_counts[0]
 
@@ -483,6 +485,8 @@ def gather_filter_table_counts(
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
+        # the device trace and the benchmark find the kernel by this name
+        name="gather_filter_table_counts",
     )(*operands)
     return counts[0]
 
@@ -513,4 +517,5 @@ def filter_count(
         out_specs=pl.BlockSpec((block_q,), lambda j, i: (j,)),
         out_shape=jax.ShapeDtypeStruct((q,), jnp.int32),
         interpret=interpret,
+        name="filter_count",
     )(row_sk_t, query_sk_t)

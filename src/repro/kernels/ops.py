@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core import encoding
 from repro.core.xash import DEFAULT_CONFIG, XashConfig
 from repro.kernels import filter_kernel, registry, xash_kernel
@@ -260,6 +261,15 @@ def _check_fused_block_n(block_n: int) -> None:
         )
 
 
+def _count_upload(elig_p, *operands) -> None:
+    """Counters of the open ``filter.launch`` span: the padded operands sent
+    to the device (``h2d_bytes``) and the eligibility operand alone
+    (``elig_bytes``)."""
+    elig_bytes = 0 if elig_p is None else int(elig_p.nbytes)
+    telemetry.count("h2d_bytes", elig_bytes + sum(int(x.nbytes) for x in operands))
+    telemetry.count("elig_bytes", elig_bytes)
+
+
 def filter_match_auto(
     row_sk: np.ndarray | jnp.ndarray,
     query_sk: np.ndarray | jnp.ndarray,
@@ -375,31 +385,34 @@ def filter_table_counts(
         budget_n = min(budget_n, block_n)
     block_n = min(nb, budget_n)
     block_q = qb if mode == "any" else min(qb, filter_kernel.DEFAULT_BLOCK_Q)
-    rows_p = np.zeros((nb, row_sk.shape[1]), dtype=np.uint32)
-    rows_p[:n] = row_sk
-    # padded queries get all-ones super keys (subsumed by nothing)
-    qry_p = np.full((qb, query_sk.shape[1]), 0xFFFFFFFF, dtype=np.uint32)
-    qry_p[:q] = query_sk
-    seg_p = np.full(nb, -1, dtype=np.int32)  # padding rows scatter nowhere
-    seg_p[:n] = seg_ids
-    elig_p = None
-    if elig is not None:
-        elig_p = np.zeros((nb, qb), dtype=np.int8)
-        elig_p[:n, :q] = elig
-        elig_p = jnp.asarray(elig_p)
-    counts, _key_counts = filter_kernel.filter_table_counts(
-        jnp.asarray(rows_p).T,
-        jnp.asarray(qry_p).T,
-        elig_p,
-        jnp.asarray(seg_p),
-        n_tables=tb,
-        n_queries=q,
-        block_n=block_n,
-        block_q=block_q,
-        mode=mode,
-        interpret=interpret,
-    )
-    return np.asarray(counts)[:n_tables]
+    with telemetry.span("filter.pad"):
+        rows_p = np.zeros((nb, row_sk.shape[1]), dtype=np.uint32)
+        rows_p[:n] = row_sk
+        # padded queries get all-ones super keys (subsumed by nothing)
+        qry_p = np.full((qb, query_sk.shape[1]), 0xFFFFFFFF, dtype=np.uint32)
+        qry_p[:q] = query_sk
+        seg_p = np.full(nb, -1, dtype=np.int32)  # padding rows scatter nowhere
+        seg_p[:n] = seg_ids
+        elig_p = None
+        if elig is not None:
+            elig_p = np.zeros((nb, qb), dtype=np.int8)
+            elig_p[:n, :q] = elig
+    with telemetry.span("filter.upload"):
+        counts, _key_counts = filter_kernel.filter_table_counts(
+            jnp.asarray(rows_p).T,
+            jnp.asarray(qry_p).T,
+            None if elig_p is None else jnp.asarray(elig_p),
+            jnp.asarray(seg_p),
+            n_tables=tb,
+            n_queries=q,
+            block_n=block_n,
+            block_q=block_q,
+            mode=mode,
+            interpret=interpret,
+        )
+    _count_upload(elig_p, rows_p, qry_p, seg_p)
+    with telemetry.span("filter.readback"):
+        return np.asarray(counts)[:n_tables]
 
 
 # device superkey stores above this size stay host-resident and the
@@ -530,33 +543,36 @@ def gather_filter_table_counts(
         budget_n = min(budget_n, block_n)
     block_n = min(nb, budget_n)
     block_q = min(qb, filter_kernel.DEFAULT_BLOCK_Q)
-    # padding offsets point at row 0 (always valid); their seg id is -1 so
-    # they scatter nowhere regardless of what row 0's superkey matches.
-    rows_p = np.zeros(nb, dtype=np.int32)
-    rows_p[:n] = rows
-    qry_p = np.full((qb, query_sk.shape[1]), 0xFFFFFFFF, dtype=np.uint32)
-    qry_p[:q] = query_sk
-    seg_p = np.full(nb, -1, dtype=np.int32)
-    seg_p[:n] = seg_ids
-    elig_p = None
-    if elig is not None:
-        elig_p = np.zeros((nb, qb), dtype=np.int8)
-        elig_p[:n, :q] = elig
-        elig_p = jnp.asarray(elig_p)
-    counts = filter_kernel.gather_filter_table_counts(
-        jnp.asarray(rows_p),
-        store.lines,
-        jnp.asarray(qry_p).T,
-        elig_p,
-        jnp.asarray(seg_p),
-        store_lanes=store.lanes,
-        n_tables=tb,
-        n_queries=q,
-        block_n=block_n,
-        block_q=block_q,
-        interpret=interpret,
-    )
-    return np.asarray(counts)[:n_tables]
+    with telemetry.span("filter.pad"):
+        # padding offsets point at row 0 (always valid); their seg id is -1
+        # so they scatter nowhere regardless of what row 0's superkey matches.
+        rows_p = np.zeros(nb, dtype=np.int32)
+        rows_p[:n] = rows
+        qry_p = np.full((qb, query_sk.shape[1]), 0xFFFFFFFF, dtype=np.uint32)
+        qry_p[:q] = query_sk
+        seg_p = np.full(nb, -1, dtype=np.int32)
+        seg_p[:n] = seg_ids
+        elig_p = None
+        if elig is not None:
+            elig_p = np.zeros((nb, qb), dtype=np.int8)
+            elig_p[:n, :q] = elig
+    with telemetry.span("filter.upload"):
+        counts = filter_kernel.gather_filter_table_counts(
+            jnp.asarray(rows_p),
+            store.lines,
+            jnp.asarray(qry_p).T,
+            None if elig_p is None else jnp.asarray(elig_p),
+            jnp.asarray(seg_p),
+            store_lanes=store.lanes,
+            n_tables=tb,
+            n_queries=q,
+            block_n=block_n,
+            block_q=block_q,
+            interpret=interpret,
+        )
+    _count_upload(elig_p, rows_p, qry_p, seg_p)
+    with telemetry.span("filter.readback"):
+        return np.asarray(counts)[:n_tables]
 
 
 def filter_hits_table_counts(
@@ -601,76 +617,82 @@ def filter_hits_table_counts(
       the host never gathers the candidate superkeys).  Fused launches over
       more tables than the scatter tile holds split into ``table_chunks``.
     """
-    n = rows.shape[0] if row_sk is None else row_sk.shape[0]
-    q = query_sk.shape[0]
-    if n == 0 or q == 0 or n_tables == 0:
-        return np.zeros((n, q), dtype=bool), np.zeros(n_tables, dtype=np.int32)
-    if not use_device:
-        backend = "numpy"
-    backend = registry.resolve_backend(backend).name
-    if backend == "fused-gather" and store is not None:
-        counts = chunked_counts(
-            lambda r, e, s, nt: gather_filter_table_counts(
-                store, r, query_sk, e, s, nt, block_n=fused_block_n
-            ),
-            seg_ids, elig, n_tables, np.asarray(rows),
-        )
-        return None, counts
-    if backend in ("fused", "fused-gather"):
-        # fused-gather without a store: the caller kept the store off the
-        # device (over budget) and counts that demotion
-        counts = chunked_counts(
-            lambda r, e, s, nt: filter_table_counts(
-                r, query_sk, e, s, nt, block_n=fused_block_n
-            ),
-            seg_ids, elig, n_tables, np.asarray(row_sk),
-        )
-        return None, counts
-    if backend == "auto":
-        backend = "numpy" if n * q < _MIN_XLA_PROBES else "xla"
-    if backend == "numpy":
-        hits = subsume_np(row_sk, query_sk) & np.asarray(elig, dtype=bool)
-        counts = np.bincount(
-            np.asarray(seg_ids, dtype=np.int64),
-            weights=hits.sum(axis=1),
-            minlength=n_tables,
-        ).astype(np.int32)
-        return hits, counts[:n_tables]
-    # bucket every dim so XLA compiles O(few) distinct shapes; padded
-    # rows/queries have elig False, so their (arbitrary) super keys and the
-    # segment-0 padding of seg_ids contribute nothing to hits or counts.
-    nb = _bucket(n, _FALLBACK_MIN_N)
-    qb = _pow2_bucket(q, _FALLBACK_MIN_Q)
-    tb = _pow2_bucket(n_tables, 16)
-    rows_p = np.zeros((nb, row_sk.shape[1]), dtype=np.uint32)
-    rows_p[:n] = row_sk
-    qry_p = np.zeros((qb, query_sk.shape[1]), dtype=np.uint32)
-    qry_p[:q] = query_sk
-    elig_p = np.zeros((nb, qb), dtype=bool)
-    elig_p[:n, :q] = elig
-    seg_p = np.zeros(nb, dtype=np.int32)
-    seg_p[:n] = seg_ids
-    if backend == "pallas":
-        interpret = _on_cpu()
-        match = filter_kernel.filter_match(
-            jnp.asarray(rows_p).T,
-            jnp.asarray(qry_p).T,
-            block_n=min(nb, filter_kernel.DEFAULT_BLOCK_N),
-            block_q=min(qb, filter_kernel.DEFAULT_BLOCK_Q),
-            interpret=interpret,
-        )
-        hits, counts = _combine_counts(
-            match, jnp.asarray(elig_p), jnp.asarray(seg_p), num_segments=tb
-        )
-    else:
-        hits, counts = _hits_counts_block(
-            jnp.asarray(rows_p),
-            jnp.asarray(qry_p),
-            jnp.asarray(elig_p),
-            jnp.asarray(seg_p),
-            num_segments=tb,
-        )
-    return hits[:n, :q], np.asarray(counts)[:n_tables]
+    with telemetry.span("filter.launch"):
+        n = rows.shape[0] if row_sk is None else row_sk.shape[0]
+        q = query_sk.shape[0]
+        if n == 0 or q == 0 or n_tables == 0:
+            return np.zeros((n, q), dtype=bool), np.zeros(n_tables, dtype=np.int32)
+        if not use_device:
+            backend = "numpy"
+        backend = registry.resolve_backend(backend).name
+        if backend == "fused-gather" and store is not None:
+            counts = chunked_counts(
+                lambda r, e, s, nt: gather_filter_table_counts(
+                    store, r, query_sk, e, s, nt, block_n=fused_block_n
+                ),
+                seg_ids, elig, n_tables, np.asarray(rows),
+            )
+            return None, counts
+        if backend in ("fused", "fused-gather"):
+            # fused-gather without a store: the caller kept the store off the
+            # device (over budget) and counts that demotion
+            counts = chunked_counts(
+                lambda r, e, s, nt: filter_table_counts(
+                    r, query_sk, e, s, nt, block_n=fused_block_n
+                ),
+                seg_ids, elig, n_tables, np.asarray(row_sk),
+            )
+            return None, counts
+        if backend == "auto":
+            backend = "numpy" if n * q < _MIN_XLA_PROBES else "xla"
+        if backend == "numpy":
+            hits = subsume_np(row_sk, query_sk) & np.asarray(elig, dtype=bool)
+            counts = np.bincount(
+                np.asarray(seg_ids, dtype=np.int64),
+                weights=hits.sum(axis=1),
+                minlength=n_tables,
+            ).astype(np.int32)
+            return hits, counts[:n_tables]
+        # bucket every dim so XLA compiles O(few) distinct shapes; padded
+        # rows/queries have elig False, so their (arbitrary) super keys and the
+        # segment-0 padding of seg_ids contribute nothing to hits or counts.
+        nb = _bucket(n, _FALLBACK_MIN_N)
+        qb = _pow2_bucket(q, _FALLBACK_MIN_Q)
+        tb = _pow2_bucket(n_tables, 16)
+        with telemetry.span("filter.pad"):
+            rows_p = np.zeros((nb, row_sk.shape[1]), dtype=np.uint32)
+            rows_p[:n] = row_sk
+            qry_p = np.zeros((qb, query_sk.shape[1]), dtype=np.uint32)
+            qry_p[:q] = query_sk
+            elig_p = np.zeros((nb, qb), dtype=bool)
+            elig_p[:n, :q] = elig
+            seg_p = np.zeros(nb, dtype=np.int32)
+            seg_p[:n] = seg_ids
+        with telemetry.span("filter.upload"):
+            if backend == "pallas":
+                interpret = _on_cpu()
+                match = filter_kernel.filter_match(
+                    jnp.asarray(rows_p).T,
+                    jnp.asarray(qry_p).T,
+                    block_n=min(nb, filter_kernel.DEFAULT_BLOCK_N),
+                    block_q=min(qb, filter_kernel.DEFAULT_BLOCK_Q),
+                    interpret=interpret,
+                )
+                hits, counts = _combine_counts(
+                    match, jnp.asarray(elig_p), jnp.asarray(seg_p), num_segments=tb
+                )
+            else:
+                hits, counts = _hits_counts_block(
+                    jnp.asarray(rows_p),
+                    jnp.asarray(qry_p),
+                    jnp.asarray(elig_p),
+                    jnp.asarray(seg_p),
+                    num_segments=tb,
+                )
+        _count_upload(elig_p, rows_p, qry_p, seg_p)
+        with telemetry.span("filter.readback"):
+            counts = np.asarray(counts)[:n_tables]
+        return hits[:n, :q], counts
 
 
 def filter_count(

@@ -128,4 +128,5 @@ def xash_superkey(
         out_specs=pl.BlockSpec((cfg.lanes, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((cfg.lanes, n), jnp.uint32),
         interpret=interpret,
+        name="xash_superkey",
     )(enc, rank)

@@ -51,6 +51,7 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import itertools
 import time
 from concurrent.futures import Future
 from typing import Any
@@ -59,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core.batched import PlanCounts
 from repro.core.corpus import Table
 from repro.core.discovery import DiscoveryStats, TopKEntry
@@ -90,6 +92,7 @@ class DiscoveryRequest:
     q_cols: list[int]
     k: int = 10
     arrival: float = 0.0
+    rid: int = -1  # the engine's request id, carried by this request's spans
     results: list[TopKEntry] | None = None
     stats: DiscoveryStats | None = None
     future: Future = dataclasses.field(default_factory=Future, repr=False)
@@ -172,6 +175,7 @@ class DiscoveryEngine:
         self.bound_cache = (
             cache_lib.BoundCache(cfg.bound_cache) if cfg.bound_cache else None
         )
+        self._rids = itertools.count()
 
     @property
     def index(self) -> MateIndex:
@@ -209,6 +213,7 @@ class DiscoveryEngine:
             q_cols=q_cols,
             k=self.session.config.k if k is None else k,
             arrival=self.clock() if now is None else now,
+            rid=next(self._rids),
         )
         st = self.session.stats
         if self.result_cache is not None or self.bound_cache is not None:
@@ -259,60 +264,70 @@ class DiscoveryEngine:
         if not group:
             return
         t0 = self.clock()
-        epoch = self.index.mutation_epoch
-        # warm requests replay cached phase-A bounds (skip gather+filter);
-        # a stale-epoch bounds object is discarded — it was cached before a
-        # §5.4 mutation that may have changed this query's candidates.
-        warm: list[DiscoveryRequest] = []
-        cold: list[DiscoveryRequest] = []
-        for r in group:
-            (warm if r.bounds is not None and r.bounds.epoch == epoch else cold).append(r)
-        lanes = self.degrade_lanes if any(r.degraded for r in cold) else None
-        try:
-            pcs = (
-                self.session.plan_and_count(
-                    [(r.query, r.q_cols) for r in cold], filter_lanes=lanes
+        with telemetry.span("serve.group") as span:
+            if span is not None:
+                span.attrs.update(
+                    size=len(group),
+                    rids=[r.rid for r in group],
+                    waits=[t0 - r.arrival for r in group],
                 )
-                if cold
-                else []
-            )
-            st = self.session.stats
-            for req, pc in zip(cold, pcs):
-                entries, stats = self.session.score_from_counts(pc, req.k)
-                if req.fingerprint is not None:
-                    if self.result_cache is not None:
-                        self.result_cache.put(
-                            req.fingerprint, req.k, pc.epoch, entries, stats
-                        )
-                    # degraded counts are valid (looser) bounds, but don't
-                    # cache them: a hot entry would keep replaying the wide
-                    # survivor set long after the pressure spike ended.
-                    if self.bound_cache is not None and not req.degraded:
-                        self.bound_cache.put(req.fingerprint, pc)
-                self._resolve(req, entries, stats)
-            for req in warm:
-                entries, stats = self.session.score_from_counts(
-                    req.bounds, req.k, from_cache=True
-                )
-                st.bound_hits += 1
-                if self.result_cache is not None and req.fingerprint is not None:
-                    self.result_cache.put(
-                        req.fingerprint, req.k, req.bounds.epoch, entries, stats
+            epoch = self.index.mutation_epoch
+            # warm requests replay cached phase-A bounds (skip gather+filter);
+            # a stale-epoch bounds object is discarded — it was cached before a
+            # §5.4 mutation that may have changed this query's candidates.
+            warm: list[DiscoveryRequest] = []
+            cold: list[DiscoveryRequest] = []
+            for r in group:
+                (warm if r.bounds is not None and r.bounds.epoch == epoch else cold).append(r)
+            lanes = self.degrade_lanes if any(r.degraded for r in cold) else None
+            try:
+                pcs = (
+                    self.session.plan_and_count(
+                        [(r.query, r.q_cols) for r in cold], filter_lanes=lanes,
+                        rids=[r.rid for r in cold],
                     )
-                self._resolve(req, entries, stats)
-        except BaseException as e:
-            # the group is already dequeued: reject every future so sibling
-            # awaiters see the failure instead of polling forever, then let
-            # the pump caller observe the exception too.  (The background
-            # pump task catches it and keeps serving later groups.)
-            for req in group:
-                if not req.future.done():
-                    req.future.set_exception(e)
-            raise
-        dt = self.clock() - t0
-        self._service_ewma = (
-            dt if self._service_ewma is None else 0.7 * self._service_ewma + 0.3 * dt
-        )
+                    if cold
+                    else []
+                )
+                st = self.session.stats
+                for req, pc in zip(cold, pcs):
+                    with telemetry.span("score.request", rid=req.rid):
+                        entries, stats = self.session.score_from_counts(pc, req.k)
+                    if req.fingerprint is not None:
+                        if self.result_cache is not None:
+                            self.result_cache.put(
+                                req.fingerprint, req.k, pc.epoch, entries, stats
+                            )
+                        # degraded counts are valid (looser) bounds, but don't
+                        # cache them: a hot entry would keep replaying the wide
+                        # survivor set long after the pressure spike ended.
+                        if self.bound_cache is not None and not req.degraded:
+                            self.bound_cache.put(req.fingerprint, pc)
+                    self._resolve(req, entries, stats)
+                for req in warm:
+                    with telemetry.span("score.request", rid=req.rid):
+                        entries, stats = self.session.score_from_counts(
+                            req.bounds, req.k, from_cache=True
+                        )
+                    st.bound_hits += 1
+                    if self.result_cache is not None and req.fingerprint is not None:
+                        self.result_cache.put(
+                            req.fingerprint, req.k, req.bounds.epoch, entries, stats
+                        )
+                    self._resolve(req, entries, stats)
+            except BaseException as e:
+                # the group is already dequeued: reject every future so sibling
+                # awaiters see the failure instead of polling forever, then let
+                # the pump caller observe the exception too.  (The background
+                # pump task catches it and keeps serving later groups.)
+                for req in group:
+                    if not req.future.done():
+                        req.future.set_exception(e)
+                raise
+            dt = self.clock() - t0
+            self._service_ewma = (
+                dt if self._service_ewma is None else 0.7 * self._service_ewma + 0.3 * dt
+            )
 
     def _resolve(self, req: DiscoveryRequest, entries, stats) -> None:
         req.results, req.stats = entries, stats
@@ -352,10 +367,14 @@ class DiscoveryEngine:
         now = self.clock() if now is None else now
         self._purge_cancelled()
         served: list[DiscoveryRequest] = []
-        while self._due(now):
-            group, self.queue = self.queue[: self.batch], self.queue[self.batch :]
-            self._serve_group(group)
-            served.extend(r for r in group if not r.future.cancelled())
+        if not self._due(now):
+            return served
+        with telemetry.span("serve.pump_turn", backlog=len(self.queue)):
+            while self._due(now):
+                group, self.queue = self.queue[: self.batch], self.queue[self.batch :]
+                self._serve_group(group)
+                served.extend(r for r in group if not r.future.cancelled())
+                telemetry.count("groups")
         return served
 
     def flush(self) -> list[DiscoveryRequest]:
@@ -512,7 +531,13 @@ class AsyncDiscoveryEngine(DiscoveryEngine):
             deadline = self.next_deadline()
             if deadline is not None:
                 timeout = max(deadline - self.clock(), 0.0)
-            await self.aclock.wait(self._wake, timeout)
+            # the sleep is an interval, not an open span: nothing that runs
+            # while the pump waits may become its child
+            waiting = telemetry.begin("serve.wait")
+            try:
+                await self.aclock.wait(self._wake, timeout)
+            finally:
+                telemetry.end(waiting)
             self._wake.clear()
 
     async def discover_async(
