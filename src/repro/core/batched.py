@@ -48,7 +48,9 @@ contiguous blocks fed straight to the §6.3 filter kernel:
   * rule 2 becomes a *stronger* bound: the exact filtered-candidate count per
     table (the device-side counts vector) replaces the paper's incremental
     ``L_t - r_checked + r_match`` bound, so strictly more tables are skipped
-    before verification;
+    before verification.  It applies before the heap fills too: the bound
+    is then 0, so a table with no filter-surviving pair (joinability 0,
+    which the heap would discard) is skipped without a re-gather;
   * only filter-surviving pairs are verified on the host (same exact
     ``calculateJ`` as the faithful engine).
 
@@ -65,7 +67,8 @@ shape ``serve.engine.DiscoveryEngine`` uses for concurrent traffic.
 Top-k results are BIT-IDENTICAL to Algorithm 1 (ids, joinability scores and
 mappings): both engines visit tables in the same order with the same
 replace-only-if-strictly-greater heap, and every pruned table provably cannot
-enter a full heap (its joinability is bounded by the pruning threshold).
+enter the heap (its joinability is bounded by the pruning threshold, which is
+0 — a joinability the heap discards — until the heap is full).
 """
 
 from __future__ import annotations
@@ -331,6 +334,13 @@ def _score_tables(
     and surviving tables gather just their own slice from the index store
     (the same ``superkeys`` array every other path reads: bit-identical).
 
+    Rule 2 prunes a table whenever its count is at or below
+    ``topk.bound()``, full heap or not: before the heap fills the bound is
+    0, so exactly the tables with no filter-surviving pair are skipped
+    (counted in ``tables_pruned_empty`` as well as ``tables_pruned_rule2``).
+    The filter has no false negatives, so joinability ≤ count, and the heap
+    discards joinability 0 — the top-k and the verified pairs are unchanged.
+
     ``rule1=True`` additionally applies the paper's rule 1 inside the range
     (tables are PL-desc sorted → the first at/below the bound prunes the
     whole suffix) — the ``discover_many`` path, where the filter already ran
@@ -351,11 +361,11 @@ def _score_tables(
             stats.verified_tp + stats.verified_fp,
             stats.tables_pruned_rule1 + stats.tables_pruned_rule2,
             stats.tables_evaluated - stats.tables_pruned_rule2,
+            stats.tables_pruned_empty,
         )
     device_hits = (not lazy) and not isinstance(hits, np.ndarray)
     if device_hits:
-        bound0 = topk.bound() if topk.full else -1
-        alive = counts[: t_stop - t_start] > bound0
+        alive = counts[: t_stop - t_start] > topk.bound()
         n_alive = int(
             (alive * np.diff(ptr[t_start : t_stop + 1])).sum()
         )
@@ -373,8 +383,10 @@ def _score_tables(
         lo, hi = int(ptr[t]) - base, int(ptr[t + 1]) - base
         # strengthened rule 2: exact filtered-candidate count bound, from the
         # device-side counts — no match-matrix transfer for pruned tables.
-        if topk.full and int(counts[t - t_start]) <= topk.bound():
+        # Before the heap fills the bound is 0: empty tables are skipped.
+        if int(counts[t - t_start]) <= topk.bound():
             stats.tables_pruned_rule2 += 1
+            stats.tables_pruned_empty += int(not topk.full)
             continue
         if timed:
             t_a = clock()
@@ -406,6 +418,9 @@ def _score_tables(
         telemetry.count(
             "tables_pruned",
             stats.tables_pruned_rule1 + stats.tables_pruned_rule2 - before[1],
+        )
+        telemetry.count(
+            "tables_pruned_empty", stats.tables_pruned_empty - before[3]
         )
         telemetry.count(
             "tables_verified",
@@ -561,8 +576,8 @@ def discover_batched(
                 row_f, q_f, elig, seg, stop - start, backend=bk,
             )
         else:
-            # heap not full (bound 0): nothing can be pruned, every hit
-            # block is about to be verified — single-transfer path.
+            # heap not full (bound 0): only empty tables can be pruned,
+            # most hit blocks are about to be verified — single-transfer path.
             stats.filter_matrix_bytes += int(elig.size)
             hits, counts = _hits_counts_host(
                 row_f, q_f, elig, seg, stop - start, bk

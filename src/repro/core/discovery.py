@@ -29,6 +29,8 @@ class DiscoveryStats:
     tables_evaluated: int = 0
     tables_pruned_rule1: int = 0  # remaining tables skipped when rule 1 fires
     tables_pruned_rule2: int = 0
+    tables_pruned_empty: int = 0  # of rule 2's, those pruned before the heap
+    # filled (bound 0: no filter-surviving pair; batched engines only)
     pl_items_total: int = 0
     pl_items_checked: int = 0
     filter_checks: int = 0  # (query row, candidate row) super-key probes

@@ -239,6 +239,7 @@ class SessionStats:
     tables_evaluated: int = 0
     tables_pruned_rule1: int = 0
     tables_pruned_rule2: int = 0
+    tables_pruned_empty: int = 0  # of rule 2's, pruned before the heap filled
     filter_checks: int = 0
     filter_passed: int = 0
     verified_tp: int = 0

@@ -1,5 +1,7 @@
 """Discovery (Algorithm 1) correctness: vs brute force, engines, baselines."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -219,6 +221,79 @@ def test_score_tables_reads_back_only_surviving_slices(lake, monkeypatch):
     )
     assert plan.stats.filter_readback_bytes - st0 == survivor_items * k
     assert plan.stats.tables_pruned_rule2 == t_stop - 1
+
+
+@pytest.mark.parametrize("lazy", [False, True])
+def test_score_tables_prunes_empty_tables_before_heap_fills(lake, lazy):
+    """Rule 2 before the heap fills: the bound is 0, so every table whose
+    exact filtered count is 0 is pruned without a slice readback or
+    re-gather — counted in ``tables_pruned_empty`` and rule 2 alike — and
+    the top-k equals verifying every table."""
+    from repro.core import batched as B
+    from repro.kernels import ops
+
+    corpus, index, query, q_cols, _ = lake
+    plan = B.plan_query(index, query, q_cols)
+    block = plan.block
+    n_tables = block.n_tables
+    k = len(plan.distinct_keys)
+    row_sk = index.superkey_of_rows(block.rows)
+    hits = ops.subsume_np(row_sk, plan.q_sk) & plan.elig
+    # empty every other table, so zero counts are certain
+    seg = B._segment_ids(block.table_ptr, 0, n_tables)
+    hits[seg % 2 == 1] = False
+    plan.elig[seg % 2 == 1] = False
+    counts = np.bincount(seg, weights=hits.sum(axis=1), minlength=n_tables)
+    counts = counts.astype(np.int32)
+    empty = counts == 0
+    assert empty.any() and not empty.all()
+
+    topk = B._TopK(n_tables + 1)  # never fills: the bound stays 0
+    st = plan.stats
+    B._score_tables(
+        index, plan, topk, None if lazy else hits, counts, block.rows,
+        0, n_tables, 0, row_sk=row_sk if lazy else None, elig=plan.elig,
+    )
+    assert not topk.full
+    assert st.tables_pruned_empty == st.tables_pruned_rule2 == int(empty.sum())
+    assert st.tables_evaluated == n_tables
+    if lazy:  # only surviving tables' slices were recomputed
+        items = np.diff(block.table_ptr)
+        assert st.filter_readback_bytes == int(items[~empty].sum()) * k
+
+    ref = B._TopK(n_tables + 1)
+    for t in range(n_tables):
+        lo, hi = int(block.table_ptr[t]), int(block.table_ptr[t + 1])
+        j, mapping = B._calculate_j(
+            index, dataclasses.replace(plan, stats=discovery.DiscoveryStats()),
+            block.rows[lo:hi], hits[lo:hi],
+        )
+        ref.offer(int(block.table_ids[t]), j, mapping)
+    assert [(e.table_id, e.joinability, e.mapping) for e in topk.entries()] == [
+        (e.table_id, e.joinability, e.mapping) for e in ref.entries()
+    ]
+
+
+@pytest.mark.parametrize("key_width", [3, 4])
+def test_fp_heavy_queries_prune_empty_tables_bit_identical(lake, key_width):
+    """Key columns from different tables (the fp-heavy mix): whole keys
+    rarely match, heaps never fill, and most candidate tables have no
+    filter-surviving pair.  discover_many and discover_batched prune those
+    tables before any re-gather and still return Algorithm 1's top-k."""
+    corpus, index, _, _, _ = lake
+    queries = synthetic.make_mixed_queries(corpus, 4, 40, key_width, seed=33)
+    assert queries
+    many = discover_many(index, queries, k=10)
+    empty_many = empty_batched = 0
+    for (q, qc), (entries, st_many) in zip(queries, many):
+        seq, _ = discovery.discover(index, q, qc, k=10)
+        want = [(e.table_id, e.joinability, e.mapping) for e in seq]
+        assert [(e.table_id, e.joinability, e.mapping) for e in entries] == want
+        bat, st_bat = discover_batched(index, q, qc, k=10)
+        assert [(e.table_id, e.joinability, e.mapping) for e in bat] == want
+        empty_many += st_many.tables_pruned_empty
+        empty_batched += st_bat.tables_pruned_empty
+    assert empty_many > 0 and empty_batched > 0
 
 
 @pytest.mark.parametrize("hash_name", ["bf", "ht", "murmur", "simhash"])

@@ -115,6 +115,25 @@ def test_one_request_gives_the_span_tree(lake):
     assert not any(s.parent in waits for s in records)
 
 
+def test_empty_prunes_counter_equals_stats_delta(lake):
+    """A width-3 request from different tables' columns leaves its heap
+    short of k: the tables with no filter-surviving pair are pruned, and the
+    ``score.tables`` span counts each of them once, as the stats do."""
+    corpus, _, _ = lake
+    (query, q_cols), = synthetic.make_mixed_queries(corpus, 1, 30, 3, seed=8)
+    session = _session(corpus)
+    _serve_one(session, query, q_cols)
+    before = session.stats.tables_pruned_empty
+    telemetry.enable()
+    req = _serve_one(session, query, q_cols)
+    records = telemetry.disable()
+    (tables,) = [s.attrs for s in records if s.name == "score.tables"]
+    delta = session.stats.tables_pruned_empty - before
+    assert delta == req.stats.tables_pruned_empty > 0
+    assert tables["tables_pruned_empty"] == delta
+    assert tables["tables_pruned"] >= delta
+
+
 def test_counters_equal_discovery_stats(lake):
     corpus, query, q_cols = lake
     session = _session(corpus)
@@ -128,6 +147,7 @@ def test_counters_equal_discovery_stats(lake):
     assert tables["pairs_verified"] == st.verified_tp + st.verified_fp
     assert tables["tables_pruned"] == st.tables_pruned_rule1 + st.tables_pruned_rule2
     assert tables["tables_verified"] == st.tables_evaluated - st.tables_pruned_rule2
+    assert tables["tables_pruned_empty"] == st.tables_pruned_empty
     assert tables["regather_s"] >= 0 and tables["exact_s"] > 0
     plan = by["plan.query"].attrs
     assert plan["items"] == st.pl_items_checked
