@@ -3,6 +3,26 @@ metrics, and each configuration, traffic mix, lake generator, query
 generator and per-layer metric lives in a file of its own under ``bench/``.
 A cell or metric is added by adding files and entries; nothing here lists
 them.
+
+A new configuration needs, each as a new file:
+
+- ``bench/configs/<config>.json``: ``name``, ``source``, ``lake``
+  (``generator``, ``params``, ``seed``), ``bits``, ``serving``,
+  ``guarantees``, ``reduced`` and ``assumed``;
+- ``bench/lakes/<generator>.py``, unless a configuration already names it:
+  ``generate(params, seed)`` returning a ``bench.lake.Lake``, and ``TINY``,
+  the ``params`` overrides that cut the lake to a size a CPU test can hold;
+- ``bench/traffic/<mix>.json``: ``generator``, ``rate``, ``seed``, ``vary``
+  and the size lists it names (read by ``bench/traffic/__init__.py``);
+- ``bench/traffic/<generator>.py``, unless a mix already names it:
+  ``query(lake, mix, size, rng)`` returning the key ids and key width, and
+  ``TINY``, the mix overrides for a CPU test, with a ``rate`` of 3.0 (the
+  tests expect 12 requests in their 4-second runs);
+- ``bench/metrics/<metric>.py`` for each new per-layer metric;
+
+and in ``BENCHMARK.json`` its ``configs`` entry, a ``workloads`` entry for
+each of its cells, and the ``per_layer`` entries of its new metrics.  The
+tests under ``bench/tests/`` then cover it with no file of theirs edited.
 """
 
 from __future__ import annotations

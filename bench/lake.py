@@ -73,12 +73,13 @@ class Lake:
 
     def shuffled(self, rng: np.random.Generator) -> "Lake":
         """The same tables in another order, each with its rows in another
-        order: every table, row and value is kept, so posting-list lengths,
-        candidate tables and join sizes stay as they were, while table ids
-        and row offsets follow ``rng``."""
-        order = rng.permutation(len(self.tables))
-        tables = [self.tables[t][rng.permutation(self.tables[t].shape[0])] for t in order.tolist()]
-        return Lake(tables=tables, vocab=self.vocab)
+        order: every table, row and value is kept, each table with its
+        relation, so posting-list lengths, candidate tables and join sizes
+        stay as they were, while table ids and row offsets follow ``rng``."""
+        order = rng.permutation(len(self.tables)).tolist()
+        tables = [self.tables[t][rng.permutation(self.tables[t].shape[0])] for t in order]
+        relation = [self.relation[t] for t in order]
+        return Lake(tables=tables, vocab=self.vocab, relation=relation, columns=self.columns)
 
 
 def factorize(columns: list[list[np.ndarray]]) -> tuple[list[np.ndarray], np.ndarray]:

@@ -35,6 +35,9 @@ LETTER_P = np.array([
 ])
 LETTER_P = LETTER_P / LETTER_P.sum()
 
+# the parameters that cut the lake to a size a CPU test can hold
+TINY = {"n_tables": 120}
+
 
 def _join(parts: np.ndarray, lengths: np.ndarray) -> list[str]:
     return ["".join(row[:n]) for row, n in zip(parts.tolist(), lengths.tolist())]

@@ -3,8 +3,9 @@
     JAX_PLATFORMS=cpu python bench/tests/cpu_run.py <tree> <cell> [--fault <name>] [--trace-off]
 
 ``<tree>`` is made by ``tiny_tree``: a copy of ``BENCHMARK.json`` and
-``bench/`` whose lakes and mixes are cut to a size a test can hold, with the
-program's ``src`` linked in.  The harness's look for a chip and its check
+``bench/`` whose lakes and mixes are cut to a size a test can hold, each by
+the ``TINY`` overrides its generator module declares, with the program's
+``src`` linked in.  The harness's look for a chip and its check
 of the chip's backend are stubbed out; everything else is the run the chip
 makes, with the named fault of ``bench/control.py`` planted underneath.  Prints the
 result's JSON line.  A run of its own process keeps JAX's compile cache
@@ -21,23 +22,39 @@ from pathlib import Path
 
 REPO = Path(__file__).resolve().parents[2]
 
-TINY_LAKES = {"webtable": {"n_tables": 120}}
-TINY_MIX = {"rate": 3.0, "rows": [10, 60]}
 SECONDS = 4.0
 SEED = 3_000_000_017  # beyond 32 bits: a seed may be any whole number
 
 
-def tiny_tree(dst: Path) -> Path:
-    shutil.copytree(REPO / "bench", dst / "bench", ignore=shutil.ignore_patterns("__pycache__"))
-    shutil.copy(REPO / "BENCHMARK.json", dst / "BENCHMARK.json")
+def tiny(generator) -> dict:
+    """The ``TINY`` overrides that a lake or traffic generator module declares."""
+    if not isinstance(getattr(generator, "TINY", None), dict):
+        raise AttributeError(
+            f"{generator.__file__} declares no TINY dict of the parameters that "
+            "cut it to a size a CPU test can hold"
+        )
+    return generator.TINY
+
+
+def tiny_tree(dst: Path, src: Path = REPO) -> Path:
+    """Copy ``src``'s ``BENCHMARK.json`` and ``bench/`` to ``dst`` and cut every
+    configuration and traffic mix that ``BENCHMARK.json`` names to its
+    generator's ``TINY`` size."""
+    shutil.copytree(src / "bench", dst / "bench", ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(src / "BENCHMARK.json", dst / "BENCHMARK.json")
     os.symlink(REPO / "src", dst / "src")
-    for path in (dst / "bench" / "configs").glob("*.json"):
+    from bench.catalog import Catalog
+
+    cat = Catalog(dst)
+    for entry in cat.spec["configs"]:
+        path = dst / entry["file"]
         cfg = json.loads(path.read_text())
-        cfg["lake"]["params"].update(TINY_LAKES[cfg["lake"]["generator"]])
+        cfg["lake"]["params"].update(tiny(cat.module("lakes", cfg["lake"]["generator"])))
         path.write_text(json.dumps(cfg))
-    for path in (dst / "bench" / "traffic").glob("*.json"):
+    for name in sorted({cell["traffic"] for cell in cat.spec["workloads"]}):
+        path = dst / "bench" / "traffic" / f"{name}.json"
         mix = json.loads(path.read_text())
-        mix.update(TINY_MIX)
+        mix.update(tiny(cat.module("traffic", mix["generator"])))
         path.write_text(json.dumps(mix))
     return dst
 

@@ -99,7 +99,7 @@ def _cpu_env() -> dict:
 
 def test_exits_without_a_chip():
     out = subprocess.run(
-        [sys.executable, "bench/run.py", "--workload", "webtable-fp-nary", "--seed", "1",
+        [sys.executable, "bench/run.py", "--workload", SPEC["workloads"][0]["name"], "--seed", "1",
          "--seconds", "1", "--trace", "0"],
         cwd=ROOT, env=_cpu_env(), capture_output=True, text=True, timeout=300,
     )
@@ -111,7 +111,7 @@ def test_exits_in_a_tree_without_the_program(tmp_path):
     shutil.copytree(ROOT / "bench", tmp_path / "bench", ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
     out = subprocess.run(
-        [sys.executable, "bench/run.py", "--workload", "webtable-fp-nary", "--seed", "2",
+        [sys.executable, "bench/run.py", "--workload", SPEC["workloads"][0]["name"], "--seed", "2",
          "--seconds", "1", "--trace", "0"],
         cwd=tmp_path, env=_cpu_env(), capture_output=True, text=True, timeout=300,
     )
@@ -152,6 +152,74 @@ def test_new_parts_are_found_by_name(tmp_path):
     result = _tiny_run(tree, "narrow-short")
     assert result["correct"] is True and result["attempted"] == 12
     assert set(result["metrics"]) == E2E
+
+
+NEW_CONFIG = Path(__file__).resolve().parent / "data" / "new_config"
+
+
+def _full_tree(dst: Path) -> Path:
+    """A copy of the benchmark's files, at full size."""
+    shutil.copytree(ROOT / "bench", dst / "bench", ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", dst)
+    return dst
+
+
+def _add(tree: Path, files: Path, entries: dict) -> None:
+    """Add new files under ``tree`` and append new entries to its
+    ``BENCHMARK.json``; assert that no file already there changed."""
+    bench_json = tree / "BENCHMARK.json"
+    before = {p: p.read_bytes() for p in tree.rglob("*") if p.is_file()}
+    for path in files.rglob("*"):
+        if path.is_file():
+            dst = tree / path.relative_to(files)
+            assert not dst.exists(), dst
+            dst.parent.mkdir(parents=True, exist_ok=True)
+            shutil.copy(path, dst)
+    spec = json.loads(bench_json.read_text())
+    for key, new in entries.items():
+        spec[key] = spec[key] + new
+    bench_json.write_text(json.dumps(spec))
+    assert [p for p, b in before.items() if p != bench_json and p.read_bytes() != b] == []
+    old = json.loads(before[bench_json])
+    assert {k: v[: len(old[k])] if k in entries else v for k, v in spec.items()} == old
+
+
+def test_a_new_lake_generator_needs_no_edit(tmp_path):
+    """A configuration whose lake generator is a new module (a two-relation
+    lake with a composite foreign key), with a new traffic generator, mix,
+    cell and metric, added as new files and new entries of BENCHMARK.json:
+    no file already in the tree changes, each generator's own TINY cuts it
+    for the CPU, and the new cell's run is correct."""
+    full = _full_tree(tmp_path / "full")
+    _add(full, NEW_CONFIG / "files", json.loads((NEW_CONFIG / "entries.json").read_text()))
+    tree = cpu_run.tiny_tree(tmp_path / "tiny", full)
+
+    cat = Catalog(tree)
+    assert cat.config("supply-lake")["lake"]["params"]["parts"] == 40
+    assert cat.traffic("lineitem-partsupp")["rows"] == [10, 40]
+    assert [m["name"] for m in cat.per_layer("supply-fk")] == ["requests_answered"]
+    assert cat.module("metrics", "requests_answered").read(type("R", (), {"outcomes": [1]})) == 1
+    result = _tiny_run(tree, "supply-fk")
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["attempted"] == 12
+    assert set(result["metrics"]) == E2E
+
+
+def test_a_generator_without_tiny_is_named(tmp_path):
+    full = _full_tree(tmp_path / "full")
+    cfg = json.loads((full / SPEC["configs"][0]["file"]).read_text())
+    cfg["lake"]["generator"] = "bare"
+    files = tmp_path / "files"
+    (files / "bench/configs").mkdir(parents=True)
+    (files / "bench/lakes").mkdir()
+    (files / "bench/configs/bare.json").write_text(json.dumps(cfg))
+    (files / "bench/lakes/bare.py").write_text("from bench.lakes.webtable import generate  # noqa: F401\n")
+    _add(full, files, {
+        "configs": [{**SPEC["configs"][0], "name": "bare", "file": "bench/configs/bare.json"}],
+        "workloads": [{**SPEC["workloads"][0], "name": "bare-cell", "config": "bare"}],
+    })
+    with pytest.raises(AttributeError, match=r"bench/lakes/bare\.py declares no TINY"):
+        cpu_run.tiny_tree(tmp_path / "tiny", full)
 
 
 def _tiny_run(tree: Path, cell: str, fault: str | None = None) -> dict:

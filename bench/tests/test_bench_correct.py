@@ -8,9 +8,9 @@ from __future__ import annotations
 import pytest
 
 from bench.tests import cpu_run
-from bench.tests.test_bench_contract import E2E, _tiny_run
+from bench.tests.test_bench_contract import E2E, SPEC, _tiny_run
 
-CELLS = ["webtable-fp-nary"]
+CELLS = [cell["name"] for cell in SPEC["workloads"]]
 
 
 @pytest.fixture(scope="module")

@@ -2,11 +2,15 @@
 
 from __future__ import annotations
 
+import importlib
+
 import numpy as np
 import pytest
 
-from bench.lake import rng
+from bench.catalog import Catalog
+from bench.lake import Lake, rng
 from bench.lakes import webtable
+from bench.tests.cpu_run import tiny
 
 WEB = dict(
     n_tables=400, shape_seed=0, rows_lo=5, rows_hi=60, cols_lo=2, cols_hi=24,
@@ -27,7 +31,21 @@ def _same(a, b) -> bool:
     )
 
 
-@pytest.mark.parametrize("gen,params", [(webtable, WEB)])
+def _generators() -> list[tuple]:
+    """(module, parameters) of each lake generator that a configuration of
+    ``BENCHMARK.json`` names: that configuration's, cut to the generator's
+    TINY size."""
+    cat, out = Catalog(), {}
+    for entry in cat.spec["configs"]:
+        lake = cat.config(entry["name"])["lake"]
+        if lake["generator"] not in out:
+            # imported by package name, so every test worker gives it the same id
+            gen = importlib.import_module(f"bench.lakes.{lake['generator']}")
+            out[lake["generator"]] = (gen, {**lake["params"], **tiny(gen)})
+    return list(out.values())
+
+
+@pytest.mark.parametrize("gen,params", _generators())
 def test_same_seed_same_lake(gen, params):
     assert _same(gen.generate(params, 7), gen.generate(params, 7))
     assert not _same(gen.generate(params, 7), gen.generate(params, 8))
@@ -72,3 +90,19 @@ def test_a_shuffled_lake_keeps_every_row(web):
     # each table keeps its rows as a set; most change their order
     by_set = lambda lake: sorted(sorted(map(tuple, t.tolist())) for t in lake.tables)  # noqa: E731
     assert by_set(a) == by_set(web)
+
+
+def test_a_shuffled_lake_keeps_each_table_with_its_relation(web):
+    """A schema lake keeps its relation names and columns through the
+    layout every run draws; a lake without a schema reads ``table``."""
+    tables = [np.full((3, 2 + t % 2), t, dtype=np.int32) for t in range(8)]
+    columns = {"orders": ["o_orderkey", "o_custkey"], "lineitem": ["l_orderkey", "l_partkey", "l_suppkey"]}
+    lake = Lake(tables=tables, vocab=np.arange(8).astype(str).astype(object),
+                relation=["orders", "lineitem"] * 4, columns=columns)
+    moved = lake.shuffled(rng(5, 5))
+    assert [int(t[0, 0]) for t in moved.tables] != list(range(8))
+    for table, relation in zip(moved.tables, moved.relation):
+        assert relation == lake.relation[int(table[0, 0])]
+        assert table.shape[1] == len(moved.columns[relation])
+    assert moved.columns == columns
+    assert web.shuffled(rng(5, 5)).relation == ["table"] * len(web.tables)

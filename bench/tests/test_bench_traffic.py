@@ -11,10 +11,16 @@ import numpy as np
 import pytest
 
 from bench import traffic
+from bench.catalog import Catalog
 from bench.lake import rng
 from bench.lakes import webtable
 from bench.traffic import columns
+from bench.tests.cpu_run import tiny
 from bench.tests.test_bench_lakes import WEB
+
+CAT = Catalog()
+# each mix a cell names, with the configuration of one such cell
+MIXES_IN_USE = {cell["traffic"]: cell["config"] for cell in CAT.spec["workloads"]}
 
 MIXES = Path(__file__).resolve().parents[1] / "traffic"
 
@@ -48,7 +54,28 @@ def test_request_count(rate, seconds, n):
     assert traffic.request_count(rate, seconds) == n
 
 
-@pytest.mark.parametrize("mix,gen", [("fp-nary", columns)])
+@pytest.mark.parametrize("mix", sorted(MIXES_IN_USE))
+def test_every_mix_is_fixed_by_its_seeds(mix):
+    """At its generator's TINY size, on its cell's lake: the run seed
+    reorders the rows of the same requests at the same due times."""
+    m = CAT.traffic(mix)
+    gen = CAT.module("traffic", m["generator"])
+    m.update(tiny(gen))
+    lake_cfg = CAT.config(MIXES_IN_USE[mix])["lake"]
+    lake_gen = CAT.module("lakes", lake_cfg["generator"])
+    lake = lake_gen.generate({**lake_cfg["params"], **tiny(lake_gen)}, lake_cfg["seed"])
+    a, b, c = (traffic.generate(m, gen.query, lake, seed, 4.0) for seed in (9, 9, 10))
+    assert len(a) == traffic.request_count(m["rate"], 4.0)
+    assert all(np.array_equal(x.key, y.key) and x.due == y.due for x, y in zip(a, b))
+    as_rows = lambda r: sorted(map(tuple, r.key.tolist()))  # noqa: E731
+    assert [as_rows(x) for x in a] == [as_rows(y) for y in c]
+    assert [(x.due, x.key_width) for x in a] == [(y.due, y.key_width) for y in c]
+    assert all(0 < r.key_width <= r.key.shape[1] and r.n_rows > 0 for r in a)
+
+
+@pytest.mark.parametrize(
+    "mix,gen", [(name, columns) for name in sorted(MIXES_IN_USE) if CAT.traffic(name)["generator"] == "columns"]
+)
 def test_column_queries(web, mix, gen):
     m = _mix(mix, rate=2.0)
     a = traffic.generate(m, gen.query, web, 9, 9.0)

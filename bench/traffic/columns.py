@@ -13,6 +13,9 @@ import numpy as np
 
 from bench.lake import Lake
 
+# the mix parameters that cut a run to a size a CPU test can hold
+TINY = {"rate": 3.0, "rows": [10, 60]}
+
 
 def query(lake: Lake, mix: dict, size: dict, rng: np.random.Generator):
     width, n = size["key_width"], size["rows"]
