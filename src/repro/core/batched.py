@@ -51,8 +51,9 @@ contiguous blocks fed straight to the §6.3 filter kernel:
     before verification.  It applies before the heap fills too: the bound
     is then 0, so a table with no filter-surviving pair (joinability 0,
     which the heap would discard) is skipped without a re-gather;
-  * only filter-surviving pairs are verified on the host (same exact
-    ``calculateJ`` as the faithful engine).
+  * only filter-surviving pairs are verified on the host: the faithful
+    engine's exact ``calculateJ``, computed on lake value ids in numpy
+    rather than one string comparison per pair.
 
 Hash width is a first-class knob: every array here is ``lanes``-wide
 (``XashConfig(bits=...)`` → 4/8/16 uint32 lanes for 128/256/512 bits), so the
@@ -76,7 +77,6 @@ from __future__ import annotations
 import dataclasses
 import heapq
 import time
-from collections import defaultdict
 
 import numpy as np
 
@@ -99,10 +99,16 @@ class QueryPlan:
     query: Table
     q_cols: list[int]
     distinct_keys: list[tuple]
+    key_ids: np.ndarray  # int32[K, width] lake value ids of each key (-1:
+    # a value the lake lacks), what exact verification compares
     q_sk: np.ndarray  # uint32[K, lanes] batched query-key super keys
     block: CandidateBlock  # CSR candidate rows grouped per table
     elig: np.ndarray  # bool[N_items, K] init-value eligibility per item
     stats: DiscoveryStats
+    # the same eligibility by init value: key ids grouped by their init
+    # value, and each value's offsets into them (``_eligible_pairs``)
+    value_keys: np.ndarray  # int64[K]
+    value_key_ptr: np.ndarray  # int64[n_values + 1]
 
 
 def _gate_block(block: CandidateBlock, keep: np.ndarray) -> CandidateBlock:
@@ -151,6 +157,12 @@ def plan_query(
             keys = [tuple(row[c] for c in q_cols) for row in query.cells]
             distinct_keys = list(dict.fromkeys(keys))
             q_sk = index.superkey_of_keys(distinct_keys)
+        with telemetry.span("plan.key_ids"):
+            value_of = index.corpus.value_of
+            key_ids = np.array(
+                [[value_of.get(v, -1) for v in key] for key in distinct_keys],
+                dtype=np.int32,
+            ).reshape(len(distinct_keys), len(q_cols))
 
         with telemetry.span("plan.gather_candidates"):
             values = list(dict.fromkeys(query.column(init_col)))
@@ -173,9 +185,17 @@ def plan_query(
             # bool[n_values, K]: key kid is probed against items of value v
             # only if the key's init-column entry IS v (Alg. 1 matches per
             # posting list).
-            elig_value = np.zeros((len(values), len(distinct_keys)), dtype=bool)
-            for kid, key in enumerate(distinct_keys):
-                elig_value[value_id[key[init_idx]], kid] = True
+            n_keys = len(distinct_keys)
+            init_value = np.array(
+                [value_id[key[init_idx]] for key in distinct_keys], dtype=np.int64
+            )
+            elig_value = np.zeros((len(values), n_keys), dtype=bool)
+            elig_value[init_value, np.arange(n_keys)] = True
+            value_keys = np.argsort(init_value, kind="stable")
+            value_key_ptr = np.zeros(len(values) + 1, dtype=np.int64)
+            np.cumsum(
+                np.bincount(init_value, minlength=len(values)), out=value_key_ptr[1:]
+            )
             elig = (
                 elig_value[block.value_idx]
                 if block.n_items
@@ -183,7 +203,10 @@ def plan_query(
             )
         telemetry.count("items", block.n_items)
         telemetry.count("tables", block.n_tables)
-    return QueryPlan(query, q_cols, distinct_keys, q_sk, block, elig, stats)
+    return QueryPlan(
+        query, q_cols, distinct_keys, key_ids, q_sk, block, elig, stats,
+        value_keys, value_key_ptr,
+    )
 
 
 def _segment_ids(table_ptr: np.ndarray, t_start: int, t_stop: int) -> np.ndarray:
@@ -212,32 +235,102 @@ def _hits_counts_host(row_sk, q_sk, elig, seg, n_tables, backend: Backend):
     return hits, counts[:n_tables]
 
 
+def _eligible_pairs(plan: QueryPlan, value_idx: np.ndarray):
+    """(item, key) index pairs of a slice's eligible probes — each item with
+    every key whose init value is the item's posting value — in the order
+    ``np.nonzero`` of the slice's ``plan.elig`` rows gives, without
+    scanning the dense [items, K] block."""
+    start = plan.value_key_ptr[value_idx]
+    n = plan.value_key_ptr[value_idx + 1] - start
+    item = np.repeat(np.arange(value_idx.size), n)
+    first = np.cumsum(n) - n
+    key = plan.value_keys[np.repeat(start - first, n) + np.arange(item.size)]
+    return item, key
+
+
+@dataclasses.dataclass
+class _Pairs:
+    """A slice's filter-surviving (row, key) pairs, row-major: what
+    ``np.nonzero`` of its hit mask gives (``np.nonzero`` calls
+    ``nonzero``), without the [rows, K] mask."""
+
+    rows: np.ndarray
+    keys: np.ndarray
+
+    def nonzero(self) -> tuple[np.ndarray, np.ndarray]:
+        return self.rows, self.keys
+
+
+def _verify_pairs(
+    index: MateIndex, plan: QueryPlan, rows: np.ndarray, rs: np.ndarray, ks: np.ndarray
+) -> tuple[np.ndarray, np.ndarray, np.ndarray, int]:
+    """Exact verification of (row ``rows[rs[p]]``, key ``ks[p]``) pairs on
+    lake value ids: each row of ``corpus.cell_value_ids`` is compared with
+    the key's ids (``plan.key_ids``, -1 matching nothing) in numpy.  The
+    injective mappings are grown one key value at a time: every column
+    holding the value and not used by the mapping so far extends it.
+    Returns bool[n] (the pair holds its key, as ``seq._verify_pair`` on
+    the strings finds), and the packed code and key of every mapping
+    found, with the code's base."""
+    cells = index.corpus.cell_value_ids[rows[rs]]  # int32[n, max_cols]
+    kv = plan.key_ids[ks]  # int32[n, width]
+    pair = np.arange(rs.size)
+    cols = np.zeros((rs.size, 0), dtype=np.int64)
+    for i in range(kv.shape[1]):
+        v = kv[pair, i, None]
+        at = (cells[pair] == v) & (v >= 0)
+        at[np.arange(pair.size)[:, None], cols] = False
+        m, c = np.nonzero(at)
+        pair, cols = pair[m], np.concatenate([cols[m], c[:, None]], axis=1)
+    tp = np.zeros(rs.size, dtype=bool)
+    tp[pair] = True
+    base = max(cells.shape[1], 1)
+    return tp, _pack(cols, base), ks[pair], base
+
+
 def _calculate_j(
     index: MateIndex,
     plan: QueryPlan,
     rows: np.ndarray,
     hits: np.ndarray,
 ) -> tuple[int, tuple[int, ...] | None]:
-    """Exact verification (Alg. 1 line 21) over filter-surviving pairs."""
-    corpus = index.corpus
+    """Exact verification (Alg. 1 line 21) over filter-surviving pairs
+    (``hits``: a slice's bool mask, or its ``_Pairs``), on lake value ids
+    (``_verify_pairs``).  Counts, joinability and mapping (ties to the
+    largest) are those of ``seq._verify_pair`` on the strings."""
     stats = plan.stats
-    rows_per_mapping: dict[tuple[int, ...], set] = defaultdict(set)
     rs, ks = np.nonzero(hits)
-    for r, kid in zip(rs.tolist(), ks.tolist()):
-        key = plan.distinct_keys[kid]
-        mappings = seq._verify_pair(key, corpus.row_values(int(rows[r])))
-        if mappings:
-            stats.verified_tp += 1
-            for m in mappings:
-                rows_per_mapping[m].add(key)
-        else:
-            stats.verified_fp += 1
-    if not rows_per_mapping:
+    if not rs.size:
         return 0, None
-    mapping, keyset = max(
-        rows_per_mapping.items(), key=lambda kv: (len(kv[1]), kv[0])
-    )
-    return len(keyset), mapping
+    tp, codes, owners, base = _verify_pairs(index, plan, rows, rs, ks)
+    n_tp = int(tp.sum())
+    stats.verified_tp += n_tp
+    stats.verified_fp += int(rs.size) - n_tp
+    if not n_tp:
+        return 0, None
+    # distinct keys per mapping; the largest count wins, ties the largest
+    # mapping (packed codes order as mapping tuples do)
+    n_keys = max(len(plan.distinct_keys), 1)
+    pairs = np.unique(codes * n_keys + owners)
+    code, size = np.unique(pairs // n_keys, return_counts=True)
+    best = np.lexsort((code, size))[-1]
+    return int(size[best]), _unpack(int(code[best]), plan.key_ids.shape[1], base)
+
+
+def _pack(pos: np.ndarray, base: int) -> np.ndarray:
+    """int64 code of each mapping row, first column most significant."""
+    code = np.zeros(pos.shape[0], dtype=np.int64)
+    for i in range(pos.shape[1]):
+        code = code * base + pos[:, i]
+    return code
+
+
+def _unpack(code: int, width: int, base: int) -> tuple[int, ...]:
+    out = []
+    for _ in range(width):
+        code, c = divmod(code, base)
+        out.append(c)
+    return tuple(reversed(out))
 
 
 class _TopK:
@@ -311,7 +404,6 @@ def _score_tables(
     base: int,
     rule1: bool = False,
     row_sk: np.ndarray | None = None,
-    elig: np.ndarray | None = None,
     prefetch_frac: float = _PREFETCH_FRAC,
 ) -> None:
     """Verify (or rule-2-prune) tables [t_start, t_stop) of the plan's block,
@@ -326,9 +418,12 @@ def _score_tables(
     evolving-bound pruning decisions below are identical either way.
 
     ``hits`` may also be None — the FUSED counts-only launch, where the
-    match matrix was never produced at all.  Surviving tables' hit slices
-    are then recomputed on demand from ``row_sk``/``elig`` (same subsumption
-    predicate → bit-identical verification inputs); pruned tables cost
+    match matrix was never produced at all.  Surviving tables' hits are
+    then recomputed on demand from ``row_sk``, testing only the table's
+    eligible (row, key) pairs (``_eligible_pairs``: the same pairs as
+    ``plan.elig``, the same subsumption predicate → bit-identical
+    verification inputs; ``regather_pairs`` counts the pairs tested);
+    pruned tables cost
     nothing beyond their 4 count bytes.  On the GATHER-fused path even
     ``row_sk`` is None — the host never gathered the candidate superkeys —
     and surviving tables gather just their own slice from the index store
@@ -349,8 +444,6 @@ def _score_tables(
     block, stats = plan.block, plan.stats
     ptr = block.table_ptr
     lazy = hits is None
-    if lazy:
-        assert elig is not None
     # per-table clocks only while tracing: the slice re-gather and the exact
     # verification, summed into the score.tables span
     timed = telemetry.enabled()
@@ -362,6 +455,7 @@ def _score_tables(
             stats.tables_pruned_rule1 + stats.tables_pruned_rule2,
             stats.tables_evaluated - stats.tables_pruned_rule2,
             stats.tables_pruned_empty,
+            stats.regather_pairs,
         )
     device_hits = (not lazy) and not isinstance(hits, np.ndarray)
     if device_hits:
@@ -391,13 +485,18 @@ def _score_tables(
         if timed:
             t_a = clock()
         if lazy:
+            # the filter again, on the table's eligible (row, key) pairs only
+            r, kid = _eligible_pairs(plan, block.value_idx[ptr[t] : ptr[t + 1]])
             rsk = (
                 row_sk[lo:hi]
                 if row_sk is not None
                 else index.superkey_of_rows(rows[lo:hi])
             )
-            sub = ops.subsume_np(rsk, plan.q_sk) & elig[lo:hi]
-            stats.filter_readback_bytes += sub.size
+            ok = ops.subsume_pairs_np(rsk, plan.q_sk, r, kid)
+            sub = _Pairs(r[ok], kid[ok])
+            stats.regather_pairs += int(r.size)
+            # booked as the slice of the match matrix the pairs stand for
+            stats.filter_readback_bytes += (hi - lo) * plan.q_sk.shape[0]
         else:
             sub = np.asarray(hits[lo:hi])
             if device_hits:
@@ -422,6 +521,7 @@ def _score_tables(
         telemetry.count(
             "tables_pruned_empty", stats.tables_pruned_empty - before[3]
         )
+        telemetry.count("regather_pairs", stats.regather_pairs - before[4])
         telemetry.count(
             "tables_verified",
             stats.tables_evaluated - stats.tables_pruned_rule2 - before[2],
@@ -602,7 +702,7 @@ def discover_batched(
         with telemetry.span("score.tables"):
             _score_tables(
                 index, plan, topk, hits, counts, rows, start, stop, lo,
-                row_sk=row_sk, elig=elig, prefetch_frac=prefetch_frac,
+                row_sk=row_sk, prefetch_frac=prefetch_frac,
             )
     return _ranked_entries(topk, rank, scores), stats
 
@@ -882,7 +982,7 @@ def score_from_counts(
     with telemetry.span("score.tables"):
         _score_tables(
             index, plan, topk, hits, pc.counts, block.rows, 0, block.n_tables, 0,
-            rule1=True, row_sk=pc.row_sk, elig=plan.elig,
+            rule1=True, row_sk=pc.row_sk,
             prefetch_frac=prefetch_frac,
         )
     return _ranked_entries(topk, rank, scores), stats

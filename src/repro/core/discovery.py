@@ -37,6 +37,9 @@ class DiscoveryStats:
     filter_passed: int = 0  # pairs surviving the row filter
     verified_tp: int = 0  # pairs passing exact verification
     verified_fp: int = 0  # pairs surviving filter but failing verification
+    regather_pairs: int = 0  # eligible (row, key) pairs the phase-B re-gather
+    # tested again on the host for surviving tables (counts-only launches;
+    # read against filter_checks, the pairs the launch tested)
     # batched-engine transfer accounting (device-side rule 1/2):
     filter_matrix_bytes: int = 0  # full match-matrix bytes the filter produced
     filter_readback_bytes: int = 0  # match bytes materialised host-side

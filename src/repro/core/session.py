@@ -244,6 +244,7 @@ class SessionStats:
     filter_passed: int = 0
     verified_tp: int = 0
     verified_fp: int = 0
+    regather_pairs: int = 0  # eligible pairs the phase-B re-gather tested
     filter_matrix_bytes: int = 0
     filter_readback_bytes: int = 0
     filter_fused_launches: int = 0

@@ -221,6 +221,40 @@ def subsume_np(row_sk: np.ndarray, query_sk: np.ndarray) -> np.ndarray:
     return ok
 
 
+# above this many pairs left, subsume_pairs_np tests one lane at a time:
+# where the filter rejects most pairs the first lanes drop them, and on
+# large slices the loop beats one pass over every lane by about 2x
+_PAIRS_BY_LANE = 1 << 14
+
+
+def subsume_pairs_np(
+    row_sk: np.ndarray, query_sk: np.ndarray, rows: np.ndarray, keys: np.ndarray
+) -> np.ndarray:
+    """``subsume_np`` on (row, key) pairs alone: bool[n], pair ``i`` tests
+    ``row_sk[rows[i]]`` against ``query_sk[keys[i]]``.  While many pairs
+    are left, one lane at a time, each lane on the pairs the earlier lanes
+    passed; the remaining lanes in one pass."""
+    rsk = np.ascontiguousarray(row_sk, dtype=np.uint32)
+    qry = np.ascontiguousarray(query_sk, dtype=np.uint32)
+    if rsk.shape[1] % 2 == 0:
+        rsk, qry = rsk.view(np.uint64), qry.view(np.uint64)
+    not_rows = ~rsk
+    alive = None  # indices of the pairs the lanes so far passed (None: all)
+    r, k, lane = rows, keys, 0
+    while lane < rsk.shape[1] and len(r) > _PAIRS_BY_LANE:
+        bits = np.take(qry[:, lane], k)
+        bits &= np.take(not_rows[:, lane], r)
+        sel = np.flatnonzero(bits == 0)
+        alive = sel if alive is None else alive[sel]
+        r, k, lane = r[sel], k[sel], lane + 1
+    ok = ~(qry[k, lane:] & not_rows[r, lane:]).any(axis=1)
+    if alive is None:
+        return ok
+    out = np.zeros(len(rows), dtype=bool)
+    out[alive[ok]] = True
+    return out
+
+
 # CPU fallback pads each dim up to a power-of-two bucket so XLA compiles
 # O(log) distinct shapes instead of one program per batch size.
 _FALLBACK_MIN_N = 512

@@ -252,7 +252,7 @@ def test_score_tables_prunes_empty_tables_before_heap_fills(lake, lazy):
     st = plan.stats
     B._score_tables(
         index, plan, topk, None if lazy else hits, counts, block.rows,
-        0, n_tables, 0, row_sk=row_sk if lazy else None, elig=plan.elig,
+        0, n_tables, 0, row_sk=row_sk if lazy else None,
     )
     assert not topk.full
     assert st.tables_pruned_empty == st.tables_pruned_rule2 == int(empty.sum())

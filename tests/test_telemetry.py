@@ -20,8 +20,8 @@ from repro.serve.clock import ManualClock
 from repro.serve.engine import AsyncDiscoveryEngine
 
 PLAN_CHILDREN = [
-    "plan.init_column", "plan.hash_keys", "plan.gather_candidates",
-    "plan.profile_gate", "plan.eligibility",
+    "plan.init_column", "plan.hash_keys", "plan.key_ids",
+    "plan.gather_candidates", "plan.profile_gate", "plan.eligibility",
 ]
 
 
