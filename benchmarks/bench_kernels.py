@@ -71,7 +71,7 @@ def kernels():
     n, q = row_sk.shape[0], q_sk.shape[0]
     n_tables = 64
     seg = np.sort(RNG.integers(0, n_tables, n)).astype(np.int32)
-    elig = np.ones((n, q), dtype=bool)
+    elig = ops.Eligibility(np.zeros(n, np.int32), np.zeros(q, np.int32))  # all
     dt_fused = _time(ops.filter_table_counts, row_sk, q_sk, elig, seg, n_tables)
     dt_comp = _time(
         lambda: ops.filter_hits_table_counts(

@@ -46,8 +46,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 PLATFORM = "tpu"
 DWTC_ROWS = 1_450_000_000  # the paper's web-table lake, which W1 models
 BITS = 512
-# requests per shared launch: a group's eligibility matrix is dense over
-# (Σ candidate rows) × (Σ query keys), over 1 GB at this lake for 2
+# requests per shared launch: two, so the smoke runs a group launch
 WINDOW = 2
 # the warm pass repeats this many of the requests, with every kernel compiled
 WARM = 8

@@ -11,9 +11,10 @@ contiguous blocks fed straight to the §6.3 filter kernel:
     boundaries as three contiguous arrays — no per-row dict lookups;
   * the row filter runs as one subsumption launch per table batch through
     ``kernels.ops.filter_hits_table_counts`` (Pallas ``filter_kernel`` on
-    TPU, vectorised XLA fallback on CPU); value/key eligibility is a
-    precomputed boolean gather fused into the launch, so match extraction is
-    ``np.nonzero`` over per-table slices — no Python loop over PL items;
+    TPU, vectorised XLA fallback on CPU); value/key eligibility rides along
+    as two id vectors (each item's and each key's init value) that the
+    launch compares tile by tile, so match extraction is ``np.nonzero``
+    over per-table slices — no Python loop over PL items;
   * the rule-1/rule-2 joinability bound check is DEVICE-SIDE in
     ``discover_batched``: each launch also reduces the match matrix to
     per-table eligible-hit counts (a matvec row-reduction + segment-sum over
@@ -103,7 +104,9 @@ class QueryPlan:
     # a value the lake lacks), what exact verification compares
     q_sk: np.ndarray  # uint32[K, lanes] batched query-key super keys
     block: CandidateBlock  # CSR candidate rows grouped per table
-    elig: np.ndarray  # bool[N_items, K] init-value eligibility per item
+    # init-value eligibility in id form: item i and key k are eligible
+    # exactly when the item's posting value is the key's init value
+    elig: ops.Eligibility
     stats: DiscoveryStats
     # the same eligibility by init value: key ids grouped by their init
     # value, and each value's offsets into them (``_eligible_pairs``)
@@ -138,7 +141,7 @@ def plan_query(
     rid: int | None = None,
 ) -> QueryPlan:
     """Initialization phase (§6.1) in columnar form: one hash launch, one
-    posting-list gather, one eligibility matrix.
+    posting-list gather, and each key's init value.
 
     ``profile_gate=True`` drops candidate tables whose column profiles
     PROVE joinability 0 (``MateIndex.gate_candidates`` — presence-mask /
@@ -182,24 +185,16 @@ def plan_query(
                     )
         with telemetry.span("plan.eligibility"):
             value_id = {v: i for i, v in enumerate(values)}
-            # bool[n_values, K]: key kid is probed against items of value v
-            # only if the key's init-column entry IS v (Alg. 1 matches per
-            # posting list).
-            n_keys = len(distinct_keys)
+            # key kid is probed against items of value v only if the key's
+            # init-column entry IS v (Alg. 1 matches per posting list)
             init_value = np.array(
-                [value_id[key[init_idx]] for key in distinct_keys], dtype=np.int64
+                [value_id[key[init_idx]] for key in distinct_keys], dtype=np.int32
             )
-            elig_value = np.zeros((len(values), n_keys), dtype=bool)
-            elig_value[init_value, np.arange(n_keys)] = True
+            elig = ops.Eligibility(block.value_idx, init_value)
             value_keys = np.argsort(init_value, kind="stable")
             value_key_ptr = np.zeros(len(values) + 1, dtype=np.int64)
             np.cumsum(
                 np.bincount(init_value, minlength=len(values)), out=value_key_ptr[1:]
-            )
-            elig = (
-                elig_value[block.value_idx]
-                if block.n_items
-                else np.zeros((0, len(distinct_keys)), dtype=bool)
             )
         telemetry.count("items", block.n_items)
         telemetry.count("tables", block.n_tables)
@@ -228,18 +223,24 @@ def _hits_counts_host(row_sk, q_sk, elig, seg, n_tables, backend: Backend):
         return ops.filter_hits_table_counts(
             row_sk, q_sk, elig, seg, n_tables, backend="numpy"
         )
-    hits = ops.filter_match_auto(row_sk, q_sk, backend=backend) & elig
+    hits = ops.filter_match_auto(row_sk, q_sk, backend=backend) & elig.dense()
     counts = np.bincount(
         seg, weights=hits.sum(axis=1), minlength=max(n_tables, 1)
     ).astype(np.int32)
     return hits, counts[:n_tables]
 
 
+def eligible_count(plan: QueryPlan, value_idx: np.ndarray) -> int:
+    """Eligible (item, key) pairs of the items with posting values
+    ``value_idx``: each item counts the keys of its init value (the CSR)."""
+    return int(np.diff(plan.value_key_ptr)[value_idx].sum())
+
+
 def _eligible_pairs(plan: QueryPlan, value_idx: np.ndarray):
     """(item, key) index pairs of a slice's eligible probes — each item with
     every key whose init value is the item's posting value — in the order
-    ``np.nonzero`` of the slice's ``plan.elig`` rows gives, without
-    scanning the dense [items, K] block."""
+    ``np.nonzero`` of the slice's ``plan.elig.dense()`` rows gives, without
+    forming the dense [items, K] block."""
     start = plan.value_key_ptr[value_idx]
     n = plan.value_key_ptr[value_idx + 1] - start
     item = np.repeat(np.arange(value_idx.size), n)
@@ -637,7 +638,7 @@ def discover_batched(
         elig = plan.elig[lo:hi]
         seg = _segment_ids(block.table_ptr, start, stop)
         stats.pl_items_checked += int(rows.shape[0])
-        stats.filter_checks += int(elig.sum())
+        stats.filter_checks += eligible_count(plan, block.value_idx[lo:hi])
         if routed:
             # shard-local counts-only launches, count-merge across shards:
             # the only cross-shard bytes are stats.route_bytes_merged.
@@ -671,14 +672,14 @@ def discover_batched(
             # bound can prune → composed device launch: hits stay on device,
             # only the per-table counts vector is read back; surviving
             # tables' slices transfer lazily in _score_tables.
-            stats.filter_matrix_bytes += int(elig.size)
+            stats.filter_matrix_bytes += int(rows.shape[0]) * q_f.shape[0]
             hits, counts = ops.filter_hits_table_counts(
                 row_f, q_f, elig, seg, stop - start, backend=bk,
             )
         else:
             # heap not full (bound 0): only empty tables can be pruned,
             # most hit blocks are about to be verified — single-transfer path.
-            stats.filter_matrix_bytes += int(elig.size)
+            stats.filter_matrix_bytes += int(rows.shape[0]) * q_f.shape[0]
             hits, counts = _hits_counts_host(
                 row_f, q_f, elig, seg, stop - start, bk
             )
@@ -800,22 +801,28 @@ def plan_and_count(
         rows_all = np.concatenate([p.block.rows for p in plans])
         q_all = np.concatenate([p.q_sk for p in plans])
         # block-diagonal eligibility (a request's keys only probe its own
-        # candidate rows) + a global per-item table index for the one-pass
-        # per-table rule-1/2 count reduction.
-        elig_all = np.zeros((rows_all.shape[0], q_all.shape[0]), dtype=bool)
+        # candidate rows): each plan's init-value ids are offset past the
+        # values of the plans before it, so no id is shared across requests;
+        # + a global per-item table index for the one-pass per-table
+        # rule-1/2 count reduction.
+        item_value, key_value = [], []
         seg_all = np.zeros(rows_all.shape[0], dtype=np.int32)
-        r_off = k_off = 0
+        r_off = v_off = 0
         n_tables_all = 0
         for p in plans:
-            ni, ki, ti = p.block.n_items, p.q_sk.shape[0], p.block.n_tables
-            elig_all[r_off : r_off + ni, k_off : k_off + ki] = p.elig
+            ni, ti = p.block.n_items, p.block.n_tables
+            item_value.append(p.elig.item_value + v_off)
+            key_value.append(p.elig.key_value + v_off)
             if ni:
                 seg_all[r_off : r_off + ni] = n_tables_all + _segment_ids(
                     p.block.table_ptr, 0, ti
                 )
             r_off += ni
-            k_off += ki
+            v_off += p.value_key_ptr.size - 1
             n_tables_all += ti
+        group_elig = ops.Eligibility(
+            np.concatenate(item_value), np.concatenate(key_value)
+        )
     full_lanes = index.cfg.lanes
     fl = full_lanes if filter_lanes is None else max(1, min(int(filter_lanes), full_lanes))
     q_f = q_all if fl == full_lanes else q_all[:, :fl]
@@ -844,12 +851,12 @@ def plan_and_count(
         # routing accounting is attributed below from each plan's own items.
         hits_all = None
         counts_all = index.routed_counts(
-            rows_all, q_f, elig_all, seg_all, n_tables_all,
+            rows_all, q_f, group_elig, seg_all, n_tables_all,
             backend=bk, fused_block_n=fused_block_n, stats=group,
         )
     elif use_gather:
         hits_all, counts_all = ops.filter_hits_table_counts(
-            None, q_f, elig_all, seg_all, n_tables_all,
+            None, q_f, group_elig, seg_all, n_tables_all,
             backend=bk, fused_block_n=fused_block_n,
             store=index.device_store(), rows=rows_all,
         )
@@ -858,10 +865,10 @@ def plan_and_count(
         # (Σ rows × Σ keys) matrix is never materialised; only the group
         # counts vector is read back.  Surviving tables recompute their
         # own-keys hit slices lazily in _score_tables (bit-identical to
-        # slicing the block-diagonal of the full matrix, since elig
+        # slicing the block-diagonal of the full matrix, since eligibility
         # already restricts each row to its own request's keys).
         hits_all, counts_all = ops.filter_hits_table_counts(
-            row_f, q_f, elig_all, seg_all, n_tables_all,
+            row_f, q_f, group_elig, seg_all, n_tables_all,
             backend=bk, fused_block_n=fused_block_n,
         )
     else:
@@ -873,7 +880,7 @@ def plan_and_count(
         # one transfer and the per-table rule-1/2 counts are a cheap
         # host reduction over it.
         hits_all, counts_all = _hits_counts_host(
-            row_f, q_f, elig_all, seg_all, n_tables_all, bk,
+            row_f, q_f, group_elig, seg_all, n_tables_all, bk,
         )
     epoch = index.mutation_epoch
     out: list[PlanCounts] = []
@@ -943,7 +950,7 @@ def score_from_counts(
     stats, block = plan.stats, plan.block
     n_items = block.n_items
     stats.pl_items_checked = n_items
-    stats.filter_checks = int(plan.elig.sum())
+    stats.filter_checks = eligible_count(plan, block.value_idx)
     stats.filter_passed = int(pc.counts.sum())
     stats.filter_lanes = pc.filter_lanes
     hits = pc.hits
@@ -1070,7 +1077,7 @@ def filter_outcomes(
     """
     plan = plan_query(index, query, q_cols, init_mode)
     out = {
-        "checks": int(plan.elig.sum()),
+        "checks": eligible_count(plan, plan.block.value_idx),
         "passed": 0,
         "tp": 0,
         "fp": 0,
@@ -1081,7 +1088,8 @@ def filter_outcomes(
     if plan.block.n_items == 0 or not plan.distinct_keys:
         return out
     row_sk = index.superkey_of_rows(plan.block.rows)
-    hits = ops.subsume_np(row_sk, plan.q_sk) & plan.elig
+    elig = plan.elig.dense()
+    hits = ops.subsume_np(row_sk, plan.q_sk) & elig
     out["passed"] = int(hits.sum())
     corpus = index.corpus
     row_values_cache: dict[int, list[str]] = {}
@@ -1099,7 +1107,7 @@ def filter_outcomes(
         else:
             out["fp"] += 1
     if check_false_negatives:
-        for r, kid in zip(*np.nonzero(plan.elig & ~hits)):
+        for r, kid in zip(*np.nonzero(elig & ~hits)):
             if _matches(int(r), int(kid)):
                 out["fn"] += 1
     return out

@@ -233,7 +233,8 @@ def _routed_local_counts_fn(
       store  uint32[n_shards·pad_store, lanes] — per-shard superkey stores
       rows   int32[n_shards·pad_items]         — SHARD-LOCAL row offsets
       seg    int32[n_shards·pad_items]         — batch table ids (-1 pads)
-      elig   int8[n_shards·pad_items, qb]      — eligibility (0 pads)
+      item   int32[n_shards·pad_items]         — item init-value ids (-1 pads)
+      kval   int32[qb] (replicated)            — key init-value ids (-2 pads)
       qry    uint32[qb, fl] (replicated)       — query superkeys
     Output: int32[n_tables], psum'ed — per-table counts, replicated.
 
@@ -246,7 +247,7 @@ def _routed_local_counts_fn(
     """
     from repro.kernels import filter_kernel
 
-    def _local(store, rows, seg, elig, qry):
+    def _local(store, rows, seg, item, kval, qry):
         fl = qry.shape[1]
         sk = store[rows][:, :fl]
         if impl == "fused":
@@ -254,7 +255,7 @@ def _routed_local_counts_fn(
             block_n = min(pad_items, filter_kernel.fused_block_n(tb))
             block_q = min(qb, filter_kernel.DEFAULT_BLOCK_Q)
             counts, _ = filter_kernel.filter_table_counts(
-                sk.T, qry.T, elig, seg,
+                sk.T, qry.T, (item, kval), seg,
                 n_tables=tb, n_queries=q, block_n=block_n, block_q=block_q,
                 mode="sum", interpret=jax.default_backend() != "tpu",
             )
@@ -264,7 +265,7 @@ def _routed_local_counts_fn(
             for lane in range(fl):
                 c = (qry[None, :, lane] & ~sk[:, lane : lane + 1]) == 0
                 ok = c if ok is None else ok & c
-            ok = ok & (elig > 0)
+            ok = ok & (item[:, None] == kval[None, :])
             per_row = jnp.sum(ok, axis=1).astype(jnp.int32)
             counts = (
                 jnp.zeros((n_tables,), jnp.int32)
@@ -278,7 +279,9 @@ def _routed_local_counts_fn(
         jax.shard_map(
             _local,
             mesh=mesh,
-            in_specs=(P(row_axes), P(row_axes), P(row_axes), P(row_axes), P()),
+            in_specs=(
+                P(row_axes), P(row_axes), P(row_axes), P(row_axes), P(), P()
+            ),
             out_specs=P(),
             check_vma=impl != "fused",
         )
@@ -308,13 +311,14 @@ def routed_filter_counts_mesh(
     index,
     rows: np.ndarray,
     query_sk: np.ndarray,
-    elig: np.ndarray,
+    elig,
     seg_ids: np.ndarray,
     n_tables: int,
     backend: Backend | str | None = None,
 ) -> np.ndarray:
     """The routed filter over ``index``'s mesh: int32[n_tables] counts,
-    bit-identical to the host-routed (and single-host) counts.
+    bit-identical to the host-routed (and single-host) counts.  ``elig`` is
+    the batch's ``ops.Eligibility``.
 
     Each launch partitions its candidate items by owning shard, pads each
     shard's slice to a shared pow2 bucket, and runs the per-shard filter +
@@ -349,14 +353,16 @@ def _routed_launch(index, rows, query_sk, elig, seg_ids, n_tables, impl):
 
     rows_p = np.zeros(n_shards * pad_items, dtype=np.int32)
     seg_p = np.full(n_shards * pad_items, -1, dtype=np.int32)
-    elig_p = np.zeros((n_shards * pad_items, qb), dtype=np.int8)
+    # eligibility ids: the items' packed per shard like the rows (all
+    # padding to start with), the keys' replicated
+    item_p, kval_p = elig[:0].padded(n_shards * pad_items, qb)
     for s, ix in enumerate(per_shard):
         if not len(ix):
             continue
         base = s * pad_items
         rows_p[base : base + len(ix)] = rows[ix] - index.shards[s].row_lo
         seg_p[base : base + len(ix)] = np.asarray(seg_ids)[ix]
-        elig_p[base : base + len(ix), :q] = elig[ix]
+        item_p[base : base + len(ix)] = elig.item_value[ix]
     qry_p = np.full((qb, fl), 0xFFFFFFFF, dtype=np.uint32)
     qry_p[:q] = query_sk
 
@@ -373,7 +379,8 @@ def _routed_launch(index, rows, query_sk, elig, seg_ids, n_tables, impl):
             store,
             jax.device_put(rows_p, sharding),
             jax.device_put(seg_p, sharding),
-            jax.device_put(elig_p, sharding),
+            jax.device_put(item_p, sharding),
+            jnp.asarray(kval_p),
             jnp.asarray(qry_p),
         )
     )

@@ -163,7 +163,7 @@ def fds_from_counts(
     query, det_cols = plan.query, plan.q_cols
     n_items = block.n_items
     stats.pl_items_checked = n_items
-    stats.filter_checks = int(plan.elig.sum())
+    stats.filter_checks = batched_lib.eligible_count(plan, block.value_idx)
     stats.filter_passed = int(pc.counts.sum())
     stats.filter_lanes = pc.filter_lanes
     if pc.fused:
@@ -208,7 +208,7 @@ def fds_from_counts(
             else index.superkey_of_rows(rows)
         )
         stats.fd_bytes_verified += int(rsk.nbytes)
-        sub = ops.subsume_np(rsk, plan.q_sk) & plan.elig[lo:hi]
+        sub = ops.subsume_np(rsk, plan.q_sk) & plan.elig[lo:hi].dense()
         matched: set = set()
         for r, kid in zip(*np.nonzero(sub)):
             key = plan.distinct_keys[int(kid)]

@@ -423,7 +423,7 @@ class ShardedMateIndex:
         self,
         rows: np.ndarray,
         query_sk: np.ndarray,
-        elig: np.ndarray,
+        elig: ops.Eligibility,
         seg_ids: np.ndarray,
         n_tables: int,
         *,
@@ -506,7 +506,7 @@ class ShardedMateIndex:
             return c
         # composed/host backends: counts-only by construction — the
         # shard-local matrix never leaves the shard.
-        hits = ops.subsume_np(row_sk, query_sk) & np.asarray(elig_s, dtype=bool)
+        hits = ops.subsume_np(row_sk, query_sk) & elig_s.dense()
         return np.bincount(
             np.asarray(seg_s, dtype=np.int64),
             weights=hits.sum(axis=1),

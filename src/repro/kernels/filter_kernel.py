@@ -15,9 +15,12 @@ tiny (4 for 128-bit hashes) and would be a terrible minor-most dim for the
 ``[lanes, n]`` before the call — each lane row is then a well-formed
 128-aligned vector.  The gather kernel's device-resident store is instead
 packed into whole 128-lane lines (see ``_gather_counts_kernel``).  Per-row
-vectors (table ids, row offsets) travel as ``[1, n]`` rows and become
-``[bn, 1]`` columns by an int32 reshape inside the kernels: Mosaic cannot
-reshape a bool vector into a column.
+vectors (table ids, row offsets, init-value ids) travel as ``[1, n]`` rows
+and become ``[bn, 1]`` columns by an int32 reshape inside the kernels:
+Mosaic cannot reshape a bool vector into a column.  Eligibility arrives as
+two such id vectors, one per row and one per query key, and each tile forms
+its own ``[bn, bq]`` mask from them: no ``[n, q]`` eligibility operand
+exists.
 """
 
 from __future__ import annotations
@@ -148,15 +151,19 @@ def _scatter_counts(acc, seg, counts_ref, first, mode: str = "sum"):
         counts_ref[...] += partial
 
 
-def _mask_tile(acc, elig_ref, seg, j, n_queries: int):
+def _mask_tile(acc, elig_refs, seg, j, n_queries: int):
     """Eligibility, padded-query-column and padding-row masks of a hit tile.
 
-    Padded query columns (col id ≥ n_queries) carry all-ones super keys that
-    match nothing EXCEPT saturated (all-ones) row super keys, which would
-    otherwise be overcounted when no eligibility mask zero-pads them."""
-    if elig_ref is not None:
-        acc = acc & (elig_ref[...] != 0)
+    ``elig_refs`` is None (all eligible) or the pair (item value ids
+    int32[1, bn], key value ids int32[1, bq]): the eligibility tile is
+    ``item == key``, formed here from the two id blocks.  Padded query
+    columns (col id ≥ n_queries) carry all-ones super keys that match
+    nothing EXCEPT saturated (all-ones) row super keys, which would
+    otherwise be overcounted when no eligibility ids mask them."""
     bn, bq = acc.shape
+    if elig_refs is not None:
+        item_ref, kval_ref = elig_refs
+        acc = acc & (item_ref[...].reshape(bn, 1) == kval_ref[...])
     col = j * bq + jax.lax.broadcasted_iota(jnp.int32, (bn, bq), 1)
     return acc & (col < n_queries) & (seg >= 0)
 
@@ -172,13 +179,14 @@ def _table_counts_kernel(
     Refs (has_elig controls arity):
       row_ref:    uint32[lanes, bn]   candidate-row super keys (transposed)
       query_ref:  uint32[lanes, bq]   query-key super keys (transposed)
-      elig_ref:   int8[bn, bq]        eligibility (only when has_elig)
+      item_ref:   int32[1, bn]        item init-value ids (only when has_elig)
+      kval_ref:   int32[1, bq]        key init-value ids (only when has_elig)
       seg_ref:    int32[1, bn]        table index per row; -1 = padding row
       counts_ref: int32[1, tb]        per-table counts (ONE block, all steps)
       key_ref:    int32[1, bq]        per-key survivor counts
 
-    A [n, 1] seg operand would also compile, but XLA would relay it out
-    lane-padded (128× its bytes) before every launch.
+    A [n, 1] seg (or item id) operand would also compile, but XLA would
+    relay it out lane-padded (128× its bytes) before every launch.
 
     ``mode``: 'sum' counts eligible (row, key) hits per table (the engines'
     exact rule-2 bound); 'any' counts rows matching ≥1 key (the distributed
@@ -186,10 +194,11 @@ def _table_counts_kernel(
     per-block ORs cannot be summed across query blocks).
     """
     if has_elig:
-        row_ref, query_ref, elig_ref, seg_ref, counts_ref, key_ref = refs
+        row_ref, query_ref, item_ref, kval_ref, seg_ref, counts_ref, key_ref = refs
+        elig_refs = (item_ref, kval_ref)
     else:
         row_ref, query_ref, seg_ref, counts_ref, key_ref = refs
-        elig_ref = None
+        elig_refs = None
     j = pl.program_id(0)  # query-block index
     i = pl.program_id(1)  # row-block index (inner grid axis → sequential)
     bn = row_ref.shape[1]
@@ -200,7 +209,7 @@ def _table_counts_kernel(
         ok = (q & ~r) == 0  # [bn, bq]
         acc = ok if acc is None else (acc & ok)
     seg = seg_ref[...].reshape(bn, 1)
-    acc = _mask_tile(acc, elig_ref, seg, j, n_queries)
+    acc = _mask_tile(acc, elig_refs, seg, j, n_queries)
     key_partial = jnp.sum(acc.astype(jnp.int32), axis=0, keepdims=True)
     _scatter_counts(
         acc, seg, counts_ref, jnp.logical_and(i == 0, j == 0), mode
@@ -224,7 +233,7 @@ def _table_counts_kernel(
 def filter_table_counts(
     row_sk_t: jnp.ndarray,
     query_sk_t: jnp.ndarray,
-    elig: jnp.ndarray | None,
+    elig: tuple[jnp.ndarray, jnp.ndarray] | None,
     seg_ids: jnp.ndarray,
     *,
     n_tables: int,
@@ -239,7 +248,9 @@ def filter_table_counts(
     Args:
       row_sk_t:   uint32[lanes, n] (n divisible by block_n).
       query_sk_t: uint32[lanes, q] (q divisible by block_q).
-      elig:       int8[n, q] eligibility, or None for all-eligible.
+      elig:       (item_value int32[n], key_value int32[q]) init-value ids,
+                  row i and query k eligible where they are equal; or None
+                  for all-eligible.
       seg_ids:    int32[n] table index per row (-1 for padding rows).
       n_tables:   padded table count tb (multiple of 128).
       n_queries:  number of REAL queries (≤ q); columns beyond it are
@@ -263,8 +274,10 @@ def filter_table_counts(
     ]
     operands = [row_sk_t, query_sk_t]
     if elig is not None:
-        in_specs.append(pl.BlockSpec((block_n, block_q), lambda j, i: (i, j)))
-        operands.append(elig)
+        item_value, key_value = elig
+        in_specs.append(pl.BlockSpec((1, block_n), lambda j, i: (0, i)))
+        in_specs.append(pl.BlockSpec((1, block_q), lambda j, i: (0, j)))
+        operands += [item_value.reshape(1, n), key_value.reshape(1, q)]
     in_specs.append(pl.BlockSpec((1, block_n), lambda j, i: (0, i)))
     operands.append(seg_ids.reshape(1, n))
     counts, key_counts = pl.pallas_call(
@@ -318,7 +331,8 @@ def _gather_counts_kernel(
       rows_ref:   int32[1, bn]        the same offsets (VMEM: lane offsets)
       store_ref:  uint32[n_lines, 128] packed super-key store (HBM/ANY)
       query_ref:  uint32[lanes, bq]   query-key super keys (transposed)
-      elig_ref:   int8[bn, bq]        eligibility (only when has_elig)
+      item_ref:   int32[1, bn]        item init-value ids (only when has_elig)
+      kval_ref:   int32[1, bq]        key init-value ids (only when has_elig)
       seg_ref:    int32[1, bn]        table index per row; -1 = padding row
       counts_ref: int32[1, tb]        per-table counts (ONE block, all steps)
       line_vmem:  uint32[bn, 128]     gathered store lines
@@ -336,13 +350,12 @@ def _gather_counts_kernel(
     count).  It may be smaller than ``store_lanes`` (the serving tier's
     lane-prefix degrade): only the first ``lanes`` of each row are picked.
     """
+    rows_smem, rows_ref, store_ref, query_ref = refs[:4]
     if has_elig:
-        rows_smem, rows_ref, store_ref, query_ref, elig_ref, seg_ref = refs[:6]
-        counts_ref, line_vmem, key_vmem, sem = refs[6:]
+        elig_refs, refs = refs[4:6], refs[6:]
     else:
-        rows_smem, rows_ref, store_ref, query_ref, seg_ref = refs[:5]
-        counts_ref, line_vmem, key_vmem, sem = refs[5:]
-        elig_ref = None
+        elig_refs, refs = None, refs[4:]
+    seg_ref, counts_ref, line_vmem, key_vmem, sem = refs
     i = pl.program_id(0)  # row-block index (outer)
     j = pl.program_id(1)  # query-block index (inner → scratch reuse across j)
     per_line = STORE_LINE // store_lanes  # rows per line, a power of two
@@ -394,7 +407,7 @@ def _gather_counts_kernel(
         ok = (q & ~r) == 0  # [bn, bq]
         acc = ok if acc is None else (acc & ok)
     seg = seg_ref[...].reshape(block_n, 1)
-    acc = _mask_tile(acc, elig_ref, seg, j, n_queries)
+    acc = _mask_tile(acc, elig_refs, seg, j, n_queries)
     _scatter_counts(acc, seg, counts_ref, jnp.logical_and(i == 0, j == 0))
 
 
@@ -409,7 +422,7 @@ def gather_filter_table_counts(
     rows: jnp.ndarray,
     store: jnp.ndarray,
     query_sk_t: jnp.ndarray,
-    elig: jnp.ndarray | None,
+    elig: tuple[jnp.ndarray, jnp.ndarray] | None,
     seg_ids: jnp.ndarray,
     *,
     store_lanes: int,
@@ -423,8 +436,8 @@ def gather_filter_table_counts(
 
     One launch from posting-list offsets to counts: each grid step
     DMA-gathers its row block's store lines into VMEM before the fused
-    subsume ∧ elig + reduce + scatter — the gathered rows×lanes block never
-    touches HBM.  The offsets reach SMEM one [1, block_n] block per grid
+    subsume ∧ eligibility + reduce + scatter — the gathered rows×lanes
+    block never touches HBM.  The offsets reach SMEM one [1, block_n] block per grid
     step, so no launch-sized operand has to fit in SMEM.
 
     Args:
@@ -436,7 +449,8 @@ def gather_filter_table_counts(
       query_sk_t: uint32[lanes, q] transposed query super keys (q divisible
                   by block_q); ``lanes <= store_lanes`` — a strict prefix
                   probes a lane-degraded filter over the full-width store.
-      elig:       int8[n, q] eligibility, or None for all-eligible.
+      elig:       (item_value int32[n], key_value int32[q]) init-value ids
+                  (see ``filter_table_counts``), or None for all-eligible.
       seg_ids:    int32[n] table index per row (-1 for padding rows).
       n_tables:   padded table count tb (multiple of 128).
       n_queries:  number of REAL queries (≤ q).
@@ -462,8 +476,10 @@ def gather_filter_table_counts(
     ]
     operands = [rows2, rows2, store, query_sk_t]
     if elig is not None:
-        in_specs.append(pl.BlockSpec((block_n, block_q), lambda i, j: (i, j)))
-        operands.append(elig)
+        item_value, key_value = elig
+        in_specs.append(pl.BlockSpec((1, block_n), lambda i, j: (0, i)))
+        in_specs.append(pl.BlockSpec((1, block_q), lambda i, j: (0, j)))
+        operands += [item_value.reshape(1, n), key_value.reshape(1, q)]
     in_specs.append(pl.BlockSpec((1, block_n), lambda i, j: (0, i)))
     operands.append(seg_ids.reshape(1, n))
     counts = pl.pallas_call(

@@ -7,6 +7,7 @@ the kernels run everywhere; on TPU backends the real Mosaic path is used).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import NamedTuple
 
@@ -295,11 +296,47 @@ def _check_fused_block_n(block_n: int) -> None:
         )
 
 
-def _count_upload(elig_p, *operands) -> None:
+# padding ids of the eligibility operands: init-value ids are >= 0, so a
+# padding item (-1) or key (-2) is eligible with nothing, not even each other
+PAD_ITEM_VALUE = -1
+PAD_KEY_VALUE = -2
+
+
+@dataclasses.dataclass(frozen=True)
+class Eligibility:
+    """Which (item, key) pairs a filter launch probes, in id form.
+
+    Algorithm 1 probes a key only against the posting list of its init
+    value, so item ``i`` and key ``k`` are eligible exactly when
+    ``item_value[i] == key_value[k]``.  Launches upload the two id vectors
+    and the kernels form each tile's mask from them: no [items, keys]
+    eligibility array exists on the host or the device."""
+
+    item_value: np.ndarray  # int32[n] each item's init-value id
+    key_value: np.ndarray  # int32[q] each key's init-value id
+
+    def __getitem__(self, items) -> "Eligibility":
+        """The eligibility of a subset of the items (a slice, mask or index)."""
+        return Eligibility(self.item_value[items], self.key_value)
+
+    def dense(self) -> np.ndarray:
+        """bool[n, q]: for host paths that return a hits matrix, and tests."""
+        return self.item_value[:, None] == self.key_value[None, :]
+
+    def padded(self, nb: int, qb: int) -> tuple[np.ndarray, np.ndarray]:
+        """The two id vectors as int32, padded to ``nb`` items, ``qb`` keys."""
+        item = np.full(nb, PAD_ITEM_VALUE, dtype=np.int32)
+        item[: self.item_value.shape[0]] = self.item_value
+        key = np.full(qb, PAD_KEY_VALUE, dtype=np.int32)
+        key[: self.key_value.shape[0]] = self.key_value
+        return item, key
+
+
+def _count_upload(elig_ids, *operands) -> None:
     """Counters of the open ``filter.launch`` span: the padded operands sent
-    to the device (``h2d_bytes``) and the eligibility operand alone
-    (``elig_bytes``)."""
-    elig_bytes = 0 if elig_p is None else int(elig_p.nbytes)
+    to the device (``h2d_bytes``) and the eligibility operands alone
+    (``elig_bytes``: the two padded id vectors)."""
+    elig_bytes = 0 if elig_ids is None else sum(int(x.nbytes) for x in elig_ids)
     telemetry.count("h2d_bytes", elig_bytes + sum(int(x.nbytes) for x in operands))
     telemetry.count("elig_bytes", elig_bytes)
 
@@ -355,16 +392,20 @@ def _per_table_counts(hits, seg, num_segments: int):
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments",))
-def _hits_counts_block(row_sk, query_sk, elig, seg, *, num_segments: int):
-    """Subsumption ∧ eligibility plus per-table hit counts, all on device."""
-    hits = jnp.all((query_sk[None, :, :] & ~row_sk[:, None, :]) == 0, axis=-1) & elig
+def _hits_counts_block(
+    row_sk, query_sk, item_value, key_value, seg, *, num_segments: int
+):
+    """Subsumption ∧ eligibility plus per-table hit counts, all on device;
+    the eligibility mask is formed here from the two id vectors."""
+    hits = jnp.all((query_sk[None, :, :] & ~row_sk[:, None, :]) == 0, axis=-1)
+    hits = hits & (item_value[:, None] == key_value[None, :])
     return hits, _per_table_counts(hits, seg, num_segments)
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments",))
-def _combine_counts(match, elig, seg, *, num_segments: int):
+def _combine_counts(match, item_value, key_value, seg, *, num_segments: int):
     """Same reduction as ``_hits_counts_block`` over a precomputed match."""
-    hits = match.astype(jnp.bool_) & elig
+    hits = match.astype(jnp.bool_) & (item_value[:, None] == key_value[None, :])
     return hits, _per_table_counts(hits, seg, num_segments)
 
 
@@ -377,7 +418,7 @@ _FUSED_MAX_TABLES = filter_kernel.FUSED_MAX_TABLES
 def filter_table_counts(
     row_sk: np.ndarray | jnp.ndarray,
     query_sk: np.ndarray | jnp.ndarray,
-    elig: np.ndarray | None,
+    elig: Eligibility | None,
     seg_ids: np.ndarray,
     n_tables: int,
     *,
@@ -393,7 +434,8 @@ def filter_table_counts(
     Args:
       row_sk:   uint32[n, lanes] candidate-row super keys.
       query_sk: uint32[q, lanes] query-key super keys.
-      elig:     bool[n, q] eligibility per (item, key), or None (all eligible).
+      elig:     per-item and per-key init-value ids (``Eligibility``), or
+                None (all eligible).
       seg_ids:  int32[n] table index (0..n_tables) of each candidate item.
       n_tables: number of tables covered by this block.
       mode:     'sum' (eligible hits per table) | 'any' (rows with ≥1 hit).
@@ -427,15 +469,12 @@ def filter_table_counts(
         qry_p[:q] = query_sk
         seg_p = np.full(nb, -1, dtype=np.int32)  # padding rows scatter nowhere
         seg_p[:n] = seg_ids
-        elig_p = None
-        if elig is not None:
-            elig_p = np.zeros((nb, qb), dtype=np.int8)
-            elig_p[:n, :q] = elig
+        elig_ids = None if elig is None else elig.padded(nb, qb)
     with telemetry.span("filter.upload"):
         counts, _key_counts = filter_kernel.filter_table_counts(
             jnp.asarray(rows_p).T,
             jnp.asarray(qry_p).T,
-            None if elig_p is None else jnp.asarray(elig_p),
+            None if elig_ids is None else tuple(map(jnp.asarray, elig_ids)),
             jnp.asarray(seg_p),
             n_tables=tb,
             n_queries=q,
@@ -444,7 +483,7 @@ def filter_table_counts(
             mode=mode,
             interpret=interpret,
         )
-    _count_upload(elig_p, rows_p, qry_p, seg_p)
+    _count_upload(elig_ids, rows_p, qry_p, seg_p)
     with telemetry.span("filter.readback"):
         return np.asarray(counts)[:n_tables]
 
@@ -508,7 +547,8 @@ def table_chunks(seg_ids: np.ndarray, n_tables: int):
 
 def chunked_counts(launch, seg_ids, elig, n_tables: int, *per_item):
     """Run ``launch(*per_item_slices, elig_slice, seg_slice, n)`` once per
-    ``table_chunks`` range and concatenate the per-table counts."""
+    ``table_chunks`` range and concatenate the per-table counts; the
+    ``Eligibility`` is sliced with the items."""
     counts = np.zeros(n_tables, dtype=np.int32)
     seg = np.asarray(seg_ids)
     for sl, t_lo, t_hi in table_chunks(seg, n_tables):
@@ -525,7 +565,7 @@ def gather_filter_table_counts(
     store: DeviceStore,
     rows: np.ndarray,
     query_sk: np.ndarray | jnp.ndarray,
-    elig: np.ndarray | None,
+    elig: Eligibility | None,
     seg_ids: np.ndarray,
     n_tables: int,
     *,
@@ -548,7 +588,8 @@ def gather_filter_table_counts(
       query_sk: uint32[q, lanes] query-key super keys; ``lanes <=
                 store.lanes`` probes a lane-prefix degrade over the
                 full-width store.
-      elig:     bool[n, q] eligibility per (item, key), or None.
+      elig:     per-item and per-key init-value ids (``Eligibility``), or
+                None (all eligible).
       seg_ids:  int32[n] table index (0..n_tables) of each candidate item.
       n_tables: number of tables covered by this block.
       block_n:  optional power-of-two row-block override
@@ -586,16 +627,13 @@ def gather_filter_table_counts(
         qry_p[:q] = query_sk
         seg_p = np.full(nb, -1, dtype=np.int32)
         seg_p[:n] = seg_ids
-        elig_p = None
-        if elig is not None:
-            elig_p = np.zeros((nb, qb), dtype=np.int8)
-            elig_p[:n, :q] = elig
+        elig_ids = None if elig is None else elig.padded(nb, qb)
     with telemetry.span("filter.upload"):
         counts = filter_kernel.gather_filter_table_counts(
             jnp.asarray(rows_p),
             store.lines,
             jnp.asarray(qry_p).T,
-            None if elig_p is None else jnp.asarray(elig_p),
+            None if elig_ids is None else tuple(map(jnp.asarray, elig_ids)),
             jnp.asarray(seg_p),
             store_lanes=store.lanes,
             n_tables=tb,
@@ -604,7 +642,7 @@ def gather_filter_table_counts(
             block_q=block_q,
             interpret=interpret,
         )
-    _count_upload(elig_p, rows_p, qry_p, seg_p)
+    _count_upload(elig_ids, rows_p, qry_p, seg_p)
     with telemetry.span("filter.readback"):
         return np.asarray(counts)[:n_tables]
 
@@ -612,7 +650,7 @@ def gather_filter_table_counts(
 def filter_hits_table_counts(
     row_sk: np.ndarray | jnp.ndarray,
     query_sk: np.ndarray | jnp.ndarray,
-    elig: np.ndarray,
+    elig: Eligibility,
     seg_ids: np.ndarray,
     n_tables: int,
     *,
@@ -628,7 +666,8 @@ def filter_hits_table_counts(
     Args:
       row_sk:   uint32[n, lanes] candidate-row super keys.
       query_sk: uint32[q, lanes] query-key super keys.
-      elig:     bool[n, q] init-value eligibility per (item, key) pair.
+      elig:     init-value eligibility per (item, key) pair, as per-item and
+                per-key ids (``Eligibility``); a launch uploads only the ids.
       seg_ids:  int32[n] table index (0..n_tables) of each candidate item.
       n_tables: number of tables covered by this block.
       use_device: False forces the host numpy path (legacy ``use_kernel``).
@@ -680,7 +719,7 @@ def filter_hits_table_counts(
         if backend == "auto":
             backend = "numpy" if n * q < _MIN_XLA_PROBES else "xla"
         if backend == "numpy":
-            hits = subsume_np(row_sk, query_sk) & np.asarray(elig, dtype=bool)
+            hits = subsume_np(row_sk, query_sk) & elig.dense()
             counts = np.bincount(
                 np.asarray(seg_ids, dtype=np.int64),
                 weights=hits.sum(axis=1),
@@ -688,8 +727,9 @@ def filter_hits_table_counts(
             ).astype(np.int32)
             return hits, counts[:n_tables]
         # bucket every dim so XLA compiles O(few) distinct shapes; padded
-        # rows/queries have elig False, so their (arbitrary) super keys and the
-        # segment-0 padding of seg_ids contribute nothing to hits or counts.
+        # rows/queries carry padding ids eligible with nothing, so their
+        # (arbitrary) super keys and the segment-0 padding of seg_ids
+        # contribute nothing to hits or counts.
         nb = _bucket(n, _FALLBACK_MIN_N)
         qb = _pow2_bucket(q, _FALLBACK_MIN_Q)
         tb = _pow2_bucket(n_tables, 16)
@@ -698,8 +738,7 @@ def filter_hits_table_counts(
             rows_p[:n] = row_sk
             qry_p = np.zeros((qb, query_sk.shape[1]), dtype=np.uint32)
             qry_p[:q] = query_sk
-            elig_p = np.zeros((nb, qb), dtype=bool)
-            elig_p[:n, :q] = elig
+            elig_ids = elig.padded(nb, qb)
             seg_p = np.zeros(nb, dtype=np.int32)
             seg_p[:n] = seg_ids
         with telemetry.span("filter.upload"):
@@ -713,17 +752,18 @@ def filter_hits_table_counts(
                     interpret=interpret,
                 )
                 hits, counts = _combine_counts(
-                    match, jnp.asarray(elig_p), jnp.asarray(seg_p), num_segments=tb
+                    match, *map(jnp.asarray, elig_ids), jnp.asarray(seg_p),
+                    num_segments=tb,
                 )
             else:
                 hits, counts = _hits_counts_block(
                     jnp.asarray(rows_p),
                     jnp.asarray(qry_p),
-                    jnp.asarray(elig_p),
+                    *map(jnp.asarray, elig_ids),
                     jnp.asarray(seg_p),
                     num_segments=tb,
                 )
-        _count_upload(elig_p, rows_p, qry_p, seg_p)
+        _count_upload(elig_ids, rows_p, qry_p, seg_p)
         with telemetry.span("filter.readback"):
             counts = np.asarray(counts)[:n_tables]
         return hits[:n, :q], counts

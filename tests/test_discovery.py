@@ -238,11 +238,14 @@ def test_score_tables_prunes_empty_tables_before_heap_fills(lake, lazy):
     n_tables = block.n_tables
     k = len(plan.distinct_keys)
     row_sk = index.superkey_of_rows(block.rows)
-    hits = ops.subsume_np(row_sk, plan.q_sk) & plan.elig
+    hits = ops.subsume_np(row_sk, plan.q_sk) & plan.elig.dense()
     # empty every other table, so zero counts are certain
     seg = B._segment_ids(block.table_ptr, 0, n_tables)
     hits[seg % 2 == 1] = False
-    plan.elig[seg % 2 == 1] = False
+    plan.elig = ops.Eligibility(
+        np.where(seg % 2 == 1, ops.PAD_ITEM_VALUE, plan.elig.item_value),
+        plan.elig.key_value,
+    )
     counts = np.bincount(seg, weights=hits.sum(axis=1), minlength=n_tables)
     counts = counts.astype(np.int32)
     empty = counts == 0

@@ -28,8 +28,20 @@ def _rand_sks(n, lanes, dense_frac=0.1):
     return sk
 
 
+def _rand_elig(n, q, n_values=2):
+    """Random init-value ids: each key is eligible with the items of its value."""
+    return ops.Eligibility(
+        RNG.integers(0, n_values, size=n).astype(np.int32),
+        RNG.integers(0, n_values, size=q).astype(np.int32),
+    )
+
+
+def _all_elig(n, q):
+    return ops.Eligibility(np.zeros(n, np.int32), np.zeros(q, np.int32))
+
+
 def _oracle_counts(row_sk, q_sk, elig, seg, n_tables):
-    hits = ops.subsume_np(row_sk, q_sk) & elig
+    hits = ops.subsume_np(row_sk, q_sk) & elig.dense()
     return np.bincount(
         seg, weights=hits.sum(axis=1), minlength=n_tables
     ).astype(np.int32)
@@ -49,13 +61,13 @@ def test_fused_counts_match_composed_oracles(bits, n, q, n_tables):
     row_sk = _rand_sks(n, lanes)
     q_sk = RNG.integers(0, 2**32, size=(q, lanes), dtype=np.uint32)
     q_sk[0] = 0  # zero (empty-key) query subsumes everything
-    elig = RNG.random((n, q)) < 0.6
+    elig = _rand_elig(n, q)
     seg = np.sort(RNG.integers(0, n_tables, size=n)).astype(np.int32)
     want = _oracle_counts(row_sk, q_sk, elig, seg, n_tables)
     got = ops.filter_table_counts(row_sk, q_sk, elig, seg, n_tables)
     assert np.array_equal(got, want), (bits, n, q, n_tables)
     # composed XLA reduction the kernel replaces (jit'd _per_table_counts)
-    hits = jnp.asarray(ops.subsume_np(row_sk, q_sk) & elig)
+    hits = jnp.asarray(ops.subsume_np(row_sk, q_sk) & elig.dense())
     composed = np.asarray(
         ops._per_table_counts(hits, jnp.asarray(seg), n_tables)
     )
@@ -70,7 +82,7 @@ def test_fused_dispatch_returns_counts_only(bits):
     n, q, n_tables = 420, 17, 7
     row_sk = _rand_sks(n, lanes)
     q_sk = RNG.integers(0, 2**32, size=(q, lanes), dtype=np.uint32)
-    elig = RNG.random((n, q)) < 0.5
+    elig = _rand_elig(n, q)
     seg = np.sort(RNG.integers(0, n_tables, size=n)).astype(np.int32)
     hits, counts = ops.filter_hits_table_counts(
         row_sk, q_sk, elig, seg, n_tables, backend="fused"
@@ -87,7 +99,7 @@ def test_fused_env_backend_dispatch(monkeypatch):
     n, q, n_tables = 300, 9, 4
     row_sk = _rand_sks(n, 4)
     q_sk = RNG.integers(0, 2**32, size=(q, 4), dtype=np.uint32)
-    elig = np.ones((n, q), dtype=bool)
+    elig = _all_elig(n, q)
     seg = np.sort(RNG.integers(0, n_tables, size=n)).astype(np.int32)
     hits, counts = ops.filter_hits_table_counts(row_sk, q_sk, elig, seg, n_tables)
     assert hits is None
@@ -100,21 +112,22 @@ def test_fused_zero_query_and_empty_blocks():
     row_sk = _rand_sks(100, 4)
     q_sk = np.zeros((0, 4), dtype=np.uint32)
     assert np.array_equal(
-        ops.filter_table_counts(row_sk, q_sk, np.zeros((100, 0), bool),
+        ops.filter_table_counts(row_sk, q_sk, _all_elig(100, 0),
                                 np.zeros(100, np.int32), 5),
         np.zeros(5, np.int32),
     )
     assert ops.filter_table_counts(
-        np.zeros((0, 4), np.uint32), _rand_sks(3, 4), np.zeros((0, 3), bool),
+        np.zeros((0, 4), np.uint32), _rand_sks(3, 4), _all_elig(0, 3),
         np.zeros(0, np.int32), 5,
     ).tolist() == [0] * 5
     assert ops.filter_table_counts(
-        row_sk, _rand_sks(3, 4), np.zeros((100, 3), bool),
+        row_sk, _rand_sks(3, 4), _all_elig(100, 3),
         np.zeros(100, np.int32), 0,
     ).shape == (0,)
-    # all-pruned: every (row, key) pair ineligible
+    # all-pruned: every (row, key) pair ineligible (no key has an item's value)
     counts = ops.filter_table_counts(
-        row_sk, np.zeros((3, 4), np.uint32), np.zeros((100, 3), bool),
+        row_sk, np.zeros((3, 4), np.uint32),
+        ops.Eligibility(np.zeros(100, np.int32), np.ones(3, np.int32)),
         np.sort(RNG.integers(0, 5, 100)).astype(np.int32), 5,
     )
     assert np.array_equal(counts, np.zeros(5, np.int32))
@@ -130,7 +143,7 @@ def test_fused_counts_large_table_counts():
     n, q, n_tables = 8192, 64, 1100  # tb=1152 → budget block_n < 1024
     row_sk = _rand_sks(n, 4)
     q_sk = RNG.integers(0, 2**32, size=(q, 4), dtype=np.uint32)
-    elig = RNG.random((n, q)) < 0.5
+    elig = _rand_elig(n, q)
     seg = np.sort(RNG.integers(0, n_tables, size=n)).astype(np.int32)
     want = _oracle_counts(row_sk, q_sk, elig, seg, n_tables)
     got = ops.filter_table_counts(row_sk, q_sk, elig, seg, n_tables)
@@ -145,12 +158,13 @@ def test_fused_counts_large_table_counts():
     big = filter_kernel.FUSED_MAX_TABLES + 1
     seg_big = np.sort(RNG.integers(0, big, size=300)).astype(np.int32)
     seg_big[-1] = big - 1  # the last chunk holds one table
+    elig_small = ops.Eligibility(elig.item_value[:300], elig.key_value[:5])
     hits, counts = ops.filter_hits_table_counts(
-        row_sk[:300], q_sk[:5], elig[:300, :5], seg_big, big, backend="fused"
+        row_sk[:300], q_sk[:5], elig_small, seg_big, big, backend="fused"
     )
     assert hits is None
     assert np.array_equal(
-        counts, _oracle_counts(row_sk[:300], q_sk[:5], elig[:300, :5], seg_big, big)
+        counts, _oracle_counts(row_sk[:300], q_sk[:5], elig_small, seg_big, big)
     )
 
 
@@ -332,9 +346,9 @@ def test_fused_counts_from_real_superkeys():
     enc_q = RNG.integers(0, 38, size=(31, 2, 32)).astype(np.uint8)
     row_sk = np.asarray(ref.xash_superkey_ref(jnp.asarray(enc_r), cfg))
     q_sk = np.asarray(ref.xash_superkey_ref(jnp.asarray(enc_q), cfg))
-    elig = RNG.random((600, 31)) < 0.8
+    elig = _rand_elig(600, 31)
     seg = np.sort(RNG.integers(0, 11, 600)).astype(np.int32)
     got = ops.filter_table_counts(row_sk, q_sk, elig, seg, 11)
-    match = np.asarray(ops.filter_match(row_sk, q_sk)) & elig
+    match = np.asarray(ops.filter_match(row_sk, q_sk)) & elig.dense()
     want = np.bincount(seg, weights=match.sum(1), minlength=11).astype(np.int32)
     assert np.array_equal(got, want)

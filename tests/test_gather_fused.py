@@ -47,7 +47,7 @@ ALL_BITS = (128, 256, 512)
 def _oracle_counts(row_sk, q_sk, elig, seg, n_tables):
     hits = ops.subsume_np(row_sk, q_sk)
     if elig is not None:
-        hits = hits & elig
+        hits = hits & elig.dense()
     return np.bincount(
         np.asarray(seg)[np.asarray(seg) >= 0],
         weights=hits.sum(axis=1)[np.asarray(seg) >= 0],
@@ -65,7 +65,11 @@ def _rand_case(lanes, n, q, n_tables, n_store=4096, seed=0):
         q_sk[k] = store[rows[k % max(n, 1)]] & rng.integers(
             0, 2**32, size=lanes, dtype=np.uint32
         )
-    elig = rng.random((n, q)) < 0.7
+    # init-value ids: each key is eligible with the items of its value
+    elig = ops.Eligibility(
+        rng.integers(0, 2, size=n).astype(np.int32),
+        rng.integers(0, 2, size=q).astype(np.int32),
+    )
     seg = np.sort(rng.integers(0, n_tables, size=n)).astype(np.int32)
     return store, rows, q_sk, elig, seg
 

@@ -123,9 +123,12 @@ def test_filter_hits_table_counts_matches_oracle(bits, monkeypatch):
     n, q, n_tables = 700, 23, 19
     row_sk = np.asarray(ref.xash_superkey_ref(jnp.asarray(rand_rows(n, 5, 32)), cfg))
     q_sk = np.asarray(ref.xash_superkey_ref(jnp.asarray(rand_rows(q, 2, 32)), cfg))
-    elig = rng.random((n, q)) < 0.6
+    elig = ops.Eligibility(
+        rng.integers(0, 2, size=n).astype(np.int32),
+        rng.integers(0, 2, size=q).astype(np.int32),
+    )
     seg = np.sort(rng.integers(0, n_tables, size=n)).astype(np.int32)
-    want_hits = ops.subsume_np(row_sk, q_sk) & elig
+    want_hits = ops.subsume_np(row_sk, q_sk) & elig.dense()
     want_counts = np.bincount(
         seg, weights=want_hits.sum(axis=1), minlength=n_tables
     ).astype(np.int32)

@@ -157,9 +157,10 @@ def test_counters_equal_discovery_stats(lake):
     q = len({tuple(row[c] for c in q_cols) for row in query.cells})
     nb, qb = ops._bucket(n, ops._FALLBACK_MIN_N), ops._pow2_bucket(q, ops._FALLBACK_MIN_Q)
     launch = by["filter.launch"].attrs
-    assert launch["elig_bytes"] == nb * qb
+    # eligibility: one int32 id per padded item and per padded key
+    assert launch["elig_bytes"] == (nb + qb) * 4
     lanes = session.index.cfg.lanes
-    assert launch["h2d_bytes"] == nb * qb + nb * 4 + qb * lanes * 4 + nb * 4
+    assert launch["h2d_bytes"] == (nb + qb) * 4 + nb * 4 + qb * lanes * 4 + nb * 4
 
 
 def test_a_lowering_is_recorded_under_its_span():

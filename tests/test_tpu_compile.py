@@ -65,7 +65,11 @@ def test_filter_table_counts_compiles(one_chip, lanes, n_tables, with_elig, mode
     # filter pads queries to 128-multiples); 'sum' tiles it
     q = 256 if mode == "any" else QUERIES
     block_q = q if mode == "any" else filter_kernel.DEFAULT_BLOCK_Q
-    elig = _spec((ROWS, q), jnp.int8, one_chip) if with_elig else None
+    elig = (
+        (_spec((ROWS,), jnp.int32, one_chip), _spec((q,), jnp.int32, one_chip))
+        if with_elig
+        else None
+    )
     compiled = filter_kernel.filter_table_counts.lower(
         _spec((lanes, ROWS), jnp.uint32, one_chip),
         _spec((lanes, q), jnp.uint32, one_chip),
@@ -89,7 +93,7 @@ def test_gather_filter_table_counts_compiles(one_chip, lanes, store_lanes, n_tab
         _spec((ROWS,), jnp.int32, one_chip),
         _spec((n_lines, filter_kernel.STORE_LINE), jnp.uint32, one_chip),
         _spec((lanes, QUERIES), jnp.uint32, one_chip),
-        _spec((ROWS, QUERIES), jnp.int8, one_chip),
+        (_spec((ROWS,), jnp.int32, one_chip), _spec((QUERIES,), jnp.int32, one_chip)),
         _spec((ROWS,), jnp.int32, one_chip),
         store_lanes=store_lanes, n_tables=n_tables, n_queries=QUERIES - 5,
         block_n=filter_kernel.fused_block_n(n_tables),
@@ -98,7 +102,7 @@ def test_gather_filter_table_counts_compiles(one_chip, lanes, store_lanes, n_tab
     assert "tpu_custom_call" in compiled.as_text()
     logical = {
         "store": n_store * store_lanes * 4,
-        "elig": ROWS * QUERIES,
+        "elig": (ROWS + QUERIES) * 4,
         "rows+seg": 2 * ROWS * 4,
         "queries": lanes * QUERIES * 4,
     }
@@ -126,7 +130,8 @@ def test_routed_mesh_body_compiles(topo, monkeypatch, lanes, n_tables):
         _spec((n * pad_store, lanes), jnp.uint32, rows_sh),
         _spec((n * pad_items,), jnp.int32, rows_sh),
         _spec((n * pad_items,), jnp.int32, rows_sh),
-        _spec((n * pad_items, qb), jnp.int8, rows_sh),
+        _spec((n * pad_items,), jnp.int32, rows_sh),
+        _spec((qb,), jnp.int32, NamedSharding(mesh, P())),
         _spec((qb, lanes), jnp.uint32, NamedSharding(mesh, P())),
     ).compile()
     text = compiled.as_text()
