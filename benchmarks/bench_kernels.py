@@ -12,7 +12,7 @@ import numpy as np
 
 from benchmarks import common
 from repro.core import discovery, xash
-from repro.core.batched import discover_batched
+from repro.core.batched import discover_many
 from repro.kernels import ops, ref
 
 RNG = np.random.default_rng(0)
@@ -96,12 +96,10 @@ def kernels():
     # ships n int32 offsets instead of n×lanes uint32 superkeys.  The
     # structural metric is input bytes shipped per launch; wall clock in
     # interpret mode only shows the path isn't pathological.
-    import jax.numpy as jnp
-
-    store = jnp.asarray(
+    store = ops.device_store(
         np.concatenate([row_sk, RNG.integers(0, 2**32, row_sk.shape, np.uint32)])
     )
-    rows_idx = RNG.permutation(store.shape[0])[:n].astype(np.int64)
+    rows_idx = RNG.permutation(store.n_rows)[:n].astype(np.int64)
     dt_gather = _time(
         ops.gather_filter_table_counts, store, rows_idx, q_sk, elig, seg, n_tables
     )
@@ -165,10 +163,10 @@ def engines():
     common.run_discovery(ridx, queries, engine="batched")  # warm
     identical = int(
         all(
-            [(e.table_id, e.joinability) for e in discover_batched(
-                ridx, q, c, k=common.K)[0]]
-            == [(e.table_id, e.joinability) for e in discover_batched(
-                idx, q, c, k=common.K)[0]]
+            [(e.table_id, e.joinability) for e in discover_many(
+                ridx, [(q, c)], k=common.K)[0][0]]
+            == [(e.table_id, e.joinability) for e in discover_many(
+                idx, [(q, c)], k=common.K)[0][0]]
             for q, c in queries
         )
     )

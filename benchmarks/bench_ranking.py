@@ -26,7 +26,7 @@ import time
 
 from benchmarks import common
 from repro.core import xash
-from repro.core.batched import discover_batched
+from repro.core.batched import discover_many
 from repro.core.index import MateIndex
 
 N_KEYS = 20
@@ -58,11 +58,11 @@ def ranking_bench():
     idx = MateIndex(corpus, cfg=xash.XashConfig(bits=BITS))
     k = N_GOOD + N_BAD  # retrieve every planted table; rank decides order
 
-    count_rank, count_stats = discover_batched(idx, query, q_cols, k=k)
+    count_rank, count_stats = discover_many(idx, [(query, q_cols)], k=k)[0]
     t0 = time.perf_counter()
-    quality, qstats = discover_batched(
-        idx, query, q_cols, k=k, rank="quality", profile_gate=True
-    )
+    quality, qstats = discover_many(
+        idx, [(query, q_cols)], k=k, rank="quality", profile_gate=True
+    )[0]
     dt_us = (time.perf_counter() - t0) * 1e6
 
     def key(entries):

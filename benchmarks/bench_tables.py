@@ -77,9 +77,9 @@ def table_engines():
             dt, st = common.run_discovery(idx, queries, engine=engine)
             times[engine] = dt
             out[(gname, engine)] = (dt, st)
-            # per-batch transfer behaviour (device-side rule 1/2): fraction
-            # of the match matrix materialised on the host — counts vector +
-            # verification slices on the device path.  Undefined for the
+            # launch transfer behaviour: fraction of the match matrix
+            # materialised on the host — all of it on the non-fused
+            # backends, which read it back in one transfer.  Undefined for the
             # scalar engine (no match matrix), so only batched/many rows
             # carry the field.
             rb = ""
@@ -188,7 +188,7 @@ def serving():
     """
     import numpy as np
 
-    from repro.core.batched import discover_batched
+    from repro.core.batched import discover_many
     from repro.core.session import DiscoveryConfig, MateSession
     from repro.data import synthetic
     from repro.serve.engine import DiscoveryEngine
@@ -214,10 +214,10 @@ def serving():
     # the cold reference must run the raw engine with the same knobs
     cold = {
         qi: key(
-            discover_batched(
-                idx, *distinct[qi], k=common.K,
+            discover_many(
+                idx, [distinct[qi]], k=common.K,
                 rank="quality", profile_gate=True,
-            )[0]
+            )[0][0]
         )
         for qi in sorted(set(traffic.tolist()))
     }
@@ -259,9 +259,9 @@ def serving():
         req = eng.discover(q, q_cols, k=5)
         lat2.append(time.perf_counter() - t0)
         identical2 &= key(req.results) == key(
-            discover_batched(
-                idx, q, q_cols, k=5, rank="quality", profile_gate=True
-            )[0]
+            discover_many(
+                idx, [(q, q_cols)], k=5, rank="quality", profile_gate=True
+            )[0][0]
         )
     lat2_us = np.asarray(lat2) * 1e6
     common.emit(

@@ -14,7 +14,7 @@ sys.path.insert(0, __file__.rsplit("/", 2)[0] + "/src")
 import numpy as np
 
 from repro.core import discovery, xash
-from repro.core.batched import discover_batched, discover_many, filter_outcomes
+from repro.core.batched import discover_many, filter_outcomes
 from repro.core.corpus import Corpus, Table
 from repro.core.index import MateIndex
 from repro.data import synthetic
@@ -148,8 +148,8 @@ def fp_outcomes(idx, queries, check_false_negatives: bool = False) -> dict:
 def run_discovery(idx, queries, k=K, row_filter=True, engine="seq"):
     """Returns (seconds_total, aggregate stats).
 
-    Engines: ``seq`` (faithful Alg. 1), ``batched`` (kernel-backed blocks,
-    registry-resolved backend: Pallas on TPU / XLA fallback on CPU),
+    Engines: ``seq`` (faithful Alg. 1), ``batched`` (the kernel-backed
+    engine, one request per filter launch, registry-resolved backend),
     ``batched_np`` (same engine, backend='numpy'), ``many`` (all queries
     share one filter launch — the DiscoveryEngine path), plus
     ``batched_fused`` / ``many_fused`` (backend='fused': the fused
@@ -175,18 +175,18 @@ def run_discovery(idx, queries, k=K, row_filter=True, engine="seq"):
             )
         ]
     else:
+        solo_backend = {
+            "batched": None,
+            "batched_fused": "fused",
+            "batched_gather": "fused-gather",
+            "batched_np": "numpy",
+        }
         stats = []
         for q, q_cols in queries:
-            if engine == "batched":
-                _, st = discover_batched(idx, q, q_cols, k=k)
-            elif engine == "batched_fused":
-                _, st = discover_batched(idx, q, q_cols, k=k, backend="fused")
-            elif engine == "batched_gather":
-                _, st = discover_batched(
-                    idx, q, q_cols, k=k, backend="fused-gather"
-                )
-            elif engine == "batched_np":
-                _, st = discover_batched(idx, q, q_cols, k=k, backend="numpy")
+            if engine in solo_backend:
+                _, st = discover_many(
+                    idx, [(q, q_cols)], k=k, backend=solo_backend[engine]
+                )[0]
             else:
                 _, st = discovery.discover(idx, q, q_cols, k=k, row_filter=row_filter)
             stats.append(st)

@@ -1,60 +1,46 @@
 """Batched kernel-backed discovery engine — the beyond-paper fast path.
 
 The faithful Algorithm 1 (discovery.py) is a branchy per-row scan: ideal on a
-CPU, hostile to a vector unit.  This engine restructures the online phase into
-contiguous blocks fed straight to the §6.3 filter kernel:
+CPU, hostile to a vector unit.  This engine restructures the online phase
+into three steps — plan, one filter launch, score — each over contiguous
+blocks:
 
-  * query-side key hashing is ONE batched ``xash.superkey`` call
-    (``MateIndex.superkey_of_keys``), not per-value host hashing;
-  * candidate posting lists are gathered into a CSR block per query
-    (``MateIndex.gather_candidates``): rows, value indices and table
+  * **plan** (``plan_query``): query-side key hashing is ONE batched
+    ``xash.superkey`` call (``MateIndex.superkey_of_keys``), not per-value
+    host hashing; candidate posting lists are gathered into a CSR block per
+    query (``MateIndex.gather_candidates``): rows, value indices and table
     boundaries as three contiguous arrays — no per-row dict lookups;
-  * the row filter runs as one subsumption launch per table batch through
-    ``kernels.ops.filter_hits_table_counts`` (Pallas ``filter_kernel`` on
-    TPU, vectorised XLA fallback on CPU); value/key eligibility rides along
-    as two id vectors (each item's and each key's init value) that the
-    launch compares tile by tile, so match extraction is ``np.nonzero``
-    over per-table slices — no Python loop over PL items;
-  * the rule-1/rule-2 joinability bound check is DEVICE-SIDE in
-    ``discover_batched``: each launch also reduces the match matrix to
-    per-table eligible-hit counts (a matvec row-reduction + segment-sum over
-    the CSR table ids), and only that tiny int32 counts vector is read back
-    per batch.  The full ``[rows × keys]`` match matrix is never transferred
-    to the host — per surviving (un-pruned) table, just its row slice of the
-    hit matrix is read back for exact verification (or one prefetch of the
-    batch when the entry bound leaves most items alive anyway);
-  * on the FUSED path (``backend='fused'`` — the TPU platform default, also
-    selectable via ``MATE_FILTER_BACKEND=fused``; see ``kernels.registry``
-    for the one precedence rule) the reduction happens INSIDE the filter kernel
-    (``filter_kernel.filter_table_counts``): subsumption ∧ eligibility is
-    row-summed and scatter-accumulated over the CSR table ids in VMEM, so
-    the match matrix never exists even in HBM — counts-only readback,
-    ``DiscoveryStats.filter_matrix_bytes == 0``, and surviving tables'
-    slices are recomputed on demand for verification.  ``discover_many``
-    uses the same fused group launch, so requests pruned by the evolving
-    bounds never pay for their block of the cross-product matrix;
-  * ``backend='fused-gather'`` (the TPU platform default) additionally
-    fuses the CANDIDATE GATHER into that launch: the kernel takes the CSR
-    posting-list row offsets and DMA-gathers each row block from the
-    device-resident superkey store (``MateIndex.device_store()``, refreshed
-    on §5.4 mutation epochs) straight into VMEM — the host never gathers the
-    candidate superkeys and the gathered rows×lanes block never exists in
-    HBM (``DiscoveryStats.gather_bytes_saved`` counts the traffic avoided).
-    Demotes to 'fused' when the store is over the device budget, counted in
+    value/key eligibility is two id vectors (each item's and each key's init
+    value) that the launch compares tile by tile;
+  * **launch** (``plan_and_count``): every request of a group shares ONE
+    §6.3 subsumption launch through ``kernels.ops.filter_hits_table_counts``,
+    which also reduces the match matrix to per-table eligible-hit counts
+    (a row reduction + segment-sum over the CSR table ids).  On the FUSED
+    backends (``'fused'``, and ``'fused-gather'`` — the TPU platform
+    default; see ``kernels.registry`` for the one precedence rule) the
+    reduction happens INSIDE the filter kernel, so the match matrix never
+    exists even in HBM and only the counts vector comes back;
+    ``'fused-gather'`` additionally DMA-gathers each candidate row block
+    from the device-resident superkey store (``MateIndex.device_store()``,
+    refreshed on §5.4 mutation epochs), so the host never gathers the
+    candidate superkeys either (``DiscoveryStats.gather_bytes_saved``).  It
+    demotes to 'fused' when the store is over the device budget, counted in
     ``DiscoveryStats.gather_demotions``; launches over the scatter tile's
-    table cap split into table chunks (``kernels.ops.table_chunks``);
-  * tables are visited in the same descending posting-list order as
-    Algorithm 1; rule 1 (global cutoff) applies BETWEEN batches — identical
-    pruning guarantee, since the bound only improves as the scan proceeds;
-  * rule 2 becomes a *stronger* bound: the exact filtered-candidate count per
-    table (the device-side counts vector) replaces the paper's incremental
+    table cap split into table chunks (``kernels.ops.table_chunks``).  The
+    other backends return the match matrix to the host in one transfer;
+  * **score** (``score_from_counts``): tables are visited in the same
+    descending posting-list order as Algorithm 1; rule 1 (the global
+    cutoff: the first table whose posting list is at/below the bound prunes
+    the whole suffix) and a *stronger* rule 2 apply — the exact
+    filtered-candidate count per table replaces the paper's incremental
     ``L_t - r_checked + r_match`` bound, so strictly more tables are skipped
-    before verification.  It applies before the heap fills too: the bound
-    is then 0, so a table with no filter-surviving pair (joinability 0,
-    which the heap would discard) is skipped without a re-gather;
+    before verification.  Rule 2 applies before the heap fills too: the
+    bound is then 0, so a table with no filter-surviving pair (joinability
+    0, which the heap would discard) is skipped without a re-gather;
   * only filter-surviving pairs are verified on the host: the faithful
     engine's exact ``calculateJ``, computed on lake value ids in numpy
-    rather than one string comparison per pair.
+    rather than one string comparison per pair.  Where the launch kept no
+    match matrix, a surviving table re-tests just its own eligible pairs.
 
 Hash width is a first-class knob: every array here is ``lanes``-wide
 (``XashConfig(bits=...)`` → 4/8/16 uint32 lanes for 128/256/512 bits), so the
@@ -62,9 +48,9 @@ same engine and kernels serve any width the index was built at — the paper's
 Table 1/2 FP-rate vs filter-bandwidth tradeoff (see
 ``benchmarks/bench_fp_rate.py``).
 
-``discover_many`` extends this to multi-query batching: all requests' rows
-and keys concatenate into ONE filter launch, then demux per request — the
-shape ``serve.engine.DiscoveryEngine`` uses for concurrent traffic.
+``discover_many`` is the composition for a list of requests;
+``session.MateSession.discover`` and ``serve.engine.DiscoveryEngine`` call
+the two phases themselves, the latter so its caches can sit between them.
 
 Top-k results are BIT-IDENTICAL to Algorithm 1 (ids, joinability scores and
 mappings): both engines visit tables in the same order with the same
@@ -89,8 +75,6 @@ from repro.core.discovery import DiscoveryStats, TopKEntry
 from repro.core.index import CandidateBlock, MateIndex
 from repro.kernels import ops, registry
 from repro.kernels.registry import Backend
-
-DEFAULT_BATCH_TABLES = 256
 
 
 @dataclasses.dataclass
@@ -204,20 +188,20 @@ def plan_query(
     )
 
 
-def _segment_ids(table_ptr: np.ndarray, t_start: int, t_stop: int) -> np.ndarray:
-    """int32 per-item table index (relative to t_start) for a CSR range."""
-    lengths = np.diff(table_ptr[t_start : t_stop + 1])
+def _segment_ids(table_ptr: np.ndarray) -> np.ndarray:
+    """int32 per-item table index of a CSR block."""
     return np.repeat(
-        np.arange(t_stop - t_start, dtype=np.int32), lengths
+        np.arange(table_ptr.shape[0] - 1, dtype=np.int32), np.diff(table_ptr)
     )
 
 
 def _hits_counts_host(row_sk, q_sk, elig, seg, n_tables, backend: Backend):
     """Host-side hits + per-table counts: one filter launch, full readback.
 
-    The right call when the top-k bound cannot prune yet (heap not full) —
-    every hit block is about to be verified anyway, so fusing the count
-    reduction into the launch would add device work without saving a byte.
+    The non-fused backends' launch: every request starts with an empty heap
+    (entry bound 0), so most hit blocks are about to be verified anyway and
+    fusing the count reduction into the launch would add device work
+    without saving a byte.
     """
     if not backend.device:
         return ops.filter_hits_table_counts(
@@ -388,62 +372,40 @@ def _ranked_entries(
     return entries
 
 
-# below this fraction of batch items surviving the entry bound, per-table
-# hit-slice readbacks beat one whole-batch transfer (dispatch vs bytes)
-_PREFETCH_FRAC = 0.25
-
-
 def _score_tables(
     index: MateIndex,
     plan: QueryPlan,
     topk: _TopK,
-    hits,
+    hits: np.ndarray | None,
     counts: np.ndarray,
-    rows: np.ndarray,
-    t_start: int,
-    t_stop: int,
-    base: int,
-    rule1: bool = False,
     row_sk: np.ndarray | None = None,
-    prefetch_frac: float = _PREFETCH_FRAC,
 ) -> None:
-    """Verify (or rule-2-prune) tables [t_start, t_stop) of the plan's block,
-    whose items live at ``block`` offsets ``base:`` covered by hits/rows.
+    """Verify (or rule-1/rule-2-prune) every table of the plan's block.
 
-    ``hits`` may be device-resident (jnp) and is only read back as needed:
-    the rule-2 bound is checked against ``counts`` (the device-computed
-    per-table eligible-hit counts, indexed relative to ``t_start``), so
-    pruned tables never transfer their slice.  When the bound at entry
-    leaves most items alive anyway, the whole range is prefetched in ONE
-    transfer instead of per-table dispatches; counts are exact, so the
-    evolving-bound pruning decisions below are identical either way.
-
-    ``hits`` may also be None — the FUSED counts-only launch, where the
-    match matrix was never produced at all.  Surviving tables' hits are
-    then recomputed on demand from ``row_sk``, testing only the table's
-    eligible (row, key) pairs (``_eligible_pairs``: the same pairs as
-    ``plan.elig``, the same subsumption predicate → bit-identical
-    verification inputs; ``regather_pairs`` counts the pairs tested);
-    pruned tables cost
-    nothing beyond their 4 count bytes.  On the GATHER-fused path even
+    ``counts`` is the launch's per-table eligible-hit counts; ``hits`` is
+    the plan's host slice of the match matrix, or None — the FUSED
+    counts-only launch, where the match matrix was never produced at all
+    (and every bound-cache replay).  Surviving tables' hits are then
+    recomputed on demand from ``row_sk``, testing only the table's eligible
+    (row, key) pairs (``_eligible_pairs``: the same pairs as ``plan.elig``,
+    the same subsumption predicate → bit-identical verification inputs;
+    ``regather_pairs`` counts the pairs tested); pruned tables cost nothing
+    beyond their 4 count bytes.  On the GATHER-fused and routed paths even
     ``row_sk`` is None — the host never gathered the candidate superkeys —
     and surviving tables gather just their own slice from the index store
     (the same ``superkeys`` array every other path reads: bit-identical).
 
-    Rule 2 prunes a table whenever its count is at or below
-    ``topk.bound()``, full heap or not: before the heap fills the bound is
-    0, so exactly the tables with no filter-surviving pair are skipped
-    (counted in ``tables_pruned_empty`` as well as ``tables_pruned_rule2``).
-    The filter has no false negatives, so joinability ≤ count, and the heap
-    discards joinability 0 — the top-k and the verified pairs are unchanged.
-
-    ``rule1=True`` additionally applies the paper's rule 1 inside the range
-    (tables are PL-desc sorted → the first at/below the bound prunes the
-    whole suffix) — the ``discover_many`` path, where the filter already ran
-    for every table and only verification work remains to be skipped.
+    Rule 1: tables are PL-desc sorted, so the first whose posting list is
+    at/below the bound of a full heap prunes the whole suffix.  Rule 2
+    prunes a table whenever its count is at or below ``topk.bound()``, full
+    heap or not: before the heap fills the bound is 0, so exactly the tables
+    with no filter-surviving pair are skipped (counted in
+    ``tables_pruned_empty`` as well as ``tables_pruned_rule2``).  The filter
+    has no false negatives, so joinability ≤ count, and the heap discards
+    joinability 0 — the top-k and the verified pairs are unchanged.
     """
     block, stats = plan.block, plan.stats
-    ptr = block.table_ptr
+    ptr, rows, n_tables = block.table_ptr, block.rows, block.n_tables
     lazy = hits is None
     # per-table clocks only while tracing: the slice re-gather and the exact
     # verification, summed into the score.tables span
@@ -458,28 +420,17 @@ def _score_tables(
             stats.tables_pruned_empty,
             stats.regather_pairs,
         )
-    device_hits = (not lazy) and not isinstance(hits, np.ndarray)
-    if device_hits:
-        alive = counts[: t_stop - t_start] > topk.bound()
-        n_alive = int(
-            (alive * np.diff(ptr[t_start : t_stop + 1])).sum()
-        )
-        total = int(ptr[t_stop] - ptr[t_start])
-        if total and n_alive >= prefetch_frac * total:
-            hits = np.asarray(hits)
-            stats.filter_readback_bytes += hits.size
-            device_hits = False
-    for t in range(t_start, t_stop):
-        if rule1 and topk.full and int(ptr[t + 1] - ptr[t]) <= topk.bound():
-            stats.tables_pruned_rule1 += t_stop - t
+    for t in range(n_tables):
+        if topk.full and int(ptr[t + 1] - ptr[t]) <= topk.bound():
+            stats.tables_pruned_rule1 += n_tables - t
             break
         stats.tables_evaluated += 1
         tid = int(block.table_ids[t])
-        lo, hi = int(ptr[t]) - base, int(ptr[t + 1]) - base
+        lo, hi = int(ptr[t]), int(ptr[t + 1])
         # strengthened rule 2: exact filtered-candidate count bound, from the
-        # device-side counts — no match-matrix transfer for pruned tables.
+        # launch's counts — no match-matrix work for pruned tables.
         # Before the heap fills the bound is 0: empty tables are skipped.
-        if int(counts[t - t_start]) <= topk.bound():
+        if int(counts[t]) <= topk.bound():
             stats.tables_pruned_rule2 += 1
             stats.tables_pruned_empty += int(not topk.full)
             continue
@@ -499,9 +450,7 @@ def _score_tables(
             # booked as the slice of the match matrix the pairs stand for
             stats.filter_readback_bytes += (hi - lo) * plan.q_sk.shape[0]
         else:
-            sub = np.asarray(hits[lo:hi])
-            if device_hits:
-                stats.filter_readback_bytes += sub.size
+            sub = hits[lo:hi]
         if timed:
             t_b = clock()
             regather_ns += t_b - t_a
@@ -529,185 +478,6 @@ def _score_tables(
         )
 
 
-def discover_batched(
-    index: MateIndex,
-    query: Table,
-    q_cols: list[int],
-    k: int = 10,
-    batch_tables: int = DEFAULT_BATCH_TABLES,
-    init_mode: str = "cardinality",
-    backend: Backend | str | None = None,
-    *,
-    prefetch_frac: float = _PREFETCH_FRAC,
-    fused_block_n: int | None = None,
-    filter_lanes: int | None = None,
-    rank: str = "count",
-    profile_gate: bool = False,
-) -> tuple[list[TopKEntry], DiscoveryStats]:
-    """Batched Algorithm 1: one filter launch per ``batch_tables`` tables.
-
-    ``profile_gate=True`` pre-filters the candidate block against the
-    column-profile store (see ``plan_query``) — pure pruning, set-identical.
-    ``rank='quality'`` runs the ``core.ranking`` scoring head over each
-    batch's counts vector (one extra launch per batch) and reorders the
-    returned entries by join quality; the heap — and therefore the verified
-    top-k SET — is untouched.  The raw engines default to the historical
-    ``rank='count'``/gate-off behaviour; ``DiscoveryConfig`` flips both
-    defaults at the session layer.
-
-    Per batch, the device computes the subsumption matrix ∧ eligibility AND
-    reduces it to per-table hit counts; only that counts vector (4 bytes per
-    table) is read back for the rule-1/rule-2 bound checks.  Hit-matrix
-    slices are transferred solely for tables that survive pruning and need
-    exact verification.
-
-    ``backend`` selects the §6.3 filter implementation (a resolved
-    ``kernels.registry.Backend`` or a registered name); None follows the
-    registry precedence: ``MATE_FILTER_BACKEND`` env var, then the platform
-    default (fused on TPU, size-based auto split elsewhere).  On 'fused' the
-    match matrix is never materialised — not even in HBM — so
-    ``stats.filter_matrix_bytes`` stays 0 and surviving tables' slices are
-    recomputed on demand.  The pre-registry ``use_kernel=``/``fused=`` shims
-    were removed after their one-release deprecation window (PR 4): passing
-    them raises TypeError; pin the path with ``backend=`` instead
-    (``use_kernel=False`` -> 'numpy', ``fused=True`` -> 'fused',
-    ``fused=False`` -> 'pallas').
-
-    ``filter_lanes`` runs the filter launches over only the first N uint32
-    lanes of the super keys (the serving tier's pressure-degrade path:
-    ``filter_lanes=4`` ≙ 128-bit filtering on a wider index).  A lane-prefix
-    subsumption test is a pure relaxation of the full-width test — zero
-    false negatives — so after exact verification the top-k is BIT-IDENTICAL
-    to the full-width run; only filter precision (and the rule-2 bound
-    tightness) degrades.
-    """
-    bk = registry.resolve_backend(backend)
-    plan = plan_query(index, query, q_cols, init_mode, profile_gate=profile_gate)
-    stats, block = plan.stats, plan.block
-    q_sketch = (
-        ranking.query_sketch(index, plan.distinct_keys)
-        if rank == "quality"
-        else None
-    )
-    scores: dict[int, float] = {}
-    full_lanes = plan.q_sk.shape[1]
-    fl = full_lanes if filter_lanes is None else max(1, min(int(filter_lanes), full_lanes))
-    stats.filter_lanes = fl
-    q_f = plan.q_sk if fl == full_lanes else plan.q_sk[:, :fl]
-    # routed index (core.routing.ShardedMateIndex): there IS no global
-    # superkey array or single device store — the filter diverts to
-    # shard-local counts-only launches and only count vectors cross shards.
-    routed = getattr(index, "routed", False)
-    # gather-fused: the engine decides per batch whether the device store
-    # carries the gather (store fits + the batch is under the scatter-tile
-    # cap), because only then may the host skip its own superkey gather.
-    store = (
-        index.device_store()
-        if not routed and bk.gather and ops.gather_store_fits(index.superkeys)
-        else None
-    )
-    # a gather backend without a store is the one counted demotion: the
-    # host gathers the candidate superkeys for the fused launch instead
-    demoted = not routed and bk.gather and store is None
-    topk = _TopK(k)
-    n_tables = block.n_tables
-    for start in range(0, n_tables, batch_tables):
-        stop = min(start + batch_tables, n_tables)
-        # rule 1 between batches: tables are PL-desc sorted, so if the FIRST
-        # table of the batch is at/below the bound, everything after is too.
-        # (PL lengths are CSR metadata the host already owns — no transfer.)
-        first_count = int(block.table_ptr[start + 1] - block.table_ptr[start])
-        if topk.full and first_count <= topk.bound():
-            stats.tables_pruned_rule1 += n_tables - start
-            break
-        lo, hi = int(block.table_ptr[start]), int(block.table_ptr[stop])
-        rows = block.rows[lo:hi]
-        use_gather = store is not None
-        # the gather-fused contract: the host NEVER touches the candidate
-        # superkeys — the kernel DMA-gathers them from the device store.
-        # The routed contract is stricter still: the host never gathers a
-        # WHOLE batch at all; surviving tables re-gather from their owning
-        # shard in _score_tables (index.superkey_of_rows routes per shard).
-        row_sk = (
-            None if (use_gather or routed) else index.superkey_of_rows(rows)
-        )
-        row_f = (
-            None if row_sk is None
-            else row_sk if fl == full_lanes else row_sk[:, :fl]
-        )
-        elig = plan.elig[lo:hi]
-        seg = _segment_ids(block.table_ptr, start, stop)
-        stats.pl_items_checked += int(rows.shape[0])
-        stats.filter_checks += eligible_count(plan, block.value_idx[lo:hi])
-        if routed:
-            # shard-local counts-only launches, count-merge across shards:
-            # the only cross-shard bytes are stats.route_bytes_merged.
-            hits = None
-            counts = index.routed_counts(
-                rows, q_f, elig, seg, stop - start,
-                backend=bk, fused_block_n=fused_block_n, stats=stats,
-            )
-        elif use_gather:
-            # one launch from posting-list offsets to counts: n×4 offset
-            # bytes go to the device instead of n×lanes×4 gathered key bytes
-            # (and the gathered block never exists in HBM either).
-            hits, counts = ops.filter_hits_table_counts(
-                None, q_f, elig, seg, stop - start, backend=bk,
-                fused_block_n=fused_block_n, store=store, rows=rows,
-            )
-            stats.filter_fused_launches += 1
-            stats.gather_bytes_saved += int(rows.shape[0]) * (fl * 4 - 4)
-        elif bk.fused:
-            # fused filter+segment-count launch: the match matrix is never
-            # produced (zero filter_matrix_bytes), only the counts vector
-            # comes back; surviving tables' slices are recomputed on demand
-            # in _score_tables.
-            hits, counts = ops.filter_hits_table_counts(
-                row_f, q_f, elig, seg, stop - start, backend=bk,
-                fused_block_n=fused_block_n,
-            )
-            stats.filter_fused_launches += 1
-            stats.gather_demotions += int(demoted)
-        elif bk.device and topk.full and topk.bound() > 0:
-            # bound can prune → composed device launch: hits stay on device,
-            # only the per-table counts vector is read back; surviving
-            # tables' slices transfer lazily in _score_tables.
-            stats.filter_matrix_bytes += int(rows.shape[0]) * q_f.shape[0]
-            hits, counts = ops.filter_hits_table_counts(
-                row_f, q_f, elig, seg, stop - start, backend=bk,
-            )
-        else:
-            # heap not full (bound 0): only empty tables can be pruned,
-            # most hit blocks are about to be verified — single-transfer path.
-            stats.filter_matrix_bytes += int(rows.shape[0]) * q_f.shape[0]
-            hits, counts = _hits_counts_host(
-                row_f, q_f, elig, seg, stop - start, bk
-            )
-        # readback = match-matrix bytes materialised host-side: the whole
-        # matrix when any path produced host hits (size-based numpy
-        # dispatch included), else the counts vector now + surviving
-        # slices lazily in _score_tables.
-        if isinstance(hits, np.ndarray):
-            stats.filter_readback_bytes += hits.size
-        else:
-            stats.filter_readback_bytes += counts.nbytes
-        stats.filter_passed += int(counts.sum())
-        if rank == "quality":
-            batch_ids = block.table_ids[start:stop]
-            with telemetry.span("score.rank"):
-                sc = ranking.quality_scores(
-                    index, batch_ids, np.asarray(counts),
-                    len(plan.distinct_keys), q_sketch, stats=stats,
-                )
-            scores.update(zip(batch_ids.tolist(), sc.tolist()))
-        with telemetry.span("score.tables"):
-            _score_tables(
-                index, plan, topk, hits, counts, rows, start, stop, lo,
-                row_sk=row_sk, prefetch_frac=prefetch_frac,
-            )
-    return _ranked_entries(topk, rank, scores), stats
-
-
 @dataclasses.dataclass
 class PlanCounts:
     """Phase-A artifact of the two-phase group engine: one request's plan
@@ -732,9 +502,9 @@ class PlanCounts:
     row_sk: np.ndarray | None  # uint32[n_items, lanes] full-width row super
     # keys (None: gather-fused launch — scoring reads the index store)
     counts: np.ndarray  # int32[n_tables] per-table eligible-hit counts
-    hits: object = None  # np/jnp [n_items, group_keys] slice, or None
+    hits: np.ndarray | None = None  # bool[n_items, n_keys]: the host match
+    # matrix's block of this plan's rows and keys
     group_keys: int = 0  # key count of the SHARED launch (accounting)
-    hits_host: bool = False  # group matrix came back host-side (np)
     fused: bool = False  # counts-only fused launch (no matrix anywhere)
     filter_lanes: int = 0  # lanes the launch probed (< index width: degraded)
     epoch: int = 0  # index.mutation_epoch at launch time
@@ -750,10 +520,10 @@ class PlanCounts:
     shard_gather_demotions: int = 0
 
     def cacheable(self) -> "PlanCounts":
-        """A copy safe to hold in a cache: the (possibly device-resident)
-        match-matrix slice is dropped; scoring recomputes surviving tables'
-        slices from ``row_sk`` on demand — same subsumption predicate, so
-        verification inputs (and the top-k) are bit-identical."""
+        """A copy safe to hold in a cache: the match-matrix slice is
+        dropped; scoring recomputes surviving tables' slices from ``row_sk``
+        on demand — same subsumption predicate, so verification inputs (and
+        the top-k) are bit-identical."""
         return dataclasses.replace(self, hits=None)
 
 
@@ -782,10 +552,13 @@ def plan_and_count(
     seam: a hot query's ``PlanCounts`` can be stored and re-scored later —
     at a different ``k`` even — without touching the index or the device.
 
-    ``filter_lanes`` restricts the launch to a lane prefix of the super
-    keys (the pressure-degrade path, see ``discover_batched``): a pure
-    relaxation — zero false negatives — so downstream verification still
-    yields bit-identical top-k.
+    ``filter_lanes`` runs the launch over only the first N uint32 lanes of
+    the super keys (the serving tier's pressure-degrade path:
+    ``filter_lanes=4`` ≙ 128-bit filtering on a wider index).  A lane-prefix
+    subsumption test is a pure relaxation of the full-width test — zero
+    false negatives — so after exact verification the top-k is
+    BIT-IDENTICAL to the full-width run; only filter precision (and the
+    rule-2 bound tightness) degrades.
     """
     bk = registry.resolve_backend(backend)
     plans = [
@@ -815,7 +588,7 @@ def plan_and_count(
             key_value.append(p.elig.key_value + v_off)
             if ni:
                 seg_all[r_off : r_off + ni] = n_tables_all + _segment_ids(
-                    p.block.table_ptr, 0, ti
+                    p.block.table_ptr
                 )
             r_off += ni
             v_off += p.value_key_ptr.size - 1
@@ -872,13 +645,11 @@ def plan_and_count(
             backend=bk, fused_block_n=fused_block_n,
         )
     else:
-        # ONE subsumption launch for the whole group.  Unlike
-        # ``discover_batched`` (whose later batches are often pruned
-        # without any matrix transfer), every request here starts with an
-        # empty heap (entry bound 0), so most plans' hit blocks are
-        # needed for verification — the matrix comes back to the host in
-        # one transfer and the per-table rule-1/2 counts are a cheap
-        # host reduction over it.
+        # ONE subsumption launch for the whole group.  Every request
+        # starts with an empty heap (entry bound 0), so most plans' hit
+        # blocks are needed for verification — the matrix comes back to
+        # the host in one transfer and the per-table rule-1/2 counts are a
+        # cheap host reduction over it.
         hits_all, counts_all = _hits_counts_host(
             row_f, q_f, group_elig, seg_all, n_tables_all, bk,
         )
@@ -906,7 +677,6 @@ def plan_and_count(
                 hits=None if hits_all is None
                 else hits_all[r_off : r_off + ni, k_off : k_off + ki],
                 group_keys=0 if hits_all is None else int(hits_all.shape[1]),
-                hits_host=isinstance(hits_all, np.ndarray),
                 fused=hits_all is None,
                 filter_lanes=fl,
                 epoch=epoch,
@@ -923,12 +693,42 @@ def plan_and_count(
     return out
 
 
+def _scoring_plan(pc: PlanCounts, *, charge_launch: bool = True) -> QueryPlan:
+    """A copy of ``pc``'s plan whose stats (a FRESH copy too) carry what
+    phase A did for the request — so one ``PlanCounts`` can be scored any
+    number of times.  ``charge_launch`` adds the request's share of the
+    shared launch's transfers: on the fused counts-only launch its counts
+    vector (and its gather, routing and demotion counters); otherwise its
+    rows against the GROUP's keys, read back to the host — the documented
+    cross-product trade."""
+    plan = dataclasses.replace(pc.plan, stats=dataclasses.replace(pc.plan.stats))
+    stats, block = plan.stats, plan.block
+    n_items = block.n_items
+    stats.pl_items_checked = n_items
+    stats.filter_checks = eligible_count(plan, block.value_idx)
+    stats.filter_passed = int(pc.counts.sum())
+    stats.filter_lanes = pc.filter_lanes
+    if not charge_launch:
+        return plan
+    if pc.fused:
+        stats.filter_fused_launches += 1
+        stats.filter_readback_bytes += pc.counts.nbytes
+        stats.gather_bytes_saved += pc.gather_saved
+        stats.shard_launches += pc.route_launches
+        stats.route_bytes_merged += pc.route_bytes
+        stats.gather_demotions += pc.gather_demotions
+        stats.shard_gather_demotions += pc.shard_gather_demotions
+    else:
+        stats.filter_matrix_bytes += n_items * pc.group_keys
+        stats.filter_readback_bytes += n_items * pc.group_keys
+    return plan
+
+
 def score_from_counts(
     index: MateIndex,
     pc: PlanCounts,
     k: int = 10,
     *,
-    prefetch_frac: float = _PREFETCH_FRAC,
     from_cache: bool = False,
     rank: str = "count",
 ) -> tuple[list[TopKEntry], DiscoveryStats]:
@@ -946,33 +746,8 @@ def score_from_counts(
     (an earlier request already paid for the filter) and forces the
     lazy-recompute path, since cached entries hold no matrix slice.
     """
-    plan = dataclasses.replace(pc.plan, stats=dataclasses.replace(pc.plan.stats))
+    plan = _scoring_plan(pc, charge_launch=not from_cache)
     stats, block = plan.stats, plan.block
-    n_items = block.n_items
-    stats.pl_items_checked = n_items
-    stats.filter_checks = eligible_count(plan, block.value_idx)
-    stats.filter_passed = int(pc.counts.sum())
-    stats.filter_lanes = pc.filter_lanes
-    hits = pc.hits
-    if from_cache:
-        hits = None
-    elif pc.fused:  # fused counts-only group launch succeeded
-        stats.filter_fused_launches += 1
-        stats.filter_readback_bytes += pc.counts.nbytes
-        stats.gather_bytes_saved += pc.gather_saved
-        stats.shard_launches += pc.route_launches
-        stats.route_bytes_merged += pc.route_bytes
-        stats.gather_demotions += pc.gather_demotions
-        stats.shard_gather_demotions += pc.shard_gather_demotions
-    else:
-        # the shared launch computes (and reads back) this plan's rows
-        # against the GROUP's keys — the documented cross-product trade.
-        # (device-resident hits — the fused→composed table-cap fallback —
-        # transfer lazily in _score_tables, which does its own readback
-        # accounting.)
-        stats.filter_matrix_bytes += n_items * pc.group_keys
-        if pc.hits_host:
-            stats.filter_readback_bytes += n_items * pc.group_keys
     scores: dict[int, float] = {}
     if rank == "quality" and block.n_tables:
         with telemetry.span("score.rank"):
@@ -983,14 +758,10 @@ def score_from_counts(
             )
             scores = dict(zip(block.table_ids.tolist(), sc.tolist()))
     topk = _TopK(k)
-    # rule 1 (PL-desc suffix pruning) applies inside the range: the filter
-    # already ran batched for every table, only verification work and
-    # hit-slice readbacks (or fused recomputes) remain to be skipped.
     with telemetry.span("score.tables"):
         _score_tables(
-            index, plan, topk, hits, pc.counts, block.rows, 0, block.n_tables, 0,
-            rule1=True, row_sk=pc.row_sk,
-            prefetch_frac=prefetch_frac,
+            index, plan, topk, None if from_cache else pc.hits, pc.counts,
+            row_sk=pc.row_sk,
         )
     return _ranked_entries(topk, rank, scores), stats
 
@@ -1002,35 +773,36 @@ def discover_many(
     init_mode: str = "cardinality",
     backend: Backend | str | None = None,
     *,
-    prefetch_frac: float = _PREFETCH_FRAC,
     fused_block_n: int | None = None,
     filter_lanes: int | None = None,
     rank: str = "count",
     profile_gate: bool = False,
 ) -> list[tuple[list[TopKEntry], DiscoveryStats]]:
-    """Multi-query discovery sharing ONE filter launch.
-
-    ``rank``/``profile_gate`` thread through both phases (see
-    ``plan_and_count`` and ``score_from_counts``): the gate shrinks each
-    request's candidate block before the shared launch, quality ranking
-    adds one scoring launch per request — neither changes the verified set.
+    """Multi-query discovery sharing ONE filter launch: ``plan_and_count``
+    (phase A: the shared launch) composed with ``score_from_counts``
+    (phase B: per-request scoring).  One request without a session is
+    ``discover_many(index, [(query, q_cols)], ...)[0]``.
 
     All requests' candidate rows and query keys concatenate into a single
-    subsumption launch; the match matrix is then demuxed per request and
-    scored with the same rule-1/rule-2 + heap semantics, so each request's
-    top-k is bit-identical to its solo ``discover``/``discover_batched`` run.
-    Internally this is ``plan_and_count`` (phase A: the shared launch)
-    composed with ``score_from_counts`` (phase B: per-request scoring) —
-    the seam the serving tier's caches plug into.
+    subsumption launch; the match matrix (or, on the fused backends, just
+    its per-table counts) is then demuxed per request and scored with the
+    same rule-1/rule-2 + heap semantics, so each request's top-k is
+    bit-identical to its scalar Algorithm 1 run (``core.discovery``).
 
-    ``backend`` resolves exactly as in ``discover_batched`` (and the removed
-    ``use_kernel=``/``fused=`` kwargs raise TypeError here too).  A 'fused'
-    backend swaps the group launch for the fused filter+segment-count kernel: the
-    (Σ rows × Σ keys) match matrix — the expensive part of the cross-product
-    trade below — is never materialised; only the group counts vector comes
-    back, and each request's surviving tables recompute their (own-keys-only)
-    hit slices on demand during scoring.  Requests pruned by the evolving
-    rule-1/2 bounds never pay for their matrix block at all.
+    ``backend`` selects the §6.3 filter implementation (a resolved
+    ``kernels.registry.Backend`` or a registered name); None follows the
+    registry precedence: ``MATE_FILTER_BACKEND`` env var, then the platform
+    default (fused-gather on TPU, size-based auto split elsewhere).  The
+    pre-registry ``use_kernel=``/``fused=`` keywords are gone: passing them
+    raises TypeError (``use_kernel=False`` -> 'numpy', ``fused=True`` ->
+    'fused', ``fused=False`` -> 'pallas').
+
+    ``rank``/``profile_gate`` thread through both phases: the gate shrinks
+    each request's candidate block before the shared launch, quality
+    ranking adds one scoring launch per request — neither changes the
+    verified set.  These raw functions default both off (``rank='count'``,
+    no gate); ``session.DiscoveryConfig`` turns both on at the session
+    layer.
 
     Cost note: the shared launch computes the full (Σ rows × Σ keys) cross
     product — only the block diagonal is consumed, so filter work grows
@@ -1048,10 +820,7 @@ def discover_many(
         fused_block_n=fused_block_n, profile_gate=profile_gate,
     )
     return [
-        score_from_counts(
-            index, pc, k_i, prefetch_frac=prefetch_frac, rank=rank
-        )
-        for pc, k_i in zip(pcs, ks)
+        score_from_counts(index, pc, k_i, rank=rank) for pc, k_i in zip(pcs, ks)
     ]
 
 

@@ -43,8 +43,8 @@ class DiscoveryStats:
     # batched-engine transfer accounting (device-side rule 1/2):
     filter_matrix_bytes: int = 0  # full match-matrix bytes the filter produced
     filter_readback_bytes: int = 0  # match bytes materialised host-side
-    # (counts vectors + verification slices on the device path; the whole
-    # matrix when a host/numpy dispatch produced it directly)
+    # (the whole matrix on the non-fused backends; on the fused ones the
+    # counts vector + the slices recomputed for surviving tables)
     filter_fused_launches: int = 0  # fused filter+segment-count launches:
     # the match matrix was never produced (not even in HBM), so these
     # contribute ZERO to filter_matrix_bytes — counts-only readback plus
@@ -80,7 +80,7 @@ class DiscoveryStats:
     gate_bytes_saved: int = 0  # superkey bytes the filter launches never
     # touched because the gate dropped those tables' posting items first
     # (items × lanes × 4, same units as gather_bytes_saved)
-    ranking_launches: int = 0  # quality-scoring launches (one per batch
+    ranking_launches: int = 0  # quality-scoring launches (one per request
     # under rank='quality'; see core.ranking.quality_scores)
     # FD-workload accounting (``core.fd.discover_fds``): counts-as-refutation
     # prunes candidate tables whose filter count upper bound is below

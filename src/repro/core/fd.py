@@ -158,26 +158,9 @@ def fds_from_counts(
     is epoch-pinned: an index mutated since the launch raises instead of
     validating against rows the filter never saw.
     """
-    plan = dataclasses.replace(pc.plan, stats=dataclasses.replace(pc.plan.stats))
+    plan = batched_lib._scoring_plan(pc)
     stats, block = plan.stats, plan.block
     query, det_cols = plan.query, plan.q_cols
-    n_items = block.n_items
-    stats.pl_items_checked = n_items
-    stats.filter_checks = batched_lib.eligible_count(plan, block.value_idx)
-    stats.filter_passed = int(pc.counts.sum())
-    stats.filter_lanes = pc.filter_lanes
-    if pc.fused:
-        stats.filter_fused_launches += 1
-        stats.filter_readback_bytes += pc.counts.nbytes
-        stats.gather_bytes_saved += pc.gather_saved
-        stats.shard_launches += pc.route_launches
-        stats.route_bytes_merged += pc.route_bytes
-        stats.gather_demotions += pc.gather_demotions
-        stats.shard_gather_demotions += pc.shard_gather_demotions
-    else:
-        stats.filter_matrix_bytes += n_items * pc.group_keys
-        if pc.hits_host:
-            stats.filter_readback_bytes += n_items * pc.group_keys
     stats.fd_candidates = block.n_tables
     if pc.epoch != index.mutation_epoch:
         raise ValueError(

@@ -1,22 +1,21 @@
 """Unified MATE discovery surface: one frozen config, one session object.
 
 MATE's pipeline (paper §4–6: super-key index → XASH filter → verification)
-is one system, but three PRs of growth left four entry points
-(``discover``, ``discover_batched``, ``discover_many``, ``DiscoveryEngine``)
-each re-threading ``bits``/``k``/``batch_tables`` positionally and selecting
-the filter backend through disjoint idioms.  This module collapses that to:
+is one system with one configuration surface:
 
   * ``DiscoveryConfig`` — a FROZEN dataclass holding every knob of the
     online phase (hash width, default top-k, filter backend, init-column
-    heuristic, batching, readback policy, serving window/deadline).  Being
-    immutable and hashable it is exactly the thing a request loop holds and
-    the thing launch caches key on.
+    heuristic, ranking, serving window/deadline).  Being immutable and
+    hashable it is exactly the thing a request loop holds and the thing
+    launch caches key on.
   * ``MateSession`` — the facade owning the ``MateIndex``, the backend
     resolved ONCE through ``kernels.registry`` (explicit config > env var >
     platform default), and per-session aggregate stats.  ``build`` runs the
     offline phase; ``discover`` / ``discover_many`` run the online phase
-    through the batched kernel engines with results bit-identical to the
-    pre-session entry points (and to scalar Algorithm 1).
+    through the two phases of ``core.batched`` (``plan_and_count``: plan
+    and one shared filter launch; ``score_from_counts``: pruning, exact
+    verification, the heap) — the path the serving tier runs — with results
+    bit-identical to scalar Algorithm 1.
 
 ``serve.engine.DiscoveryEngine`` is rebuilt on top of a ``MateSession`` as
 the async-capable serving loop (arrival-window batching, deadlines,
@@ -59,12 +58,8 @@ class DiscoveryConfig:
                      the fused launch, demoting to 'fused' when the store
                      doesn't fit the device budget.
       init_mode    — §6.1 initial-column heuristic.
-      batch_tables — tables per filter launch in ``discover``.
       fused_block_n — optional row-block override for the fused kernel
                      (power of two ≥ 128; clamped to the VMEM budget).
-      prefetch_frac — readback policy: below this fraction of batch items
-                     surviving the entry bound, per-table hit-slice
-                     readbacks beat one whole-batch transfer.
       rank         — result ordering: 'quality' (default) runs the
                      ``core.ranking`` scoring head over the filter counts
                      and orders by join quality; 'count' is the historical
@@ -119,9 +114,7 @@ class DiscoveryConfig:
     k: int = 10
     backend: str | None = None
     init_mode: str = "cardinality"
-    batch_tables: int = batched_lib.DEFAULT_BATCH_TABLES
     fused_block_n: int | None = None
-    prefetch_frac: float = batched_lib._PREFETCH_FRAC
     rank: str = "quality"
     profile_gate: bool = True
     signals: tuple[tuple[str, float], ...] | None = None
@@ -175,10 +168,6 @@ class DiscoveryConfig:
                     raise ValueError(
                         f"signal weight must be > 0, got {name}={weight!r}"
                     )
-        if not 0.0 <= self.prefetch_frac <= 1.0:
-            raise ValueError(f"prefetch_frac must be in [0, 1], got {self.prefetch_frac}")
-        if self.batch_tables < 1:
-            raise ValueError(f"batch_tables must be >= 1, got {self.batch_tables}")
         if self.k < 1:
             raise ValueError(f"k must be >= 1, got {self.k}")
         if self.window < 1:
@@ -372,22 +361,13 @@ class MateSession:
     def discover(
         self, query: Table, q_cols: list[int], k: int | None = None
     ) -> tuple[list[TopKEntry], DiscoveryStats]:
-        """Top-k n-ary join discovery for one query (batched Algorithm 1)."""
-        entries, stats = batched_lib.discover_batched(
-            self.index,
-            query,
-            q_cols,
-            k=self.config.k if k is None else k,
-            batch_tables=self.config.batch_tables,
-            init_mode=self.config.init_mode,
-            backend=self.backend,
-            prefetch_frac=self.config.prefetch_frac,
-            fused_block_n=self.config.fused_block_n,
-            rank=self.config.rank,
-            profile_gate=self.config.profile_gate,
-        )
-        self.stats.absorb(stats)
-        return entries, stats
+        """Top-k n-ary join discovery for one query: phase A
+        (``plan_and_count``) on a group of one, then phase B
+        (``score_from_counts``) — the serving tier's path, so the answer
+        and the ``DiscoveryStats`` are those of ``DiscoveryEngine`` at
+        ``window=1``."""
+        (pc,) = self.plan_and_count([(query, q_cols)])
+        return self.score_from_counts(pc, k)
 
     def discover_many(
         self,
@@ -401,7 +381,6 @@ class MateSession:
             k=self.config.k if k is None else k,
             init_mode=self.config.init_mode,
             backend=self.backend,
-            prefetch_frac=self.config.prefetch_frac,
             fused_block_n=self.config.fused_block_n,
             rank=self.config.rank,
             profile_gate=self.config.profile_gate,
@@ -450,7 +429,6 @@ class MateSession:
             self.index,
             pc,
             self.config.k if k is None else k,
-            prefetch_frac=self.config.prefetch_frac,
             from_cache=from_cache,
             rank=self.config.rank,
         )

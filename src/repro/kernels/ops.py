@@ -680,7 +680,7 @@ def filter_hits_table_counts(
                 it ``fused-gather`` runs the host-gather fused launch.
       rows:     int[n] store row offsets for the gather-fused launch.
     Returns:
-      (hits, counts) — ``counts`` int32[n_tables] is the one per-batch host
+      (hits, counts) — ``counts`` int32[n_tables] is the one per-launch host
       readback the rule-1/rule-2 bounds consume.  On the composed XLA/Pallas
       paths ``hits`` bool[n, q] stays device-resident (slice it per surviving
       table; only those slices are ever read back).  On the FUSED paths

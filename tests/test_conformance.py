@@ -5,7 +5,8 @@ One seeded discovery scenario, parametrized over EVERY backend registered in
 reproduces the numpy reference bit-identically across all four engine
 surfaces:
 
-  * ``discover_batched`` — entry sequence (count rank: fully deterministic);
+  * ``discover_many`` on one request — entry sequence (count rank: fully
+    deterministic);
   * ``discover_many`` — per-request entry sequences under the shared launch;
   * two-phase ``plan_and_count`` + ``score_from_counts`` — the per-table
     COUNT VECTORS themselves (the §6.3 filter is exact bitwise arithmetic,
@@ -81,9 +82,9 @@ def reference(lake, built, fd_lake):
     ref = {}
     for bits in ALL_BITS:
         idx = built[bits]
-        single, _ = batched.discover_batched(
-            idx, queries[0][0], queries[0][1], k=K, backend="numpy"
-        )
+        single, _ = batched.discover_many(
+            idx, [(queries[0][0], queries[0][1])], k=K, backend="numpy"
+        )[0]
         many = batched.discover_many(idx, queries, k=K, backend="numpy")
         pcs = batched.plan_and_count(idx, queries, "numpy")
         counts = [np.asarray(pc.counts).copy() for pc in pcs]
@@ -115,9 +116,9 @@ def test_backend_conforms(lake, built, fd_lake, reference, backend, bits):
     bk = registry.resolve_backend(backend)
 
     # -- discover: bit-identical entry sequence + matrix invariant --------
-    single, st = batched.discover_batched(
-        idx, queries[0][0], queries[0][1], k=K, backend=bk
-    )
+    single, st = batched.discover_many(
+        idx, [(queries[0][0], queries[0][1])], k=K, backend=bk
+    )[0]
     assert _key(single) == ref["single"], "discover drifted"
     if bk.fused:
         assert st.filter_matrix_bytes == 0, (

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.core import discovery, xash
-from repro.core.batched import discover_batched, discover_many
+from repro.core.batched import discover_many
 from repro.core.corpus import Corpus, Table
 from repro.core.index import MateIndex
 from repro.data import synthetic
@@ -65,23 +65,10 @@ def test_batched_engine_bit_identical(lake):
     corpus, index, query, q_cols, _ = lake
     seq, _ = discovery.discover(index, query, q_cols, k=10)
     for backend in ("numpy", None):
-        bat, _ = discover_batched(index, query, q_cols, k=10, backend=backend)
+        bat, _ = discover_many(index, [(query, q_cols)], k=10, backend=backend)[0]
         assert [(e.table_id, e.joinability, e.mapping) for e in seq] == [
             (e.table_id, e.joinability, e.mapping) for e in bat
         ]
-
-
-def test_batched_small_batches_bit_identical(lake):
-    """Rule-1 between-batch pruning must not change results at any batch size."""
-    corpus, index, query, q_cols, _ = lake
-    seq, _ = discovery.discover(index, query, q_cols, k=5)
-    for batch_tables in (1, 7, 64):
-        bat, _ = discover_batched(
-            index, query, q_cols, k=5, batch_tables=batch_tables, backend="numpy"
-        )
-        assert [(e.table_id, e.joinability) for e in seq] == [
-            (e.table_id, e.joinability) for e in bat
-        ], batch_tables
 
 
 def test_discover_many_bit_identical(lake):
@@ -120,8 +107,8 @@ def test_discovery_engine_slot_batching(lake):
 
 
 def test_512bit_engines_bit_identical(lake512):
-    """512-bit end-to-end: discover_batched, discover_many and
-    DiscoveryEngine.flush all match the scalar Algorithm 1 scan exactly,
+    """512-bit end-to-end: discover_many on one request and on a group, and
+    DiscoveryEngine.flush, all match the scalar Algorithm 1 scan exactly,
     mirroring the 128-bit assertions above (ids, scores, mappings)."""
     from repro.serve.engine import DiscoveryEngine
 
@@ -131,7 +118,7 @@ def test_512bit_engines_bit_identical(lake512):
     seq, _ = discovery.discover(index, query, q_cols, k=10)
     want = [(e.table_id, e.joinability, e.mapping) for e in seq]
     for backend in ("numpy", None):
-        bat, _ = discover_batched(index, query, q_cols, k=10, backend=backend)
+        bat, _ = discover_many(index, [(query, q_cols)], k=10, backend=backend)[0]
         assert [(e.table_id, e.joinability, e.mapping) for e in bat] == want
     out = discover_many(index, [(query, q_cols)] * 3, k=10)
     for entries, _stats in out:
@@ -143,9 +130,9 @@ def test_512bit_engines_bit_identical(lake512):
     # count-ranked references above by the pure-pruning/reorder contract)
     want_q = [
         (e.table_id, e.joinability, e.mapping)
-        for e in discover_batched(
-            index, query, q_cols, k=10, rank="quality", profile_gate=True
-        )[0]
+        for e in discover_many(
+            index, [(query, q_cols)], k=10, rank="quality", profile_gate=True
+        )[0][0]
     ]
     assert sorted(want_q) == sorted(want)
     reqs = [engine.submit(query, q_cols, k=10) for _ in range(3)]
@@ -163,14 +150,15 @@ def test_512bit_topk_matches_bruteforce(lake512):
 
 
 def test_batched_readback_accounting(lake):
-    """Device-side rule-1/2: the batched engine accounts for match-matrix
-    bytes and reads back at most the full matrix (counts + verify slices).
-    Under the fused dispatch (MATE_FILTER_BACKEND=fused / TPU) the matrix is
-    never produced at all — zero matrix bytes is the contract instead."""
+    """Launch accounting of one request: a non-fused launch materialises its
+    match matrix and reads the whole of it back, once.  Under the fused
+    dispatch (MATE_FILTER_BACKEND=fused / TPU) the matrix is never produced
+    at all — zero matrix bytes, and only the counts vector plus recomputed
+    surviving slices are booked as read back."""
     from repro.kernels import ops
 
     corpus, index, query, q_cols, _ = lake
-    _, st = discover_batched(index, query, q_cols, k=5)
+    _, st = discover_many(index, [(query, q_cols)], k=5)[0]
     if ops.fused_filter_default():
         assert st.filter_matrix_bytes == 0
         assert st.filter_fused_launches > 0
@@ -185,42 +173,8 @@ def test_batched_readback_accounting(lake):
         )
     else:
         assert st.filter_matrix_bytes > 0
-        # at most: every table verified (full slice) + 4 count bytes/table
-        assert st.filter_readback_bytes <= (
-            st.filter_matrix_bytes + 4 * st.tables_fetched
-        )
-
-
-def test_score_tables_reads_back_only_surviving_slices(lake, monkeypatch):
-    """Pins the device-side rule-2 contract directly: with device-resident
-    hits and a full heap, ONLY un-pruned tables' hit slices are transferred
-    (prefetch disabled by the low alive fraction)."""
-    import jax.numpy as jnp
-
-    from repro.core import batched as B
-
-    corpus, index, query, q_cols, _ = lake
-    plan = B.plan_query(index, query, q_cols)
-    block = plan.block
-    assert block.n_tables >= 3
-    t_stop = min(block.n_tables, 8)
-    n_items = int(block.table_ptr[t_stop])
-    k = len(plan.distinct_keys)
-    hits_dev = jnp.zeros((n_items, k), dtype=bool)  # device-resident
-
-    topk = B._TopK(1)
-    topk.offer(10_000, 5, None)  # full heap, bound 5
-    # exactly one table above the bound -> exactly its slice is read back
-    counts = np.zeros(t_stop, dtype=np.int32)
-    counts[t_stop - 1] = 6
-    survivor_items = int(block.table_ptr[t_stop] - block.table_ptr[t_stop - 1])
-    monkeypatch.setattr(B, "_PREFETCH_FRAC", 1.1)  # force per-table path
-    st0 = plan.stats.filter_readback_bytes
-    B._score_tables(
-        index, plan, topk, hits_dev, counts, block.rows[:n_items], 0, t_stop, 0
-    )
-    assert plan.stats.filter_readback_bytes - st0 == survivor_items * k
-    assert plan.stats.tables_pruned_rule2 == t_stop - 1
+        # the whole matrix comes back in one transfer, nothing else
+        assert st.filter_readback_bytes == st.filter_matrix_bytes
 
 
 @pytest.mark.parametrize("lazy", [False, True])
@@ -240,7 +194,7 @@ def test_score_tables_prunes_empty_tables_before_heap_fills(lake, lazy):
     row_sk = index.superkey_of_rows(block.rows)
     hits = ops.subsume_np(row_sk, plan.q_sk) & plan.elig.dense()
     # empty every other table, so zero counts are certain
-    seg = B._segment_ids(block.table_ptr, 0, n_tables)
+    seg = B._segment_ids(block.table_ptr)
     hits[seg % 2 == 1] = False
     plan.elig = ops.Eligibility(
         np.where(seg % 2 == 1, ops.PAD_ITEM_VALUE, plan.elig.item_value),
@@ -254,8 +208,8 @@ def test_score_tables_prunes_empty_tables_before_heap_fills(lake, lazy):
     topk = B._TopK(n_tables + 1)  # never fills: the bound stays 0
     st = plan.stats
     B._score_tables(
-        index, plan, topk, None if lazy else hits, counts, block.rows,
-        0, n_tables, 0, row_sk=row_sk if lazy else None,
+        index, plan, topk, None if lazy else hits, counts,
+        row_sk=row_sk if lazy else None,
     )
     assert not topk.full
     assert st.tables_pruned_empty == st.tables_pruned_rule2 == int(empty.sum())
@@ -281,22 +235,22 @@ def test_score_tables_prunes_empty_tables_before_heap_fills(lake, lazy):
 def test_fp_heavy_queries_prune_empty_tables_bit_identical(lake, key_width):
     """Key columns from different tables (the fp-heavy mix): whole keys
     rarely match, heaps never fill, and most candidate tables have no
-    filter-surviving pair.  discover_many and discover_batched prune those
-    tables before any re-gather and still return Algorithm 1's top-k."""
+    filter-surviving pair.  The engine prunes those tables before any
+    re-gather, alone or in a group, and still returns Algorithm 1's top-k."""
     corpus, index, _, _, _ = lake
     queries = synthetic.make_mixed_queries(corpus, 4, 40, key_width, seed=33)
     assert queries
     many = discover_many(index, queries, k=10)
-    empty_many = empty_batched = 0
+    empty_many = empty_solo = 0
     for (q, qc), (entries, st_many) in zip(queries, many):
         seq, _ = discovery.discover(index, q, qc, k=10)
         want = [(e.table_id, e.joinability, e.mapping) for e in seq]
         assert [(e.table_id, e.joinability, e.mapping) for e in entries] == want
-        bat, st_bat = discover_batched(index, q, qc, k=10)
-        assert [(e.table_id, e.joinability, e.mapping) for e in bat] == want
+        solo, st_solo = discover_many(index, [(q, qc)], k=10)[0]
+        assert [(e.table_id, e.joinability, e.mapping) for e in solo] == want
         empty_many += st_many.tables_pruned_empty
-        empty_batched += st_bat.tables_pruned_empty
-    assert empty_many > 0 and empty_batched > 0
+        empty_solo += st_solo.tables_pruned_empty
+    assert empty_many > 0 and empty_solo > 0
 
 
 @pytest.mark.parametrize("hash_name", ["bf", "ht", "murmur", "simhash"])

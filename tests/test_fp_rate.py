@@ -7,7 +7,7 @@ rows, and NO width may ever reject an exact match (§6.3 lemma).
 
 import pytest
 
-from repro.core.batched import discover_batched, filter_outcomes
+from repro.core.batched import discover_many, filter_outcomes
 
 from conftest import mixed_query_lake, indexes_at_widths
 
@@ -62,8 +62,8 @@ def test_fp_ordering_survives_topk_engine(fp_lake):
     queries, indexes, _ = fp_lake
     fp128 = fp512 = 0
     for q, q_cols in queries:
-        top128, st128 = discover_batched(indexes[128], q, q_cols, k=5)
-        top512, st512 = discover_batched(indexes[512], q, q_cols, k=5)
+        top128, st128 = discover_many(indexes[128], [(q, q_cols)], k=5)[0]
+        top512, st512 = discover_many(indexes[512], [(q, q_cols)], k=5)[0]
         assert [(e.table_id, e.joinability) for e in top128] == [
             (e.table_id, e.joinability) for e in top512
         ]

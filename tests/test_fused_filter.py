@@ -12,7 +12,7 @@ import pytest
 import jax.numpy as jnp
 
 from repro.core import discovery, xash
-from repro.core.batched import discover_batched, discover_many
+from repro.core.batched import discover_many
 from repro.core.session import DiscoveryConfig
 from repro.core.index import MateIndex
 from repro.data import synthetic
@@ -196,7 +196,7 @@ def test_fused_false_pins_composed_path(lake, monkeypatch):
     corpus, index, query, q_cols = lake
     monkeypatch.setenv("MATE_FILTER_BACKEND", "fused")
     seq, _ = discovery.discover(index, query, q_cols, k=10)
-    bat, st = discover_batched(index, query, q_cols, k=10, backend="pallas")
+    bat, st = discover_many(index, [(query, q_cols)], k=10, backend="pallas")[0]
     assert [(e.table_id, e.joinability) for e in bat] == [
         (e.table_id, e.joinability) for e in seq
     ]
@@ -210,7 +210,7 @@ def test_fused_table_cap_fallback_accounting(lake, monkeypatch):
     corpus, index, query, q_cols = lake
     monkeypatch.setattr(ops, "_FUSED_MAX_TABLES", 4)  # force the split
     seq, _ = discovery.discover(index, query, q_cols, k=10)
-    bat, st = discover_batched(index, query, q_cols, k=10, backend="fused")
+    bat, st = discover_many(index, [(query, q_cols)], k=10, backend="fused")[0]
     assert [(e.table_id, e.joinability) for e in bat] == [
         (e.table_id, e.joinability) for e in seq
     ]
@@ -246,14 +246,12 @@ def lake():
 def test_fused_engine_topk_bit_identical(lake):
     """Engine acceptance: the fused counts-only path returns the same top-k
     (ids, scores, mappings) as scalar Algorithm 1, with ZERO match-matrix
-    bytes and small batch sizes exercising multi-batch fused launches."""
+    bytes, at heap sizes that fill early (rule 1 prunes) and late."""
     corpus, index, query, q_cols = lake
-    seq, _ = discovery.discover(index, query, q_cols, k=10)
-    want = [(e.table_id, e.joinability, e.mapping) for e in seq]
-    for batch_tables in (7, 64, 256):
-        bat, st = discover_batched(
-            index, query, q_cols, k=10, batch_tables=batch_tables, backend="fused"
-        )
+    for k in (1, 3, 10):
+        seq, _ = discovery.discover(index, query, q_cols, k=k)
+        want = [(e.table_id, e.joinability, e.mapping) for e in seq]
+        bat, st = discover_many(index, [(query, q_cols)], k=k, backend="fused")[0]
         assert [(e.table_id, e.joinability, e.mapping) for e in bat] == want
         assert st.filter_matrix_bytes == 0
         assert st.filter_fused_launches > 0
@@ -300,7 +298,7 @@ def test_fused_engine_topk_across_widths(lake, bits):
     corpus, _index, query, q_cols = lake
     index = MateIndex(corpus, cfg=xash.XashConfig(bits=bits))
     seq, _ = discovery.discover(index, query, q_cols, k=10)
-    bat, st = discover_batched(index, query, q_cols, k=10, backend="fused")
+    bat, st = discover_many(index, [(query, q_cols)], k=10, backend="fused")[0]
     assert [(e.table_id, e.joinability, e.mapping) for e in bat] == [
         (e.table_id, e.joinability, e.mapping) for e in seq
     ]

@@ -34,7 +34,7 @@ except ModuleNotFoundError:  # property tests skip; unit tests still run
     st = _StrategyStub()
 
 from repro.core import discovery, xash
-from repro.core.batched import discover_batched, discover_many, plan_and_count, score_from_counts
+from repro.core.batched import discover_many, plan_and_count, score_from_counts
 from repro.core.index import MateIndex
 from repro.core.session import DiscoveryConfig, MateSession
 from repro.data import synthetic
@@ -168,16 +168,16 @@ def lake():
 
 @pytest.mark.parametrize("bits", ALL_BITS)
 def test_gather_engine_topk_bit_identical(lake, bits):
-    """discover_batched(backend='fused-gather') == scalar Algorithm 1 at
-    every width, with zero matrix bytes and positive gather savings."""
+    """discover_many(backend='fused-gather') == scalar Algorithm 1 at
+    every width and at heap sizes that fill early and late, with zero matrix
+    bytes and positive gather savings."""
     corpus, query, q_cols = lake
     index = MateIndex(corpus, cfg=xash.XashConfig(bits=bits))
-    seq, _ = discovery.discover(index, query, q_cols, k=10)
-    for batch_tables in (7, 256):
-        bat, st = discover_batched(
-            index, query, q_cols, k=10, batch_tables=batch_tables,
-            backend="fused-gather",
-        )
+    for k in (3, 10):
+        seq, _ = discovery.discover(index, query, q_cols, k=k)
+        bat, st = discover_many(
+            index, [(query, q_cols)], k=k, backend="fused-gather"
+        )[0]
         assert [(e.table_id, e.joinability, e.mapping) for e in bat] == [
             (e.table_id, e.joinability, e.mapping) for e in seq
         ]
@@ -229,7 +229,9 @@ def test_gather_empty_posting_lists(lake):
     index = MateIndex(corpus)
     ghost = synthetic.Table(-1, [["zzznope", "zzznope2"]] * 3)
     seq, _ = discovery.discover(index, ghost, [0, 1], k=5)
-    bat, st = discover_batched(index, ghost, [0, 1], k=5, backend="fused-gather")
+    bat, st = discover_many(
+        index, [(ghost, [0, 1])], k=5, backend="fused-gather"
+    )[0]
     assert [(e.table_id, e.joinability) for e in bat] == [
         (e.table_id, e.joinability) for e in seq
     ]
@@ -243,7 +245,9 @@ def test_gather_all_candidates_one_table():
     index = MateIndex(corpus)
     query = synthetic.Table(-1, [[f"k{r}", f"v{r % 3}"] for r in range(5)])
     seq, _ = discovery.discover(index, query, [0, 1], k=3)
-    bat, st = discover_batched(index, query, [0, 1], k=3, backend="fused-gather")
+    bat, st = discover_many(
+        index, [(query, [0, 1])], k=3, backend="fused-gather"
+    )[0]
     assert [(e.table_id, e.joinability, e.mapping) for e in bat] == [
         (e.table_id, e.joinability, e.mapping) for e in seq
     ]
@@ -255,11 +259,15 @@ def test_gather_all_tables_deleted(lake):
     the CSR block is empty, and the gather path returns an empty top-k."""
     corpus, query, q_cols = lake
     index = MateIndex(corpus)
-    ref, _ = discover_batched(index, query, q_cols, k=5, backend="fused-gather")
+    ref, _ = discover_many(
+        index, [(query, q_cols)], k=5, backend="fused-gather"
+    )[0]
     assert ref  # sanity: undeleted lake finds joinable tables
     for t in range(len(corpus.tables)):
         index.delete_table(t)
-    got, st = discover_batched(index, query, q_cols, k=5, backend="fused-gather")
+    got, st = discover_many(
+        index, [(query, q_cols)], k=5, backend="fused-gather"
+    )[0]
     assert got == []
     assert st.gather_bytes_saved == 0
     seq, _ = discovery.discover(index, query, q_cols, k=5)
@@ -317,7 +325,9 @@ def test_gather_bit_identical_across_mutations(lake):
 
     def check():
         seq, _ = discovery.discover(index, query, q_cols, k=8)
-        bat, st = discover_batched(index, query, q_cols, k=8, backend="fused-gather")
+        bat, st = discover_many(
+            index, [(query, q_cols)], k=8, backend="fused-gather"
+        )[0]
         assert [(e.table_id, e.joinability, e.mapping) for e in bat] == [
             (e.table_id, e.joinability, e.mapping) for e in seq
         ]
@@ -339,9 +349,11 @@ def test_gather_store_budget_demotes_to_host_gather(lake, monkeypatch):
     fused launch: identical results, zero gather savings claimed."""
     corpus, query, q_cols = lake
     index = MateIndex(corpus)
-    want, _ = discover_batched(index, query, q_cols, k=10, backend="fused")
+    want, _ = discovery.discover(index, query, q_cols, k=10)
     monkeypatch.setattr(ops, "GATHER_STORE_MAX_BYTES", 0)
-    got, st = discover_batched(index, query, q_cols, k=10, backend="fused-gather")
+    got, st = discover_many(
+        index, [(query, q_cols)], k=10, backend="fused-gather"
+    )[0]
     assert [(e.table_id, e.joinability, e.mapping) for e in got] == [
         (e.table_id, e.joinability, e.mapping) for e in want
     ]
@@ -351,16 +363,31 @@ def test_gather_store_budget_demotes_to_host_gather(lake, monkeypatch):
 
 
 def test_gather_table_cap_demotes_per_batch(lake, monkeypatch):
-    """Batches above the scatter-tile table cap split into table chunks and
-    stay on the gather path — results bit-identical, counts-only."""
+    """A launch above the scatter-tile table cap splits into table chunks
+    (one gather-fused kernel call each) and stays on the gather path —
+    results bit-identical, counts-only."""
     corpus, query, q_cols = lake
     index = MateIndex(corpus)
     seq, _ = discovery.discover(index, query, q_cols, k=10)
     monkeypatch.setattr(ops, "_FUSED_MAX_TABLES", 4)
-    bat, st = discover_batched(index, query, q_cols, k=10, backend="fused-gather")
+    calls = []
+    launch = ops.gather_filter_table_counts
+
+    def spy(store, rows, *args, **kw):
+        calls.append(rows.shape[0])
+        return launch(store, rows, *args, **kw)
+
+    monkeypatch.setattr(ops, "gather_filter_table_counts", spy)
+    bat, st = discover_many(
+        index, [(query, q_cols)], k=10, backend="fused-gather"
+    )[0]
     assert [(e.table_id, e.joinability, e.mapping) for e in bat] == [
         (e.table_id, e.joinability, e.mapping) for e in seq
     ]
+    # every chunk but the last holds exactly the cap's tables
+    n_tables = st.tables_fetched - st.tables_gated
+    assert len(calls) == -(-n_tables // 4) > 1
+    assert sum(calls) == st.pl_items_checked
     assert st.gather_bytes_saved > 0
     assert st.filter_fused_launches > 0
     assert st.filter_matrix_bytes == 0

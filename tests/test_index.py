@@ -134,7 +134,7 @@ def test_delete_table_matches_rebuild():
 
 def test_updates_keep_engines_bit_identical():
     """After a mix of §5.4 updates, scalar and batched engines still agree."""
-    from repro.core.batched import discover_batched, discover_many
+    from repro.core.batched import discover_many
 
     corpus = synthetic.make_corpus(synthetic.SyntheticSpec(n_tables=60, seed=9))
     query, q_cols, _, corpus = synthetic.make_query_with_ground_truth(corpus)
@@ -147,7 +147,7 @@ def test_updates_keep_engines_bit_identical():
     seq, _ = discovery.discover(idx, query, q_cols, k=8)
     assert tid in [e.table_id for e in seq]
     for backend in ("numpy", None):
-        bat, _ = discover_batched(idx, query, q_cols, k=8, backend=backend)
+        bat, _ = discover_many(idx, [(query, q_cols)], k=8, backend=backend)[0]
         assert [(e.table_id, e.joinability, e.mapping) for e in seq] == [
             (e.table_id, e.joinability, e.mapping) for e in bat
         ]

@@ -44,7 +44,7 @@ except ModuleNotFoundError:  # property tests skip; unit tests still run
     st = _StrategyStub()
 
 from repro.core import profiles, ranking, xash
-from repro.core.batched import discover_batched, discover_many
+from repro.core.batched import discover_many
 from repro.core.corpus import Corpus, Table
 from repro.core.discovery import DiscoveryStats
 from repro.core.index import build_index
@@ -147,10 +147,10 @@ def test_profile_store_layout(lake):
 def test_gate_is_pure_pruning_every_width(built, lake, bits):
     _corpus, query, q_cols, expected = lake
     idx = built[bits]
-    base, _ = discover_batched(idx, query, q_cols, k=10)
-    gated, gstats = discover_batched(
-        idx, query, q_cols, k=10, profile_gate=True
-    )
+    base, _ = discover_many(idx, [(query, q_cols)], k=10)[0]
+    gated, gstats = discover_many(
+        idx, [(query, q_cols)], k=10, profile_gate=True
+    )[0]
     assert _key(gated) == _key(base)  # count rank: order too
     assert gstats.tables_gated >= 0
     # the planted ground truth survives the gate
@@ -169,10 +169,10 @@ def test_gate_prunes_crafted_narrow_table(lake):
     corpus2 = Corpus(tables, max_len=corpus.max_len)
     idx = build_index(corpus2)[0]
 
-    base, _ = discover_batched(idx, query, q_cols, k=10)
-    gated, gstats = discover_batched(
-        idx, query, q_cols, k=10, profile_gate=True
-    )
+    base, _ = discover_many(idx, [(query, q_cols)], k=10)[0]
+    gated, gstats = discover_many(
+        idx, [(query, q_cols)], k=10, profile_gate=True
+    )[0]
     assert gstats.tables_gated >= 1
     assert gstats.gate_bytes_saved > 0
     assert _key(gated) == _key(base)
@@ -197,10 +197,10 @@ def test_gate_purity_property(seed):
     )
     for bits in ALL_BITS:
         idx = build_index(corpus, cfg=xash.XashConfig(bits=bits))[0]
-        base, _ = discover_batched(idx, query, q_cols, k=8)
-        gated, _ = discover_batched(
-            idx, query, q_cols, k=8, profile_gate=True
-        )
+        base, _ = discover_many(idx, [(query, q_cols)], k=8)[0]
+        gated, _ = discover_many(
+            idx, [(query, q_cols)], k=8, profile_gate=True
+        )[0]
         assert _key(gated) == _key(base)
 
 
@@ -240,10 +240,10 @@ def test_scoring_launch_matches_numpy_oracle():
 def test_quality_rank_preserves_verified_set(built, lake, bits):
     _corpus, query, q_cols, _e = lake
     idx = built[bits]
-    count_rank, _ = discover_batched(idx, query, q_cols, k=10)
-    quality, qstats = discover_batched(
-        idx, query, q_cols, k=10, rank="quality", profile_gate=True
-    )
+    count_rank, _ = discover_many(idx, [(query, q_cols)], k=10)[0]
+    quality, qstats = discover_many(
+        idx, [(query, q_cols)], k=10, rank="quality", profile_gate=True
+    )[0]
     assert _ids(quality) == _ids(count_rank)
     assert sorted(_key(quality)) == sorted(_key(count_rank))
     assert qstats.ranking_launches >= 1
@@ -256,14 +256,14 @@ def test_quality_rank_preserves_verified_set(built, lake, bits):
 
 
 def test_quality_rank_two_phase_matches_batched(built, lake):
-    """discover_many (plan_and_count + score_from_counts) produces the same
-    quality-annotated entries as discover_batched — one scoring launch per
-    request on the two-phase path."""
+    """A request scored inside a group of two gets the same
+    quality-annotated entries as the same request alone — one scoring
+    launch per request on the two-phase path."""
     _corpus, query, q_cols, _e = lake
     idx = built[128]
-    solo, _ = discover_batched(
-        idx, query, q_cols, k=10, rank="quality", profile_gate=True
-    )
+    solo, _ = discover_many(
+        idx, [(query, q_cols)], k=10, rank="quality", profile_gate=True
+    )[0]
     many = discover_many(
         idx, [(query, q_cols)] * 2, k=10, rank="quality", profile_gate=True
     )
@@ -307,9 +307,9 @@ def test_serving_inherits_rank_and_gate(built, lake):
     assert [e.quality for e in warm.results] == [
         e.quality for e in cold.results
     ]
-    ref, _ = discover_batched(
-        idx, query, q_cols, k=10, rank="quality", profile_gate=True
-    )
+    ref, _ = discover_many(
+        idx, [(query, q_cols)], k=10, rank="quality", profile_gate=True
+    )[0]
     assert _key(cold.results) == _key(ref)
 
 

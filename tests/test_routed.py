@@ -118,8 +118,8 @@ def test_routed_matrix_byte_identical(lake, single_host, n_shards, bits):
     corpus, query, q_cols = lake
     idx = make_routed(corpus, bits, n_shards)
     ref = single_host[bits]
-    want, _ = batched.discover_batched(ref, query, q_cols, k=10)
-    got, stats = batched.discover_batched(idx, query, q_cols, k=10)
+    want, _ = batched.discover_many(ref, [(query, q_cols)], k=10)[0]
+    got, stats = batched.discover_many(idx, [(query, q_cols)], k=10)[0]
     assert topk_key(got) == topk_key(want)
     # the routed invariant: count vectors crossed shards, superkeys did not
     assert stats.shard_launches >= 1
@@ -211,12 +211,12 @@ def test_routed_bound_cache_replay_no_new_launches(lake):
 @pytest.mark.parametrize("n_devices", SHARD_COUNTS)
 def test_mesh_routed_matrix_byte_identical(lake, single_host, n_devices, bits):
     corpus, query, q_cols = lake
-    want, _ = batched.discover_batched(single_host[bits], query, q_cols, k=10)
+    want, _ = batched.discover_many(single_host[bits], [(query, q_cols)], k=10)[0]
     idx = make_routed(corpus, bits, n_devices)
     if n_devices > 1:
         mesh = meshlib.make_mesh((n_devices,), ("data",))
         idx.attach_mesh(mesh, ("data",))
-    got, stats = batched.discover_batched(idx, query, q_cols, k=10)
+    got, stats = batched.discover_many(idx, [(query, q_cols)], k=10)[0]
     assert topk_key(got) == topk_key(want)
     assert stats.shard_launches >= 1 and stats.route_bytes_merged > 0
 
@@ -236,12 +236,12 @@ def test_mesh_built_routed_session(lake, single_host):
     )
     assert stats.sharded and stats.n_shards == 4
     assert sum(stats.shard_rows) == corpus.total_rows
-    want, _ = batched.discover_batched(single_host[256], query, q_cols, k=10)
-    got, _ = batched.discover_batched(idx, query, q_cols, k=10)
+    want, _ = batched.discover_many(single_host[256], [(query, q_cols)], k=10)[0]
+    got, _ = batched.discover_many(idx, [(query, q_cols)], k=10)[0]
     assert topk_key(got) == topk_key(want)
     # detach falls back to host-routed launches, still identical
     idx.detach_mesh()
-    got2, st2 = batched.discover_batched(idx, query, q_cols, k=10)
+    got2, st2 = batched.discover_many(idx, [(query, q_cols)], k=10)[0]
     assert topk_key(got2) == topk_key(want)
     assert st2.shard_launches >= 1
 
@@ -304,8 +304,8 @@ def test_mutations_shard_local_epochs_and_stores():
     rebuilt = MateIndex(
         Corpus([*corpus.tables[:-1], Table(tid, mutated)]), cfg=idx.cfg
     )
-    got, _ = batched.discover_batched(idx, query, q_cols, k=8)
-    want, _ = batched.discover_batched(rebuilt, query, q_cols, k=8)
+    got, _ = batched.discover_many(idx, [(query, q_cols)], k=8)[0]
+    want, _ = batched.discover_many(rebuilt, [(query, q_cols)], k=8)[0]
     assert topk_key(got) == topk_key(want)
     assert tid in [e.table_id for e in got]
 
@@ -316,8 +316,8 @@ def test_mutations_shard_local_epochs_and_stores():
     assert epochs_del[:-1] == epochs_mid[:-1]
     assert epochs_del[-1] > epochs_mid[-1]
     ref = MateIndex(corpus2_without(corpus, tid), cfg=idx.cfg)
-    got2, _ = batched.discover_batched(idx, query, q_cols, k=8)
-    want2, _ = batched.discover_batched(ref, query, q_cols, k=8)
+    got2, _ = batched.discover_many(idx, [(query, q_cols)], k=8)[0]
+    want2, _ = batched.discover_many(ref, [(query, q_cols)], k=8)[0]
     assert topk_key(got2) == topk_key(want2)
     assert tid not in [e.table_id for e in got2]
 
@@ -348,8 +348,8 @@ def test_update_cell_on_interior_shard_touches_only_that_shard(lake):
             assert s.device_store() is stores[i]
     # and the index still matches a rebuild
     rebuilt = MateIndex(Corpus(corpus.tables), cfg=idx.cfg)
-    got, _ = batched.discover_batched(idx, query, q_cols, k=8)
-    want, _ = batched.discover_batched(rebuilt, query, q_cols, k=8)
+    got, _ = batched.discover_many(idx, [(query, q_cols)], k=8)[0]
+    want, _ = batched.discover_many(rebuilt, [(query, q_cols)], k=8)[0]
     assert topk_key(got) == topk_key(want)
     # restore for the module-scoped fixture's other consumers
     idx.update_cell(tid, 0, 0, old)

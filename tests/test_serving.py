@@ -38,7 +38,7 @@ except ModuleNotFoundError:
     HAVE_HYPOTHESIS = False
 
 from repro.core import xash
-from repro.core.batched import discover_batched
+from repro.core.batched import discover_many
 from repro.core.corpus import Table
 from repro.core.discovery import DiscoveryStats
 from repro.core.index import build_index
@@ -89,9 +89,9 @@ def _cold(index, query, q_cols, k=5):
     # raw-engine reference at the SESSION's default flags (rank='quality' +
     # profile gate), so cache-hit comparisons stay exact including order
     return _key(
-        discover_batched(
-            index, query, q_cols, k=k, rank="quality", profile_gate=True
-        )[0]
+        discover_many(
+            index, [(query, q_cols)], k=k, rank="quality", profile_gate=True
+        )[0][0]
     )
 
 
@@ -144,7 +144,7 @@ def test_degrade_admits_at_narrow_width_bit_identical(built, lake):
     assert sorted(_key(degraded.results)) == sorted(_cold(built[512], *queries[1]))
     assert _key(normal.results) == _cold(built[512], *queries[0])
     # degraded (prefix) filtering can only pass MORE pairs, never fewer
-    cold_passed = discover_batched(built[512], *queries[1], k=5)[1].filter_passed
+    cold_passed = discover_many(built[512], [queries[1]], k=5)[0][1].filter_passed
     assert degraded.stats.filter_passed >= cold_passed
 
 

@@ -6,8 +6,8 @@ widths 128/256/512 and all backends (numpy/xla/pallas/fused) — since ISSUE 9
 the session defaults to ``rank='quality'``, which REORDERS that set by the
 scoring head (and the profile gate prunes candidates without changing it),
 so set-level comparisons run against the count-ranked scalar engine and
-exact-order comparisons against the raw engines at the session's own
-rank/gate flags.  The engine's arrival-window batching honours window-full
+order checks against the scoring head's own annotations.  ``discover`` runs
+the serving tier's two phases on a group of one.  The engine's arrival-window batching honours window-full
 and flush-after-deadline semantics deterministically.  The PR 4 deprecation
 shims (``use_kernel=``/``fused=``/``impl=``) were REMOVED one release later
 (ISSUE 5): the old kwargs now raise TypeError — pinned below.
@@ -20,11 +20,13 @@ import numpy as np
 import pytest
 
 from repro.core import discovery, xash
-from repro.core.batched import discover_batched, discover_many
+from repro.core import batched
+from repro.core.batched import discover_many
 from repro.core.index import MateIndex
 from repro.core.session import DiscoveryConfig, MateSession, VALID_BITS
 from repro.data import synthetic
 from repro.serve.engine import DiscoveryEngine
+from repro.kernels import registry
 from repro.kernels.registry import Backend
 
 BACKENDS = ("numpy", "xla", "pallas", "fused", "fused-gather")
@@ -74,9 +76,7 @@ def test_config_is_frozen_and_hashable():
     {"backend": "cuda"},
     {"fused_block_n": 100},
     {"fused_block_n": 384},
-    {"prefetch_frac": 1.5},
     {"window": 0},
-    {"batch_tables": 0},
     {"k": 0},
     {"flush_after": -1.0},
 ])
@@ -119,9 +119,8 @@ def test_session_build_records_build_stats(sessions):
 @pytest.mark.parametrize("bits", VALID_BITS)
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_session_discover_bit_identical(sessions, lake, bits, backend):
-    """session.discover returns the scalar Algorithm 1 SET (quality rank
-    reorders it) and matches the raw engine exactly at the session's own
-    rank/gate flags, at every width and backend."""
+    """session.discover returns the scalar Algorithm 1 SET, ordered by the
+    quality annotations of its scoring head, at every width and backend."""
     _corpus, query, q_cols = lake
     base = sessions[bits]
     session = MateSession(
@@ -130,12 +129,10 @@ def test_session_discover_bit_identical(sessions, lake, bits, backend):
     ref, _ = discovery.discover(session.index, query, q_cols, k=10)
     got, stats = session.discover(query, q_cols)
     assert _same_set(got, ref)
-    old, _ = discover_batched(
-        session.index, query, q_cols, k=10, backend=backend,
-        rank="quality", profile_gate=True,
-    )
-    assert _key(got) == _key(old)
     assert all(e.quality is not None for e in got)
+    assert _key(got) == _key(
+        sorted(got, key=lambda e: (-e.quality, -e.joinability, e.table_id))
+    )
     if backend in ("fused", "fused-gather"):
         assert stats.filter_matrix_bytes == 0
         assert stats.filter_fused_launches > 0
@@ -161,6 +158,52 @@ def test_session_discover_many_bit_identical(sessions, lake, backend):
     for (q, qc), k_i, (entries, _st) in zip(queries, [10, 3, 5], out):
         ref, _ = discovery.discover(session.index, q, qc, k=k_i)
         assert _same_set(entries, ref)
+
+
+@pytest.mark.parametrize("backend", ["numpy", "auto", "fused", "fused-gather"])
+def test_session_discover_is_the_served_path(sessions, lake, backend):
+    """MateSession.discover and the serving tier at a window of one run the
+    same two phases: the same request gets identical entries (order,
+    scores, mappings, quality) and identical DiscoveryStats either way, and
+    the two sessions aggregate identically."""
+    corpus, query, q_cols = lake
+    base = sessions[128]
+    config = dataclasses.replace(base.config, backend=backend, k=10)
+    direct = MateSession(base.index, config)
+    served = MateSession(base.index, config)
+    engine = DiscoveryEngine(served, batch=1)
+    queries = [(query, q_cols)] + synthetic.make_mixed_queries(
+        corpus, 2, 12, 2, seed=21
+    )
+    for q, qc in queries:
+        got, stats = direct.discover(q, qc)
+        req = engine.discover(q, qc)
+        assert got == req.results  # TopKEntry fields, quality included
+        assert stats == req.stats
+    assert direct.stats == served.stats
+    assert direct.stats.requests == len(queries)
+
+
+@pytest.mark.parametrize("backend", registry.backend_names())
+def test_plan_counts_hits_are_host_or_none(sessions, lake, backend):
+    """Phase A hands phase B either no match matrix (the counts-only fused
+    and routed launches) or a host numpy slice of it — never a device
+    array — so scoring has exactly those two cases to handle."""
+    corpus, query, q_cols = lake
+    index = sessions[128].index
+    queries = [(query, q_cols)] + synthetic.make_mixed_queries(
+        corpus, 2, 12, 2, seed=21
+    )
+    pcs = batched.plan_and_count(index, queries, backend)
+    assert len(pcs) == len(queries)
+    for pc in pcs:
+        assert pc.hits is None or isinstance(pc.hits, np.ndarray)
+        assert (pc.hits is None) == pc.fused
+        if pc.hits is not None:
+            assert pc.hits.dtype == bool
+            # the plan's own block of the group matrix
+            assert pc.hits.shape == pc.plan.elig.dense().shape
+        assert pc.counts.shape == (pc.plan.block.n_tables,)
 
 
 def test_session_stats_accumulate(sessions, lake):
@@ -260,9 +303,9 @@ def test_removed_use_kernel_kwarg_raises(sessions, lake):
     _corpus, query, q_cols = lake
     index = sessions[128].index
     with pytest.raises(TypeError, match="use_kernel"):
-        discover_batched(index, query, q_cols, k=10, use_kernel=False)
+        discover_many(index, [(query, q_cols)], k=10, use_kernel=False)
     # the modern spelling of the old flag
-    got, _ = discover_batched(index, query, q_cols, k=10, backend="numpy")
+    got, _ = discover_many(index, [(query, q_cols)], k=10, backend="numpy")[0]
     ref, _ = discovery.discover(index, query, q_cols, k=10)
     assert _key(got) == _key(ref)
 
@@ -271,9 +314,9 @@ def test_removed_fused_kwarg_raises(sessions, lake):
     _corpus, query, q_cols = lake
     index = sessions[128].index
     with pytest.raises(TypeError, match="fused"):
-        discover_batched(index, query, q_cols, k=10, fused=True)
-    with pytest.raises(TypeError, match="fused"):
         discover_many(index, [(query, q_cols)], k=[5], fused=True)
+    with pytest.raises(TypeError, match="fused"):
+        discover_many(index, [(query, q_cols)], k=10, fused=False)
     with pytest.raises(TypeError, match="fused"):
         DiscoveryEngine(index, batch=2, fused=True)
     with pytest.raises(TypeError, match="use_kernel"):

@@ -158,7 +158,7 @@ def test_regather_pairs_equal_the_full_regather(gathered, monkeypatch):
     # every table above the bound: each is re-gathered, in block order
     B._score_tables(
         index, plan, B._TopK(n_tables + 1), None, np.ones(n_tables, dtype=np.int32),
-        block.rows, 0, n_tables, 0, row_sk=None if gathered else row_sk,
+        row_sk=None if gathered else row_sk,
     )
     assert len(seen) == n_tables
     for t, (rs, ks) in enumerate(seen):
