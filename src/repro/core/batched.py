@@ -39,8 +39,11 @@ blocks:
     0, which the heap would discard) is skipped without a re-gather;
   * only filter-surviving pairs are verified on the host: the faithful
     engine's exact ``calculateJ``, computed on lake value ids in numpy
-    rather than one string comparison per pair.  Where the launch kept no
-    match matrix, a surviving table re-tests just its own eligible pairs.
+    rather than one string comparison per pair.  The gather-fused launch
+    reads back the (item, key) pairs its kernel passed, and a surviving
+    table verifies its own run of them; where a launch kept neither those
+    nor a match matrix, a surviving table re-tests just its own eligible
+    pairs.
 
 Hash width is a first-class knob: every array here is ``lanes``-wide
 (``XashConfig(bits=...)`` → 4/8/16 uint32 lanes for 128/256/512 bits), so the
@@ -233,19 +236,6 @@ def _eligible_pairs(plan: QueryPlan, value_idx: np.ndarray):
     return item, key
 
 
-@dataclasses.dataclass
-class _Pairs:
-    """A slice's filter-surviving (row, key) pairs, row-major: what
-    ``np.nonzero`` of its hit mask gives (``np.nonzero`` calls
-    ``nonzero``), without the [rows, K] mask."""
-
-    rows: np.ndarray
-    keys: np.ndarray
-
-    def nonzero(self) -> tuple[np.ndarray, np.ndarray]:
-        return self.rows, self.keys
-
-
 def _verify_pairs(
     index: MateIndex, plan: QueryPlan, rows: np.ndarray, rs: np.ndarray, ks: np.ndarray
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray, int]:
@@ -280,7 +270,7 @@ def _calculate_j(
     hits: np.ndarray,
 ) -> tuple[int, tuple[int, ...] | None]:
     """Exact verification (Alg. 1 line 21) over filter-surviving pairs
-    (``hits``: a slice's bool mask, or its ``_Pairs``), on lake value ids
+    (``hits``: a slice's bool mask, or its ``ops.Pairs``), on lake value ids
     (``_verify_pairs``).  Counts, joinability and mapping (ties to the
     largest) are those of ``seq._verify_pair`` on the strings."""
     stats = plan.stats
@@ -376,24 +366,29 @@ def _score_tables(
     index: MateIndex,
     plan: QueryPlan,
     topk: _TopK,
-    hits: np.ndarray | None,
+    hits: np.ndarray | ops.Pairs | None,
     counts: np.ndarray,
     row_sk: np.ndarray | None = None,
 ) -> None:
     """Verify (or rule-1/rule-2-prune) every table of the plan's block.
 
-    ``counts`` is the launch's per-table eligible-hit counts; ``hits`` is
-    the plan's host slice of the match matrix, or None — the FUSED
-    counts-only launch, where the match matrix was never produced at all
-    (and every bound-cache replay).  Surviving tables' hits are then
-    recomputed on demand from ``row_sk``, testing only the table's eligible
-    (row, key) pairs (``_eligible_pairs``: the same pairs as ``plan.elig``,
-    the same subsumption predicate → bit-identical verification inputs;
+    ``counts`` is the launch's per-table eligible-hit counts.  ``hits`` is
+    the plan's host slice of the match matrix; or the GATHER-fused
+    launch's own survivors, item-major ``ops.Pairs`` over the plan's items
+    and keys, of which a surviving table takes its run — nothing is tested
+    again, unless the launch probed a lane prefix (``stats.filter_lanes``
+    below the keys' width): its pairs are then a superset, re-tested at full
+    width (``regather_pairs`` counts them); or None — the host-gather FUSED
+    and routed counts-only launches, where the match matrix was never
+    produced at all.  Surviving tables' hits are then recomputed on demand
+    from ``row_sk``, testing only the table's eligible (row, key) pairs
+    (``_eligible_pairs``: the same pairs as ``plan.elig``, the same
+    subsumption predicate → bit-identical verification inputs;
     ``regather_pairs`` counts the pairs tested); pruned tables cost nothing
-    beyond their 4 count bytes.  On the GATHER-fused and routed paths even
-    ``row_sk`` is None — the host never gathered the candidate superkeys —
-    and surviving tables gather just their own slice from the index store
-    (the same ``superkeys`` array every other path reads: bit-identical).
+    beyond their 4 count bytes.  On the routed path even ``row_sk`` is None
+    — the host never gathered the candidate superkeys — and surviving
+    tables gather just their own slice from the index store (the same
+    ``superkeys`` array every other path reads: bit-identical).
 
     Rule 1: tables are PL-desc sorted, so the first whose posting list is
     at/below the bound of a full heap prunes the whole suffix.  Rule 2
@@ -407,6 +402,10 @@ def _score_tables(
     block, stats = plan.block, plan.stats
     ptr, rows, n_tables = block.table_ptr, block.rows, block.n_tables
     lazy = hits is None
+    survivors = isinstance(hits, ops.Pairs)
+    if survivors:
+        run = np.searchsorted(hits.rows, ptr)  # each table's run of pairs
+        retest = 0 < stats.filter_lanes < plan.q_sk.shape[1]
     # per-table clocks only while tracing: the slice re-gather and the exact
     # verification, summed into the score.tables span
     timed = telemetry.enabled()
@@ -436,7 +435,17 @@ def _score_tables(
             continue
         if timed:
             t_a = clock()
-        if lazy:
+        if survivors:
+            a, b = run[t], run[t + 1]
+            sub = ops.Pairs(hits.rows[a:b] - lo, hits.keys[a:b])
+            if retest:
+                rsk = index.superkey_of_rows(rows[lo + sub.rows])
+                ok = ops.subsume_pairs_np(
+                    rsk, plan.q_sk, np.arange(sub.rows.size), sub.keys
+                )
+                sub = ops.Pairs(sub.rows[ok], sub.keys[ok])
+                stats.regather_pairs += int(b - a)
+        elif lazy:
             # the filter again, on the table's eligible (row, key) pairs only
             r, kid = _eligible_pairs(plan, block.value_idx[ptr[t] : ptr[t + 1]])
             rsk = (
@@ -445,7 +454,7 @@ def _score_tables(
                 else index.superkey_of_rows(rows[lo:hi])
             )
             ok = ops.subsume_pairs_np(rsk, plan.q_sk, r, kid)
-            sub = _Pairs(r[ok], kid[ok])
+            sub = ops.Pairs(r[ok], kid[ok])
             stats.regather_pairs += int(r.size)
             # booked as the slice of the match matrix the pairs stand for
             stats.filter_readback_bytes += (hi - lo) * plan.q_sk.shape[0]
@@ -486,16 +495,19 @@ class PlanCounts:
 
     ``counts`` is the per-table eligible-hit count vector driving rule-1/2
     pruning; ``hits`` is this plan's slice of the group match matrix (None
-    on the fused counts-only path, and always None once cached — see
-    ``cacheable``); ``row_sk`` keeps the FULL-width row super keys so a
-    dropped/absent matrix is recomputed lazily during scoring,
-    bit-identically.  On the GATHER-fused launch ``row_sk`` is None too —
-    the host never gathered the superkeys — and scoring gathers surviving
-    tables' slices from the index store instead, which is why ``epoch``
-    matters doubly there: the store read at scoring time must be the store
-    the launch filtered against.  ``epoch`` pins ``MateIndex.mutation_epoch``
-    at launch time: a PlanCounts is replayable only while the index is
-    unchanged.
+    on the fused counts-only paths, and always None once cached — see
+    ``cacheable``); ``pairs`` the GATHER-fused launch's survivors among the
+    plan's items and keys, item-major, which scoring verifies as they are
+    and a cache keeps (O(survivors)); ``row_sk`` keeps the FULL-width row
+    super keys so a dropped/absent matrix is recomputed lazily during
+    scoring, bit-identically.  On the gather-fused and routed launches
+    ``row_sk`` is None — the host never gathered the superkeys — and
+    scoring reads any row it needs (a routed re-gather, a lane-degraded
+    launch's re-test, ``core.fd``'s validation) from the index store, which
+    is why ``epoch`` matters doubly there: the store read at scoring time
+    must be the store the launch filtered against.  ``epoch`` pins
+    ``MateIndex.mutation_epoch`` at launch time: a PlanCounts is replayable
+    only while the index is unchanged.
     """
 
     plan: QueryPlan
@@ -504,6 +516,8 @@ class PlanCounts:
     counts: np.ndarray  # int32[n_tables] per-table eligible-hit counts
     hits: np.ndarray | None = None  # bool[n_items, n_keys]: the host match
     # matrix's block of this plan's rows and keys
+    pairs: ops.Pairs | None = None  # (item, key) pairs the gather-fused
+    # launch passed among this plan's items and keys, item-major
     group_keys: int = 0  # key count of the SHARED launch (accounting)
     fused: bool = False  # counts-only fused launch (no matrix anywhere)
     filter_lanes: int = 0  # lanes the launch probed (< index width: degraded)
@@ -523,7 +537,8 @@ class PlanCounts:
         """A copy safe to hold in a cache: the match-matrix slice is
         dropped; scoring recomputes surviving tables' slices from ``row_sk``
         on demand — same subsumption predicate, so verification inputs (and
-        the top-k) are bit-identical."""
+        the top-k) are bit-identical.  The survivor ``pairs`` stay: they
+        are O(survivors), and a replay verifies them without a re-gather."""
         return dataclasses.replace(self, hits=None)
 
 
@@ -605,9 +620,9 @@ def plan_and_count(
     )
     # gather-fused group launch: no host superkey gather at all — the kernel
     # pulls every request's candidate rows from the device store, and phase B
-    # re-gathers only surviving tables' slices (bit-identical: same array).
-    # The routed group launch shares that contract (row_sk stays None) and
-    # scoring re-gathers from the OWNING shard only.
+    # verifies the (item, key) pairs it passed.  The routed group launch
+    # shares the no-gather contract (row_sk stays None) but returns counts
+    # alone: scoring re-gathers from the OWNING shard only.
     row_sk_all = (
         None if (use_gather or routed) else index.superkey_of_rows(rows_all)
     )
@@ -619,6 +634,7 @@ def plan_and_count(
     # routed byte/launch accounting is attributed per request below)
     group = DiscoveryStats()
     group.gather_demotions = int(not routed and bk.gather and not use_gather)
+    pairs_all = None
     if routed:
         # shard-local counts-only launches for the whole group; per-request
         # routing accounting is attributed below from each plan's own items.
@@ -628,7 +644,8 @@ def plan_and_count(
             backend=bk, fused_block_n=fused_block_n, stats=group,
         )
     elif use_gather:
-        hits_all, counts_all = ops.filter_hits_table_counts(
+        hits_all = None
+        pairs_all, counts_all = ops.filter_hits_table_counts(
             None, q_f, group_elig, seg_all, n_tables_all,
             backend=bk, fused_block_n=fused_block_n,
             store=index.device_store(), rows=rows_all,
@@ -666,6 +683,14 @@ def plan_and_count(
             if routed and ni
             else 0
         )
+        pairs = None
+        if pairs_all is not None:
+            # eligibility is block-diagonal: this plan's items pass only its
+            # own keys
+            a, b = np.searchsorted(pairs_all.rows, [r_off, r_off + ni])
+            pairs = ops.Pairs(
+                pairs_all.rows[a:b] - r_off, pairs_all.keys[a:b] - k_off
+            )
         out.append(
             PlanCounts(
                 plan=p,
@@ -676,6 +701,7 @@ def plan_and_count(
                 counts=counts_all[t_off : t_off + ti],
                 hits=None if hits_all is None
                 else hits_all[r_off : r_off + ni, k_off : k_off + ki],
+                pairs=pairs,
                 group_keys=0 if hits_all is None else int(hits_all.shape[1]),
                 fused=hits_all is None,
                 filter_lanes=fl,
@@ -707,12 +733,15 @@ def _scoring_plan(pc: PlanCounts, *, charge_launch: bool = True) -> QueryPlan:
     stats.pl_items_checked = n_items
     stats.filter_checks = eligible_count(plan, block.value_idx)
     stats.filter_passed = int(pc.counts.sum())
+    stats.filter_pairs_returned = 0 if pc.pairs is None else pc.pairs.rows.size
     stats.filter_lanes = pc.filter_lanes
     if not charge_launch:
         return plan
     if pc.fused:
         stats.filter_fused_launches += 1
         stats.filter_readback_bytes += pc.counts.nbytes
+        if pc.pairs is not None:
+            stats.filter_readback_bytes += pc.pairs.nbytes
         stats.gather_bytes_saved += pc.gather_saved
         stats.shard_launches += pc.route_launches
         stats.route_bytes_merged += pc.route_bytes
@@ -743,8 +772,9 @@ def score_from_counts(
     Re-runnable: stats land on a FRESH copy of the plan's, so the same
     PlanCounts (a bound-cache hit) can be scored any number of times — at
     any ``k``.  ``from_cache=True`` skips the launch-transfer accounting
-    (an earlier request already paid for the filter) and forces the
-    lazy-recompute path, since cached entries hold no matrix slice.
+    (an earlier request already paid for the filter); cached entries hold
+    no matrix slice, so scoring verifies their survivor pairs or, without
+    those, takes the lazy-recompute path.
     """
     plan = _scoring_plan(pc, charge_launch=not from_cache)
     stats, block = plan.stats, plan.block
@@ -760,8 +790,10 @@ def score_from_counts(
     topk = _TopK(k)
     with telemetry.span("score.tables"):
         _score_tables(
-            index, plan, topk, None if from_cache else pc.hits, pc.counts,
-            row_sk=pc.row_sk,
+            index, plan, topk,
+            pc.pairs if pc.pairs is not None
+            else None if from_cache else pc.hits,
+            pc.counts, row_sk=pc.row_sk,
         )
     return _ranked_entries(topk, rank, scores), stats
 

@@ -38,13 +38,18 @@ class DiscoveryStats:
     verified_tp: int = 0  # pairs passing exact verification
     verified_fp: int = 0  # pairs surviving filter but failing verification
     regather_pairs: int = 0  # eligible (row, key) pairs the phase-B re-gather
-    # tested again on the host for surviving tables (counts-only launches;
-    # read against filter_checks, the pairs the launch tested)
+    # tested again on the host for surviving tables (counts-only launches,
+    # and a lane-degraded gather-fused launch's returned pairs; read
+    # against filter_checks, the pairs the launch tested)
+    filter_pairs_returned: int = 0  # surviving (row, key) pairs the
+    # gather-fused launch read back for phase B to verify (== filter_passed
+    # there; 0 on the launches that return counts or a match matrix)
     # batched-engine transfer accounting (device-side rule 1/2):
     filter_matrix_bytes: int = 0  # full match-matrix bytes the filter produced
     filter_readback_bytes: int = 0  # match bytes materialised host-side
     # (the whole matrix on the non-fused backends; on the fused ones the
-    # counts vector + the slices recomputed for surviving tables)
+    # counts vector + the slices recomputed for surviving tables, or the
+    # gather-fused launch's survivor pairs)
     filter_fused_launches: int = 0  # fused filter+segment-count launches:
     # the match matrix was never produced (not even in HBM), so these
     # contribute ZERO to filter_matrix_bytes — counts-only readback plus

@@ -48,6 +48,7 @@ import time
 
 import numpy as np
 
+from repro import telemetry
 from repro.core import profiles as profiles_lib
 from repro.core import xash
 from repro.core.corpus import Corpus, Table
@@ -478,11 +479,16 @@ class ShardedMateIndex:
         """One shard-local counts-only launch (gather-fused → fused → host)."""
         fl = query_sk.shape[1]
         if bk.gather and ops.gather_store_fits(shard.superkeys):
-            _, c = ops.filter_hits_table_counts(
-                None, query_sk, elig_s, seg_s, n_tables, backend=bk,
-                fused_block_n=fused_block_n, store=shard.device_store(),
-                rows=local,
-            )
+            # counts alone: scoring re-gathers from the owning shard, so the
+            # launch's surviving pairs are never read back
+            with telemetry.span("filter.launch"):
+                c = ops.chunked_counts(
+                    lambda r, e, s, nt: ops.gather_filter_table_counts(
+                        shard.device_store(), r, query_sk, e, s, nt,
+                        block_n=fused_block_n,
+                    ),
+                    seg_s, elig_s, n_tables, local,
+                )
             if stats is not None:
                 stats.filter_fused_launches += 1
                 stats.gather_bytes_saved += int(local.shape[0]) * (fl * 4 - 4)

@@ -234,6 +234,8 @@ class SessionStats:
     verified_tp: int = 0
     verified_fp: int = 0
     regather_pairs: int = 0  # eligible pairs the phase-B re-gather tested
+    filter_pairs_returned: int = 0  # survivor pairs gather-fused launches
+    # read back for verification
     filter_matrix_bytes: int = 0
     filter_readback_bytes: int = 0
     filter_fused_launches: int = 0

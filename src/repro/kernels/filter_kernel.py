@@ -8,6 +8,9 @@ the match matrix, a fused per-query count, or a fused per-TABLE segment count
 scatter-accumulated over the CSR table ids — the reduction happens in VMEM,
 the n×q matrix never reaches HBM, which is what makes the filter
 memory-roofline-optimal: 16 bytes read per row, 4 bytes written per table).
+The gather-fused variant (``gather_filter_table_counts``) also writes the
+masked matrix packed one bit per pair, n×q / 8 bytes, so that the launch
+can read back the surviving pairs without the host testing them again.
 
 Layout note: super keys live on the host as ``uint32[n, lanes]``; lanes is
 tiny (4 for 128-bit hashes) and would be a terrible minor-most dim for the
@@ -122,18 +125,16 @@ def filter_match(
     )(row_sk_t, query_sk_t)
 
 
-def _scatter_counts(acc, seg, counts_ref, first, mode: str = "sum"):
-    """Row-reduce a masked [bn, bq] hit tile and scatter it into per-table
-    counts: the one-hot f32 matvec shared by both fused kernels.
+def _scatter_counts(per_row, seg, counts_ref, first):
+    """Scatter per-row hit counts into per-table counts: the one-hot f32
+    matvec shared by both fused kernels.
 
-    ``seg`` is the int32[bn, 1] table-id column (-1 matches no iota column,
-    so padding rows contribute 0).  The one-hot [bn, tb] is contracted over
-    its row axis on the MXU; f32 accumulation is exact here (per-step
-    partials are bounded by bn·bq « 2^24)."""
-    per_row = jnp.sum(acc.astype(jnp.int32), axis=1, keepdims=True)  # [bn, 1]
-    if mode == "any":
-        per_row = (per_row > 0).astype(jnp.int32)
-    bn, tb = acc.shape[0], counts_ref.shape[1]
+    ``per_row`` and ``seg`` are int32[bn, 1] columns (row hit counts, table
+    ids; -1 matches no iota column, so padding rows contribute 0).  The
+    one-hot [bn, tb] is contracted over its row axis on the MXU; f32
+    accumulation is exact here (per-step partials are bounded by bn·bq «
+    2^24)."""
+    bn, tb = per_row.shape[0], counts_ref.shape[1]
     onehot = seg == jax.lax.broadcasted_iota(jnp.int32, (bn, tb), 1)
     partial = jax.lax.dot_general(
         per_row.astype(jnp.float32),
@@ -151,21 +152,19 @@ def _scatter_counts(acc, seg, counts_ref, first, mode: str = "sum"):
         counts_ref[...] += partial
 
 
-def _mask_tile(acc, elig_refs, seg, j, n_queries: int):
+def _mask_tile(acc, elig, seg, key_index, n_queries: int):
     """Eligibility, padded-query-column and padding-row masks of a hit tile.
 
-    ``elig_refs`` is None (all eligible) or the pair (item value ids
-    int32[1, bn], key value ids int32[1, bq]): the eligibility tile is
-    ``item == key``, formed here from the two id blocks.  Padded query
-    columns (col id ≥ n_queries) carry all-ones super keys that match
-    nothing EXCEPT saturated (all-ones) row super keys, which would
-    otherwise be overcounted when no eligibility ids mask them."""
-    bn, bq = acc.shape
-    if elig_refs is not None:
-        item_ref, kval_ref = elig_refs
-        acc = acc & (item_ref[...].reshape(bn, 1) == kval_ref[...])
-    col = j * bq + jax.lax.broadcasted_iota(jnp.int32, (bn, bq), 1)
-    return acc & (col < n_queries) & (seg >= 0)
+    ``elig`` is None (all eligible) or the tile's eligibility, formed by
+    the caller from the two id blocks (item init-value id == key
+    init-value id); ``seg`` the rows' table ids and ``key_index`` each
+    element's query-key index, both broadcastable to ``acc``.  Padded query
+    keys (index ≥ n_queries) carry all-ones super keys that match nothing
+    EXCEPT saturated (all-ones) row super keys, which would otherwise be
+    overcounted when no eligibility ids mask them."""
+    if elig is not None:
+        acc = acc & elig
+    return acc & (key_index < n_queries) & (seg >= 0)
 
 
 def _table_counts_kernel(
@@ -195,13 +194,11 @@ def _table_counts_kernel(
     """
     if has_elig:
         row_ref, query_ref, item_ref, kval_ref, seg_ref, counts_ref, key_ref = refs
-        elig_refs = (item_ref, kval_ref)
     else:
         row_ref, query_ref, seg_ref, counts_ref, key_ref = refs
-        elig_refs = None
     j = pl.program_id(0)  # query-block index
     i = pl.program_id(1)  # row-block index (inner grid axis → sequential)
-    bn = row_ref.shape[1]
+    bn, bq = row_ref.shape[1], query_ref.shape[1]
     acc = None
     for lane in range(lanes):
         r = row_ref[lane, :][:, None]  # [bn, 1]
@@ -209,11 +206,16 @@ def _table_counts_kernel(
         ok = (q & ~r) == 0  # [bn, bq]
         acc = ok if acc is None else (acc & ok)
     seg = seg_ref[...].reshape(bn, 1)
-    acc = _mask_tile(acc, elig_refs, seg, j, n_queries)
-    key_partial = jnp.sum(acc.astype(jnp.int32), axis=0, keepdims=True)
-    _scatter_counts(
-        acc, seg, counts_ref, jnp.logical_and(i == 0, j == 0), mode
+    elig = (
+        item_ref[...].reshape(bn, 1) == kval_ref[...] if has_elig else None
     )
+    key_index = j * bq + jax.lax.broadcasted_iota(jnp.int32, (bn, bq), 1)
+    acc = _mask_tile(acc, elig, seg, key_index, n_queries)
+    key_partial = jnp.sum(acc.astype(jnp.int32), axis=0, keepdims=True)
+    per_row = jnp.sum(acc.astype(jnp.int32), axis=1, keepdims=True)  # [bn, 1]
+    if mode == "any":
+        per_row = (per_row > 0).astype(jnp.int32)
+    _scatter_counts(per_row, seg, counts_ref, jnp.logical_and(i == 0, j == 0))
 
     @pl.when(i == 0)
     def _init_keys():
@@ -313,7 +315,7 @@ def _gather_counts_kernel(
     block_n: int,
 ):
     """Gather-fused filter + segment-count: one launch from posting-list row
-    offsets to per-table counts.
+    offsets to per-table counts and the packed hit tile.
 
     The candidate rows' super keys are DMA-gathered from the device-resident
     PACKED store (HBM, ``memory_space=ANY``) straight into a VMEM scratch
@@ -324,7 +326,14 @@ def _gather_counts_kernel(
     ``per = 128 // store_lanes``.  Each DMA copies the one 512-byte line
     holding its row (a DMA's minor extent must be the whole 128-lane tile
     row); the row's lanes are then shifted to the tile's first columns by a
-    masked rotate-and-add fold, once per row block.
+    masked rotate-and-add fold, and the [bn, 128] fold is transposed so
+    that each super-key lane is one [1, bn] row, once per row block.
+
+    The hit tile is formed transposed, [bq, bn] with query keys on sublanes
+    and rows on lanes: each row's hit count is a sublane sum, and each run
+    of 32 keys packs into one uint32 word per row by a shift and a sublane
+    sum, so the masked tile leaves the kernel as ``words[q / 32, n]``
+    (bit ``b`` of word ``[w, r]``: row ``r`` passes key ``32·w + b``).
 
     Refs (has_elig sets arity):
       rows_smem:  int32[1, bn]        row offsets (SMEM: DMA addresses)
@@ -335,8 +344,9 @@ def _gather_counts_kernel(
       kval_ref:   int32[1, bq]        key init-value ids (only when has_elig)
       seg_ref:    int32[1, bn]        table index per row; -1 = padding row
       counts_ref: int32[1, tb]        per-table counts (ONE block, all steps)
+      words_ref:  int32[bq / 32, bn]  the masked hit tile, 32 keys a word
       line_vmem:  uint32[bn, 128]     gathered store lines
-      key_vmem:   int32[bn, 128]      each row's lanes in columns 0..lanes-1
+      key_vmem:   int32[128, bn]      each row's lanes in rows 0..lanes-1
       sem:        DMA semaphore for the gather copies
 
     Grid is (row blocks, query blocks) with the QUERY axis innermost, the
@@ -352,12 +362,13 @@ def _gather_counts_kernel(
     """
     rows_smem, rows_ref, store_ref, query_ref = refs[:4]
     if has_elig:
-        elig_refs, refs = refs[4:6], refs[6:]
+        (item_ref, kval_ref), refs = refs[4:6], refs[6:]
     else:
-        elig_refs, refs = None, refs[4:]
-    seg_ref, counts_ref, line_vmem, key_vmem, sem = refs
+        refs = refs[4:]
+    seg_ref, counts_ref, words_ref, line_vmem, key_vmem, sem = refs
     i = pl.program_id(0)  # row-block index (outer)
     j = pl.program_id(1)  # query-block index (inner → scratch reuse across j)
+    bq = query_ref.shape[1]
     per_line = STORE_LINE // store_lanes  # rows per line, a power of two
     slot_bits = per_line.bit_length() - 1
     lane_bits = store_lanes.bit_length() - 1
@@ -396,19 +407,32 @@ def _gather_counts_kernel(
         while step >= store_lanes:
             keys = keys + pltpu.roll(keys, step, 1)
             step //= 2
-        key_vmem[...] = keys
+        key_vmem[...] = keys.T
 
     acc = None
     for lane in range(lanes):
-        r = key_vmem[:, lane : lane + 1]  # [bn, 1]
+        r = key_vmem[lane : lane + 1, :]  # [1, bn]
         q = jax.lax.bitcast_convert_type(
             query_ref[lane : lane + 1, :], jnp.int32
-        )  # [1, bq]
-        ok = (q & ~r) == 0  # [bn, bq]
+        ).reshape(bq, 1)
+        ok = (q & ~r) == 0  # [bq, bn]
         acc = ok if acc is None else (acc & ok)
-    seg = seg_ref[...].reshape(block_n, 1)
-    acc = _mask_tile(acc, elig_refs, seg, j, n_queries)
-    _scatter_counts(acc, seg, counts_ref, jnp.logical_and(i == 0, j == 0))
+    seg = seg_ref[...]  # [1, bn]
+    elig = kval_ref[...].reshape(bq, 1) == item_ref[...] if has_elig else None
+    key_index = j * bq + jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
+    acc = _mask_tile(acc, elig, seg, key_index, n_queries)
+    per_row = jnp.sum(acc.astype(jnp.int32), axis=0, keepdims=True)  # [1, bn]
+    _scatter_counts(
+        per_row.reshape(block_n, 1), seg.reshape(block_n, 1), counts_ref,
+        jnp.logical_and(i == 0, j == 0),
+    )
+    # bit (key % 32) of each hit; the 32 distinct powers of two of a word
+    # sum to their OR (int32 wraps at bit 31 and keeps the bit pattern)
+    bits = jnp.where(acc, jnp.left_shift(1, key_index & 31), 0)
+    for w in range(bq // 32):
+        words_ref[w : w + 1, :] = jnp.sum(
+            bits[32 * w : 32 * (w + 1), :], axis=0, keepdims=True
+        )
 
 
 @functools.partial(
@@ -431,8 +455,8 @@ def gather_filter_table_counts(
     block_n: int = DEFAULT_BLOCK_N,
     block_q: int = DEFAULT_BLOCK_Q,
     interpret: bool = False,
-) -> jnp.ndarray:
-    """Gather-fused filter + per-table segment count.
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Gather-fused filter + per-table segment count + packed hits.
 
     One launch from posting-list offsets to counts: each grid step
     DMA-gathers its row block's store lines into VMEM before the fused
@@ -447,22 +471,27 @@ def gather_filter_table_counts(
       store:      uint32[n_lines, 128] packed super-key store (see
                   ``_gather_counts_kernel``), ``store_lanes`` lanes per row.
       query_sk_t: uint32[lanes, q] transposed query super keys (q divisible
-                  by block_q); ``lanes <= store_lanes`` — a strict prefix
-                  probes a lane-degraded filter over the full-width store.
+                  by block_q, block_q by 32); ``lanes <= store_lanes`` — a
+                  strict prefix probes a lane-degraded filter over the
+                  full-width store.
       elig:       (item_value int32[n], key_value int32[q]) init-value ids
                   (see ``filter_table_counts``), or None for all-eligible.
       seg_ids:    int32[n] table index per row (-1 for padding rows).
       n_tables:   padded table count tb (multiple of 128).
       n_queries:  number of REAL queries (≤ q).
     Returns:
-      counts int32[tb] — the ONLY output (no per-key counts: the grid runs
-      query-blocks innermost so the gather amortises over them, which rules
-      out the per-key accumulation layout of ``filter_table_counts``).
+      (counts int32[tb], words int32[q / 32, n]) — ``words`` is the masked
+      hit matrix, bit ``k % 32`` of word ``[k // 32, i]`` set where row
+      ``i`` passes key ``k``; its set bits sum to ``counts``.  No per-key
+      counts: the grid runs query-blocks innermost so the gather amortises
+      over them, which rules out the per-key accumulation layout of
+      ``filter_table_counts``.
     """
     lanes, q = query_sk_t.shape
     n = rows.shape[0]
     assert lanes <= store_lanes and STORE_LINE % store_lanes == 0
     assert store.shape[1] == STORE_LINE, store.shape
+    assert block_q % 32 == 0, block_q
     n_queries = q if n_queries is None else n_queries
     grid = (n // block_n, q // block_q)  # query axis INNER → scratch reuse
     rows2 = rows.reshape(1, n)
@@ -482,7 +511,7 @@ def gather_filter_table_counts(
         operands += [item_value.reshape(1, n), key_value.reshape(1, q)]
     in_specs.append(pl.BlockSpec((1, block_n), lambda i, j: (0, i)))
     operands.append(seg_ids.reshape(1, n))
-    counts = pl.pallas_call(
+    counts, words = pl.pallas_call(
         functools.partial(
             _gather_counts_kernel,
             lanes=lanes,
@@ -493,18 +522,24 @@ def gather_filter_table_counts(
         ),
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, n_tables), lambda i, j: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, n_tables), jnp.int32),
+        out_specs=[
+            pl.BlockSpec((1, n_tables), lambda i, j: (0, 0)),
+            pl.BlockSpec((block_q // 32, block_n), lambda i, j: (j, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((1, n_tables), jnp.int32),
+            jax.ShapeDtypeStruct((q // 32, n), jnp.int32),
+        ],
         scratch_shapes=[
             pltpu.VMEM((block_n, STORE_LINE), jnp.uint32),
-            pltpu.VMEM((block_n, STORE_LINE), jnp.int32),
+            pltpu.VMEM((STORE_LINE, block_n), jnp.int32),
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
         # the device trace and the benchmark find the kernel by this name
         name="gather_filter_table_counts",
     )(*operands)
-    return counts[0]
+    return counts[0], words
 
 
 @functools.partial(

@@ -332,6 +332,27 @@ class Eligibility:
         return item, key
 
 
+@dataclasses.dataclass(frozen=True)
+class Pairs:
+    """(row, key) index pairs a filter passed, row-major with keys ascending
+    within a row: what ``np.nonzero`` of a hit mask gives (``np.nonzero``
+    calls ``nonzero``), without the [rows, keys] mask."""
+
+    rows: np.ndarray  # int64[p]
+    keys: np.ndarray  # int64[p]
+
+    def nonzero(self) -> tuple[np.ndarray, np.ndarray]:
+        return self.rows, self.keys
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.rows.nbytes + self.keys.nbytes)
+
+    @staticmethod
+    def empty() -> "Pairs":
+        return Pairs(np.zeros(0, np.int64), np.zeros(0, np.int64))
+
+
 def _count_upload(elig_ids, *operands) -> None:
     """Counters of the open ``filter.launch`` span: the padded operands sent
     to the device (``h2d_bytes``) and the eligibility operands alone
@@ -561,6 +582,64 @@ def chunked_counts(launch, seg_ids, elig, n_tables: int, *per_item):
     return counts
 
 
+def _gather_launch(
+    store: DeviceStore,
+    rows: np.ndarray,
+    query_sk: np.ndarray | jnp.ndarray,
+    elig: Eligibility | None,
+    seg_ids: np.ndarray,
+    n_tables: int,
+    block_n: int | None,
+    interpret: bool | None,
+) -> tuple[jax.Array, jax.Array]:
+    """Pad, upload and run one gather-fused kernel call: (device counts
+    int32[tb], device hit words int32[qb / 32, nb]); see
+    ``gather_filter_table_counts``."""
+    if n_tables > _FUSED_MAX_TABLES:
+        raise ValueError(
+            f"gather-fused scatter tile supports at most {_FUSED_MAX_TABLES}"
+            f" tables per launch, got {n_tables} — split the launch with"
+            " table_chunks"
+        )
+    interpret = _on_cpu() if interpret is None else interpret
+    n, q = rows.shape[0], query_sk.shape[0]
+    nb = _bucket(n, _FALLBACK_MIN_N)
+    qb = _pow2_bucket(q, _FALLBACK_MIN_Q)
+    tb = max(-(-n_tables // 128) * 128, 128)
+    budget_n = filter_kernel.fused_block_n(tb)
+    if block_n is not None:
+        _check_fused_block_n(block_n)
+        budget_n = min(budget_n, block_n)
+    block_n = min(nb, budget_n)
+    block_q = min(qb, filter_kernel.DEFAULT_BLOCK_Q)
+    with telemetry.span("filter.pad"):
+        # padding offsets point at row 0 (always valid); their seg id is -1
+        # so they scatter nowhere regardless of what row 0's superkey matches.
+        rows_p = np.zeros(nb, dtype=np.int32)
+        rows_p[:n] = rows
+        qry_p = np.full((qb, query_sk.shape[1]), 0xFFFFFFFF, dtype=np.uint32)
+        qry_p[:q] = query_sk
+        seg_p = np.full(nb, -1, dtype=np.int32)
+        seg_p[:n] = seg_ids
+        elig_ids = None if elig is None else elig.padded(nb, qb)
+    with telemetry.span("filter.upload"):
+        out = filter_kernel.gather_filter_table_counts(
+            jnp.asarray(rows_p),
+            store.lines,
+            jnp.asarray(qry_p).T,
+            None if elig_ids is None else tuple(map(jnp.asarray, elig_ids)),
+            jnp.asarray(seg_p),
+            store_lanes=store.lanes,
+            n_tables=tb,
+            n_queries=q,
+            block_n=block_n,
+            block_q=block_q,
+            interpret=interpret,
+        )
+    _count_upload(elig_ids, rows_p, qry_p, seg_p)
+    return out
+
+
 def gather_filter_table_counts(
     store: DeviceStore,
     rows: np.ndarray,
@@ -598,53 +677,123 @@ def gather_filter_table_counts(
     Returns:
       int32[n_tables] counts on the host — bit-identical to
       ``filter_table_counts(superkeys[rows][:, :lanes], ...)`` (mode='sum').
+      The kernel's hit words stay on the device (``gather_filter_survivors``
+      reads the pairs back).
     """
     n, q = rows.shape[0], query_sk.shape[0]
     if n == 0 or q == 0 or n_tables == 0:
         return np.zeros(n_tables, dtype=np.int32)
-    if n_tables > _FUSED_MAX_TABLES:
-        raise ValueError(
-            f"gather-fused scatter tile supports at most {_FUSED_MAX_TABLES}"
-            f" tables per launch, got {n_tables} — split the launch with"
-            " table_chunks"
-        )
-    interpret = _on_cpu() if interpret is None else interpret
-    nb = _bucket(n, _FALLBACK_MIN_N)
-    qb = _pow2_bucket(q, _FALLBACK_MIN_Q)
-    tb = max(-(-n_tables // 128) * 128, 128)
-    budget_n = filter_kernel.fused_block_n(tb)
-    if block_n is not None:
-        _check_fused_block_n(block_n)
-        budget_n = min(budget_n, block_n)
-    block_n = min(nb, budget_n)
-    block_q = min(qb, filter_kernel.DEFAULT_BLOCK_Q)
-    with telemetry.span("filter.pad"):
-        # padding offsets point at row 0 (always valid); their seg id is -1
-        # so they scatter nowhere regardless of what row 0's superkey matches.
-        rows_p = np.zeros(nb, dtype=np.int32)
-        rows_p[:n] = rows
-        qry_p = np.full((qb, query_sk.shape[1]), 0xFFFFFFFF, dtype=np.uint32)
-        qry_p[:q] = query_sk
-        seg_p = np.full(nb, -1, dtype=np.int32)
-        seg_p[:n] = seg_ids
-        elig_ids = None if elig is None else elig.padded(nb, qb)
-    with telemetry.span("filter.upload"):
-        counts = filter_kernel.gather_filter_table_counts(
-            jnp.asarray(rows_p),
-            store.lines,
-            jnp.asarray(qry_p).T,
-            None if elig_ids is None else tuple(map(jnp.asarray, elig_ids)),
-            jnp.asarray(seg_p),
-            store_lanes=store.lanes,
-            n_tables=tb,
-            n_queries=q,
-            block_n=block_n,
-            block_q=block_q,
-            interpret=interpret,
-        )
-    _count_upload(elig_ids, rows_p, qry_p, seg_p)
+    counts, _words = _gather_launch(
+        store, rows, query_sk, elig, seg_ids, n_tables, block_n, interpret
+    )
     with telemetry.span("filter.readback"):
         return np.asarray(counts)[:n_tables]
+
+
+# the least capacity of a survivor compaction: fewer compiled shapes
+_MIN_PAIRS_BUCKET = 1024
+
+
+_PREFIX_BLOCK = 128
+
+
+def _running_count(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive running sum of a flat 0/1 float32 vector, by matmuls with
+    a 128 × 128 upper-triangular matrix, one level per factor of 128.
+
+    It stands in for ``jnp.cumsum``, whose TPU lowering takes seconds to
+    minutes to compile for each new length; these matmuls compile in under
+    a second.  Exact: every partial sum is an integer below 2^24, and the
+    matmuls run at full float32 precision."""
+    n = x.shape[0]
+    b = min(n, _PREFIX_BLOCK)
+    tri = jnp.triu(jnp.ones((b, b), jnp.float32))
+    pad = -n % b
+    rows = jnp.pad(x, (0, pad)).reshape(-1, b)
+    inner = jnp.matmul(rows, tri, precision=jax.lax.Precision.HIGHEST)
+    if rows.shape[0] > 1:
+        totals = inner[:, -1]
+        inner = inner + (_running_count(totals) - totals)[:, None]
+    return inner.reshape(-1)[:n]
+
+
+@functools.partial(jax.jit, static_argnames=("size",))
+def _nonzero_words(words: jnp.ndarray, *, size: int):
+    """The nonzero hit words in item-major order, at capacity ``size``:
+    (flat index ``item · n_words + word``, word); the slots past the last
+    nonzero word hold word 0.  Slot ``j`` is the first position at which
+    the running count of nonzero words reaches ``j + 1``: a binary search,
+    where ``jnp.nonzero`` would scatter-add over every word.  The words are
+    gathered by (word, item): a gather from the transposed, flattened words
+    takes the TPU compiler up to a minute for narrow ``words``."""
+    n_words = words.shape[0]
+    nonzero = (words.T != 0).reshape(-1).astype(jnp.float32)
+    seen = _running_count(nonzero).astype(jnp.int32)
+    at = jnp.searchsorted(seen, jnp.arange(1, size + 1, dtype=jnp.int32))
+    at = at.astype(jnp.int32)
+    word = words.at[at % n_words, at // n_words].get(mode="fill", fill_value=0)
+    return at, word
+
+
+def _surviving_pairs(words: jax.Array, n_pairs: int) -> Pairs:
+    """Read the launch's ``n_pairs`` set bits of ``words`` (int32[w, n],
+    bit ``k % 32`` of word ``[k // 32, i]``: item ``i`` passes key ``k``)
+    back as item-major ``Pairs``.  The device compacts the nonzero words
+    (at most ``n_pairs`` of them) and the host expands their bits, lowest
+    first, each word's into the slots its bit count reserves."""
+    if not n_pairs:
+        return Pairs.empty()
+    at, word = jax.device_get(
+        _nonzero_words(words, size=_pow2_bucket(n_pairs, _MIN_PAIRS_BUCKET))
+    )
+    telemetry.count("pairs", n_pairs)
+    telemetry.count("pair_bytes", int(at.nbytes + word.nbytes))
+    live = int(np.count_nonzero(word))  # the nonzero words lead
+    word = word[:live].view(np.uint32)
+    n_bits = np.bitwise_count(word)
+    at = np.repeat(at[:live].astype(np.int64), n_bits)
+    if at.size != n_pairs:
+        raise RuntimeError(
+            f"the filter kernel's hit words hold {at.size} pairs, its counts"
+            f" {n_pairs}"
+        )
+    bit = np.empty(n_pairs, dtype=np.int64)
+    slot = np.cumsum(n_bits, dtype=np.int64) - n_bits
+    while word.size:
+        low = word & -word  # the lowest set bit, 2^b: frexp gives b + 1
+        bit[slot] = np.frexp(low)[1] - 1
+        word = word ^ low
+        more = np.flatnonzero(word)
+        word, slot = word[more], slot[more] + 1
+    item, w = np.divmod(at, words.shape[0])
+    return Pairs(item, w * 32 + bit)
+
+
+def gather_filter_survivors(
+    store: DeviceStore,
+    rows: np.ndarray,
+    query_sk: np.ndarray | jnp.ndarray,
+    elig: Eligibility | None,
+    seg_ids: np.ndarray,
+    n_tables: int,
+    *,
+    block_n: int | None = None,
+    interpret: bool | None = None,
+) -> tuple[Pairs, np.ndarray]:
+    """``gather_filter_table_counts`` that also reads back the pairs the
+    filter passed: (item-major ``Pairs`` of item index and key index, the
+    counts).  The counts come back first; their sum is the number of pairs,
+    which sizes the device-side compaction of the kernel's hit words (none
+    when it is 0), so the readback is O(survivors), not O(items × keys)."""
+    n, q = rows.shape[0], query_sk.shape[0]
+    if n == 0 or q == 0 or n_tables == 0:
+        return Pairs.empty(), np.zeros(n_tables, dtype=np.int32)
+    counts, words = _gather_launch(
+        store, rows, query_sk, elig, seg_ids, n_tables, block_n, interpret
+    )
+    with telemetry.span("filter.readback"):
+        counts = np.asarray(counts)[:n_tables]
+        return _surviving_pairs(words, int(counts.sum())), counts
 
 
 def filter_hits_table_counts(
@@ -659,7 +808,7 @@ def filter_hits_table_counts(
     fused_block_n: int | None = None,
     store: DeviceStore | None = None,
     rows: np.ndarray | None = None,
-) -> tuple[np.ndarray | jnp.ndarray | None, np.ndarray]:
+) -> tuple[np.ndarray | jnp.ndarray | Pairs | None, np.ndarray]:
     """Device-side inputs for the §6.2 bound checks: eligible filter hits plus
     per-table hit counts, WITHOUT transferring the match matrix to the host.
 
@@ -683,8 +832,11 @@ def filter_hits_table_counts(
       (hits, counts) — ``counts`` int32[n_tables] is the one per-launch host
       readback the rule-1/rule-2 bounds consume.  On the composed XLA/Pallas
       paths ``hits`` bool[n, q] stays device-resident (slice it per surviving
-      table; only those slices are ever read back).  On the FUSED paths
-      ``hits`` is None: the match matrix was never produced at all — callers
+      table; only those slices are ever read back).  On the gather-fused
+      path ``hits`` is the filter's own survivors, item-major ``Pairs`` of
+      (item, key) indices read back from the kernel's packed hit tile
+      (``gather_filter_survivors``).  On the host-gather FUSED path ``hits``
+      is None: the match matrix was never produced at all — callers
       recompute the (few) surviving tables' slices on demand.  ``row_sk`` may
       be None when ``store``+``rows`` are given (the gather-fused contract:
       the host never gathers the candidate superkeys).  Fused launches over
@@ -693,19 +845,25 @@ def filter_hits_table_counts(
     with telemetry.span("filter.launch"):
         n = rows.shape[0] if row_sk is None else row_sk.shape[0]
         q = query_sk.shape[0]
-        if n == 0 or q == 0 or n_tables == 0:
-            return np.zeros((n, q), dtype=bool), np.zeros(n_tables, dtype=np.int32)
         if not use_device:
             backend = "numpy"
         backend = registry.resolve_backend(backend).name
         if backend == "fused-gather" and store is not None:
-            counts = chunked_counts(
-                lambda r, e, s, nt: gather_filter_table_counts(
-                    store, r, query_sk, e, s, nt, block_n=fused_block_n
-                ),
-                seg_ids, elig, n_tables, np.asarray(rows),
-            )
-            return None, counts
+            counts = np.zeros(n_tables, dtype=np.int32)
+            found = []
+            seg, rows = np.asarray(seg_ids), np.asarray(rows)
+            for sl, t_lo, t_hi in table_chunks(seg, n_tables):
+                pairs, counts[t_lo:t_hi] = gather_filter_survivors(
+                    store, rows[sl], query_sk, None if elig is None else elig[sl],
+                    seg[sl] - t_lo, t_hi - t_lo, block_n=fused_block_n,
+                )
+                found.append(Pairs(pairs.rows + sl.start, pairs.keys))
+            return Pairs(
+                np.concatenate([p.rows for p in found]),
+                np.concatenate([p.keys for p in found]),
+            ), counts
+        if n == 0 or q == 0 or n_tables == 0:
+            return np.zeros((n, q), dtype=bool), np.zeros(n_tables, dtype=np.int32)
         if backend in ("fused", "fused-gather"):
             # fused-gather without a store: the caller kept the store off the
             # device (over budget) and counts that demotion

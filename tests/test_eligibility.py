@@ -80,15 +80,19 @@ def _launch(case, monkeypatch, backend):
     want = _dense_counts(row_sk, q_sk, dense, seg, n_tables)
     assert want.any()
     if backend == "fused-gather":
-        hits, got = ops.filter_hits_table_counts(
+        pairs, got = ops.filter_hits_table_counts(
             None, q_sk, elig, seg, n_tables, backend=backend,
             store=ops.device_store(store), rows=rows,
         )
+        # the launch's own survivors: the dense block's hits, item-major
+        want_pairs = np.nonzero(ops.subsume_np(row_sk, q_sk) & dense)
+        assert np.array_equal(pairs.rows, want_pairs[0])
+        assert np.array_equal(pairs.keys, want_pairs[1])
     else:
         hits, got = ops.filter_hits_table_counts(
             row_sk, q_sk, elig, seg, n_tables, backend=backend
         )
-    assert hits is None
+        assert hits is None
     return got, want
 
 
@@ -101,7 +105,8 @@ def test_table_counts_kernel_ids_equal_dense(case, monkeypatch):
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_gather_kernel_ids_equal_dense(case, monkeypatch):
-    """``filter_kernel.gather_filter_table_counts`` from the id operands."""
+    """``filter_kernel.gather_filter_table_counts`` from the id operands:
+    its counts, and the pairs its packed hit tile returns."""
     got, want = _launch(case, monkeypatch, "fused-gather")
     assert np.array_equal(got, want), case
 

@@ -101,16 +101,110 @@ def test_gather_counts_match_host_gather(bits, n, q, n_tables):
 @pytest.mark.parametrize("bits", ALL_BITS)
 def test_gather_dispatch_counts_only_no_host_superkeys(bits):
     """The fused-gather dispatch accepts row_sk=None — the host never gathers
-    — and returns hits=None with composed-identical counts."""
+    — and returns composed-identical counts with the filter's own
+    survivors, item-major (item, key) pairs, in place of a hits matrix."""
     lanes = xash.XashConfig(bits=bits).lanes
     store, rows, q_sk, elig, seg = _rand_case(lanes, 420, 17, 7, seed=bits)
-    hits, counts = ops.filter_hits_table_counts(
+    pairs, counts = ops.filter_hits_table_counts(
         None, q_sk, elig, seg, 7, backend="fused-gather",
         store=ops.device_store(store), rows=rows,
     )
-    assert hits is None
+    assert isinstance(pairs, ops.Pairs)
     want = ops.filter_table_counts(store[rows], q_sk, elig, seg, 7)
     assert np.array_equal(counts, want)
+    rs, ks = np.nonzero(ops.subsume_np(store[rows], q_sk) & elig.dense())
+    assert np.array_equal(pairs.rows, rs) and np.array_equal(pairs.keys, ks)
+    assert pairs.rows.size == int(counts.sum()) > 0
+
+
+def _packed_reference(store, rows, q_sk, elig, seg, n_queries, qb):
+    """The kernel's hit words from the dense masked reference: bit
+    ``k % 32`` of word ``[k // 32, i]`` where row ``i`` passes key ``k``
+    (``subsume_np`` ∧ ``Eligibility.dense()``, padding rows and padded key
+    columns cleared)."""
+    hits = np.zeros((rows.shape[0], qb), dtype=bool)
+    lanes = q_sk.shape[1]
+    hits[:, :n_queries] = ops.subsume_np(store[rows][:, :lanes], q_sk[:n_queries])
+    if elig is not None:
+        hits[:, :n_queries] &= elig.dense()
+    hits[seg < 0] = False
+    bits = hits.reshape(rows.shape[0], qb // 32, 32).astype(np.uint64)
+    words = (bits << np.arange(32, dtype=np.uint64)).sum(axis=2)
+    return words.astype(np.uint32).T  # [qb / 32, n]
+
+
+@pytest.mark.parametrize("lanes,store_lanes", [(4, 4), (8, 8), (16, 16), (4, 16)])
+@pytest.mark.parametrize("use_elig", [True, False])
+@pytest.mark.parametrize("n_q,qb", [(45, 64), (300, 512)])
+def test_gather_kernel_packed_words_equal_dense_reference(
+    lanes, store_lanes, use_elig, n_q, qb
+):
+    """The kernel's packed hit tile, bit for bit, against the dense masked
+    reference: padded rows (seg -1) and padded query columns (all-ones
+    keys) set no bit, an all-ones row super key passes every real key its
+    eligibility allows, and a lane-prefix probe (4 of 16 lanes) packs the
+    prefix test's hits, over one query block and over two.  Its set bits
+    sum to the per-table counts."""
+    import jax.numpy as jnp
+
+    from repro.kernels import filter_kernel
+
+    n, n_tables = 1024, 9
+    store, rows, q_sk, elig, seg = _rand_case(
+        store_lanes, n, n_q, n_tables, n_store=512, seed=lanes * store_lanes
+    )
+    store[7] = 0xFFFFFFFF
+    rows[::37] = 7  # saturated rows subsume every key, the padding keys too
+    seg[-100:] = -1  # padding rows
+    q_sk = q_sk[:, :lanes]
+    qry = np.full((qb, lanes), 0xFFFFFFFF, dtype=np.uint32)
+    qry[:n_q] = q_sk
+    elig_ids = None if not use_elig else tuple(map(jnp.asarray, elig.padded(n, qb)))
+    counts, words = filter_kernel.gather_filter_table_counts(
+        jnp.asarray(rows.astype(np.int32)),
+        ops.device_store(store).lines,
+        jnp.asarray(qry).T,
+        elig_ids,
+        jnp.asarray(seg),
+        store_lanes=store_lanes, n_tables=128, n_queries=n_q,
+        block_n=256, block_q=min(qb, filter_kernel.DEFAULT_BLOCK_Q),
+        interpret=True,
+    )
+    want = _packed_reference(
+        store, rows, qry, elig if use_elig else None, seg, n_q, qb
+    )
+    got = np.asarray(words).view(np.uint32)
+    assert np.array_equal(got, want)
+    # the padded key columns stay clear, the saturated rows' too
+    assert not (got[n_q // 32] >> np.uint32(n_q % 32)).any()
+    assert not got[n_q // 32 + 1 :].any()
+    assert got[:, rows == 7].any()
+    n_bits = int(np.unpackbits(got.view(np.uint8)).sum())
+    assert n_bits == int(np.asarray(counts).sum()) > 0
+
+
+@pytest.mark.parametrize("n_words,n_items,n_live", [
+    (2, 512, 0),  # nothing survived
+    (2, 512, 1),
+    (3, 1000, 1500),  # item count off the 128 block
+    (4, 1 << 15, 20000),  # three levels of running count
+    (8, 64, 512),  # every word nonzero
+])
+def test_nonzero_words_item_major(n_words, n_items, n_live):
+    """The device compaction returns the nonzero words item-major, exactly
+    ``np.flatnonzero`` of the transposed words, with the free slots
+    past them holding index ``n_words · n_items`` and word 0."""
+    rng = np.random.default_rng(n_live)
+    words = np.zeros((n_words, n_items), np.int32)
+    at = rng.choice(words.size, size=n_live, replace=False)
+    words.reshape(-1)[at] = rng.integers(1, 1 << 31, size=n_live) * rng.choice([-1, 1], n_live)
+    flat = words.T.reshape(-1)
+    want = np.flatnonzero(flat)
+    size = ops._pow2_bucket(max(n_live, 1), ops._MIN_PAIRS_BUCKET)
+    got_at, got_word = map(np.asarray, ops._nonzero_words(jnp.asarray(words), size=size))
+    assert np.array_equal(got_at[:n_live], want)
+    assert np.array_equal(got_word[:n_live], flat[want])
+    assert (got_at[n_live:] == words.size).all() and not got_word[n_live:].any()
 
 
 def test_gather_lane_prefix_degrade_over_full_width_store():
@@ -371,13 +465,13 @@ def test_gather_table_cap_demotes_per_batch(lake, monkeypatch):
     seq, _ = discovery.discover(index, query, q_cols, k=10)
     monkeypatch.setattr(ops, "_FUSED_MAX_TABLES", 4)
     calls = []
-    launch = ops.gather_filter_table_counts
+    launch = ops.gather_filter_survivors
 
     def spy(store, rows, *args, **kw):
         calls.append(rows.shape[0])
         return launch(store, rows, *args, **kw)
 
-    monkeypatch.setattr(ops, "gather_filter_table_counts", spy)
+    monkeypatch.setattr(ops, "gather_filter_survivors", spy)
     bat, st = discover_many(
         index, [(query, q_cols)], k=10, backend="fused-gather"
     )[0]
@@ -392,6 +486,9 @@ def test_gather_table_cap_demotes_per_batch(lake, monkeypatch):
     assert st.filter_fused_launches > 0
     assert st.filter_matrix_bytes == 0
     assert st.gather_demotions == 0
+    # each chunk's survivors, re-based to the launch's items, concatenate
+    assert st.filter_pairs_returned == st.filter_passed > 0
+    assert st.regather_pairs == 0
 
 
 def test_gather_session_and_serving_inherit(lake):

@@ -108,8 +108,25 @@ def test_gather_filter_table_counts_compiles(one_chip, lanes, store_lanes, n_tab
     }
     # no operand is padded: arguments cost what they hold (small operands
     # may round up to a whole tile)
-    got = compiled.memory_analysis().argument_size_in_bytes
+    memory = compiled.memory_analysis()
+    got = memory.argument_size_in_bytes
     assert sum(logical.values()) <= got <= sum(logical.values()) + (64 << 10)
+    # the outputs: the counts and the packed hit tile, one bit per (row,
+    # key), unpadded
+    words = ROWS * QUERIES // 8
+    assert words <= memory.output_size_in_bytes <= words + n_tables * 4 + (64 << 10)
+
+
+@pytest.mark.parametrize("n_words,n_items,size", [
+    (QUERIES // 32, ROWS, 1 << 20),  # the largest launch, 2^20 survivors
+    (2, 1 << 17, 1 << 12),  # narrow words: a flat gather took ~45 s here
+])
+def test_survivor_compaction_compiles(one_chip, n_words, n_items, size):
+    words = _spec((n_words, n_items), jnp.int32, one_chip)
+    compiled = ops._nonzero_words.lower(words, size=size).compile()
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert 8 * size <= out <= 8 * size + (64 << 10)  # (index, word) pairs
+    assert " scatter(" not in compiled.as_text()
 
 
 @pytest.mark.parametrize("lanes", LANES)

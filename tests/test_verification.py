@@ -217,10 +217,11 @@ def _web():
 
 @pytest.mark.parametrize("lake,bits", [("tpch", 256), ("web", 128), ("web", 512)])
 def test_batched_equals_sequential(lake, bits):
-    """Top-k of the lazy paths (fused and gather-fused launches: eligible-
-    pair re-gather, value-id verification) equal Algorithm 1's on the
-    strings; the verified pair counts equal those of the host-hits path,
-    which verifies the launch's own match matrix."""
+    """Top-k of the counts-only paths (the fused launch's eligible-pair
+    re-gather, the gather-fused launch's own survivors; value-id
+    verification) equal Algorithm 1's on the strings; the verified pair
+    counts equal those of the host-hits path, which verifies the launch's
+    own match matrix."""
     corpus, queries = _tiny_tpch() if lake == "tpch" else _web()
     (index,) = indexes_at_widths(corpus, widths=(bits,)).values()
     host = B.discover_many(index, queries, k=5, backend="numpy")
@@ -231,32 +232,123 @@ def test_batched_equals_sequential(lake, bits):
             as_tuples = lambda es: [(e.table_id, e.joinability, e.mapping) for e in es]  # noqa: E731
             assert as_tuples(got) == as_tuples(want) == as_tuples(want_host)
             assert (st.verified_tp, st.verified_fp) == (st_host.verified_tp, st_host.verified_fp)
-            assert 0 < st.regather_pairs <= st.filter_checks
+            if backend == "fused-gather":  # nothing is tested twice
+                assert st.regather_pairs == 0
+                assert st.filter_pairs_returned == st.filter_passed > 0
+            else:
+                assert 0 < st.regather_pairs <= st.filter_checks
+                assert st.filter_pairs_returned == 0
             assert st_host.regather_pairs == 0  # its hits came back whole
 
 
 def test_regather_pairs_is_counted_and_absorbed():
+    """Served at ``window=1``: the fused launch re-gathers, the gather-fused
+    launch verifies the pairs it read back (``filter.readback`` counts
+    them) and re-gathers none."""
     corpus, queries = mixed_query_lake(n_tables=60, n_rows=30)
-    session = MateSession.build(
-        corpus, DiscoveryConfig(bits=128, k=3, window=1, backend="fused-gather")
-    )
     query, q_cols = queries[0]
+    for backend in ("fused", "fused-gather"):
+        session = MateSession.build(
+            corpus, DiscoveryConfig(bits=128, k=3, window=1, backend=backend)
+        )
 
-    async def serve():
-        engine = AsyncDiscoveryEngine(session=session, clock=ManualClock())
-        async with engine:
-            return await engine.discover_async(query, q_cols)
+        async def serve():
+            engine = AsyncDiscoveryEngine(session=session, clock=ManualClock())
+            async with engine:
+                return await engine.discover_async(query, q_cols)
 
-    asyncio.run(serve())  # compile first
-    before = session.stats.regather_pairs
-    telemetry.enable()
-    try:
-        req = asyncio.run(serve())
-    finally:
-        records = telemetry.disable()
-    st = req.stats
-    assert 0 < st.regather_pairs <= st.filter_checks
-    assert session.stats.regather_pairs - before == st.regather_pairs
-    (tables,) = [s.attrs for s in records if s.name == "score.tables"]
-    assert tables["regather_pairs"] == st.regather_pairs
-    assert [s.name for s in records].count("plan.key_ids") == 1
+        asyncio.run(serve())  # compile first
+        before = session.stats.regather_pairs
+        telemetry.enable()
+        try:
+            req = asyncio.run(serve())
+        finally:
+            records = telemetry.disable()
+        st = req.stats
+        if backend == "fused-gather":
+            assert st.regather_pairs == 0
+            assert st.filter_pairs_returned == st.filter_passed > 0
+            (readback,) = [s.attrs for s in records if s.name == "filter.readback"]
+            assert readback["pairs"] == st.filter_pairs_returned
+            assert 8 * st.filter_pairs_returned <= readback["pair_bytes"]
+        else:
+            assert 0 < st.regather_pairs <= st.filter_checks
+        assert session.stats.regather_pairs - before == st.regather_pairs
+        (tables,) = [s.attrs for s in records if s.name == "score.tables"]
+        assert tables["regather_pairs"] == st.regather_pairs
+        assert [s.name for s in records].count("plan.key_ids") == 1
+
+
+SURVIVOR_CASES = ("group", "table_chunks", "no_survivors", "lane_degraded", "replay")
+
+
+@pytest.fixture(scope="module")
+def survivor_lake():
+    corpus, queries = mixed_query_lake(n_tables=60, n_rows=30)
+    return indexes_at_widths(corpus), queries
+
+
+def _outcome(entries, st):
+    """What the two phase-B inputs must agree on, bit for bit."""
+    return (
+        [(e.table_id, e.joinability, e.mapping) for e in entries],
+        st.verified_tp, st.verified_fp, st.tables_evaluated,
+        st.tables_pruned_rule1, st.tables_pruned_rule2, st.tables_pruned_empty,
+    )
+
+
+@pytest.mark.parametrize("bits", [128, 256, 512])
+@pytest.mark.parametrize("case", SURVIVOR_CASES)
+def test_device_survivors_equal_the_host_regather(survivor_lake, case, bits, monkeypatch):
+    """Scoring the pairs the gather-fused launch returned gives what the
+    host re-gather of the same launch gives: top-k ids, joinabilities and
+    mappings, verified pairs and every prune counter.  Over a group launch,
+    a launch split into table chunks, a request with candidates and no
+    survivor, a lane-prefix launch (its pairs re-tested at full width) and
+    a bound-cache replay."""
+    indexes, queries = survivor_lake
+    index = indexes[bits]
+    group = [queries[0]]
+    lanes = None
+    if case == "group":
+        group = queries[:2]
+    elif case == "table_chunks":
+        monkeypatch.setattr(ops, "_FUSED_MAX_TABLES", 4)
+    elif case == "no_survivors":
+        # init values the lake holds, key values it has never seen
+        q, qc = queries[0]
+        init = [row[qc[0]] for row in q.cells][:6]
+        group = [(Table(-1, [[v, f"zq{i}xv{i}zq wj"] for i, v in enumerate(init)]), [0, 1])]
+    elif case == "lane_degraded":
+        lanes = index.cfg.lanes // 4
+    pcs = B.plan_and_count(index, group, "fused-gather", filter_lanes=lanes)
+    assert len(pcs) == len(group)
+    for pc in pcs:
+        for k in (3, 10):
+            got, st = B.score_from_counts(index, pc, k=k)
+            # the same launch, its pairs dropped: phase B re-gathers
+            want, st_want = B.score_from_counts(
+                index, dataclasses.replace(pc, pairs=None), k=k
+            )
+            assert _outcome(got, st) == _outcome(want, st_want)
+            assert st.filter_pairs_returned == st.filter_passed
+            assert st_want.filter_pairs_returned == 0
+            # every table is pruned where nothing survived
+            assert (st_want.regather_pairs > 0) == (case != "no_survivors")
+            if case == "replay":
+                again, st_again = B.score_from_counts(
+                    index, pc.cacheable(), k=k, from_cache=True
+                )
+                assert _outcome(again, st_again) == _outcome(got, st)
+                assert st_again.regather_pairs == 0
+            if case == "lane_degraded":  # a superset, re-tested on the host
+                assert st.filter_lanes == lanes
+                assert st.regather_pairs > 0
+            else:
+                assert st.regather_pairs == 0
+    if case == "no_survivors":
+        (pc,) = pcs
+        assert pc.plan.block.n_items > 0 and pc.pairs.rows.size == 0
+        assert int(pc.counts.sum()) == 0
+    else:
+        assert all(pc.pairs.rows.size > 0 for pc in pcs)
